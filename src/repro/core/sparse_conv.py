@@ -194,7 +194,9 @@ class DBBConv2d:
         bit-identical to the unplanned chain); ``tiles`` is the resolved
         config (empty on reference/XLA paths).
         """
-        from repro.kernels.core import conv_geometry, default_interpret, pick_tile
+        from repro.kernels.core import (
+            conv_geometry, default_conv_tiles, default_interpret,
+        )
 
         wp = params["w"]
         pallas = self.kernel_mode == "pallas"
@@ -219,12 +221,11 @@ class DBBConv2d:
                 reps=reps,
             )
         if tiled and not tiles:
-            # freeze the pick_tile defaults explicitly, so the staged
+            # freeze the default tiles explicitly, so the staged
             # closure never depends on ambient registry state at trace time
             _, _, (ho, wo) = conv_geometry(h, w, self.kh, self.kw,
                                            self.stride, self.padding)
-            tiles = {"bf": pick_tile(self.out_channels, 128),
-                     "tile_h": ho, "tile_w": wo}
+            tiles = default_conv_tiles(ho, wo, self.out_channels)
         if quant and fused:
             def run(x):
                 return self.quant_serve(params, x, relu=relu,

@@ -168,7 +168,7 @@ class DBBLinear:
         twin of :meth:`DBBConv2d.make_plan`. ``batch`` is the GEMM's M
         (the tiny-M reference fallback applies, so classifier-head-sized
         plans carry no tiles). Returns ``(run, tiles)``."""
-        from repro.kernels.core import pick_tile, pick_tile_padded
+        from repro.kernels.core import default_matmul_tiles
 
         wp = params["w"]
         quant = isinstance(wp, QuantDBBWeight)
@@ -191,13 +191,11 @@ class DBBLinear:
                 mode=tune, cache=cache, top_k=top_k, reps=reps,
             )
         if tiled and not tiles:
-            # freeze the pick_tile defaults explicitly, so the staged
+            # freeze the default tiles explicitly, so the staged
             # closure never depends on ambient registry state at trace time
-            tc = wp.fmt.group_size(self.out_features) == self.out_features
-            tiles = {"bm": pick_tile_padded(batch, 128)[0],
-                     "bn": pick_tile_padded(self.out_features, 256)[0],
-                     "kb": pick_tile(self.in_features // wp.fmt.bz,
-                                     16 if tc else 8)}
+            tiles = default_matmul_tiles(
+                batch, self.in_features, self.out_features, wp.fmt.bz,
+                jnp.int8 if quant else self.dtype)
         if quant and fused:
             def run(x):
                 return self.quant_serve(params, x, relu=relu,

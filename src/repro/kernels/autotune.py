@@ -14,7 +14,7 @@ kernels and ``(bf, tile_h, tile_w)`` for the fused convs:
 2. **prune** with the analytic roofline model (compute vs HBM traffic
    from ``dbb_gemm_costs``/``dbb_conv_costs``, tile-revisit factors, and
    a per-grid-step overhead term), keeping the top-K;
-3. **measure** the survivors (plus the ``pick_tile`` default, always)
+3. **measure** the survivors (plus the default tiles, always)
    with the shared ``block_until_ready`` median-of-k harness
    (``repro.xla_utils.median_time_us`` — the same code path
    ``benchmarks/timing.py`` uses, so tuner and benchmark numbers are
@@ -212,19 +212,6 @@ def conv_candidates(ho: int, wo: int, f: int, keep: int = 4):
             for bf in bfs for th in ths for tw in tws]
 
 
-def default_matmul_tiles(m: int, k: int, n: int, fmt: DBBFormat, tc: bool) -> dict:
-    """What the untuned ``pick_tile`` path resolves to (the baseline every
-    search measures against)."""
-    bm, _ = core.pick_tile_padded(m, 128)
-    bn, _ = core.pick_tile_padded(n, 256)
-    kb = core.pick_tile(k // fmt.bz, 16 if tc else 8)
-    return {"bm": bm, "bn": bn, "kb": kb}
-
-
-def default_conv_tiles(ho: int, wo: int, f: int) -> dict:
-    return {"bf": core.pick_tile(f, 128), "tile_h": ho, "tile_w": wo}
-
-
 # ---------------------------------------------------------------------------
 # Analytic pruning model (roofline over the §5/§6 cost accounting)
 # ---------------------------------------------------------------------------
@@ -263,7 +250,7 @@ def conv_cost_terms(batch: int, ho: int, wo: int, c_in: int, f: int,
     bh_in = (bh - 1) * sh + kh
     bw_in = (bw - 1) * sw + kw
     spatial = batch * th * tw
-    grid = spatial * (f // bf) * kh * kw
+    grid = spatial * (f // bf)  # the kh·kw taps run inside each step
     g = dbb_gemm_costs(batch * ho * wo, kh * kw * c_in, f, fmt,
                        bits=int(8 * itemsize), act_bits=int(8 * itemsize))
     act = spatial * bh_in * bw_in * c_in * itemsize * (f // bf)
@@ -318,7 +305,7 @@ class TuneResult:
     sig: tuple
     tiles: dict            # measured-best config
     measured_us: float     # its median wall time
-    default_tiles: dict    # the pick_tile baseline
+    default_tiles: dict    # the untuned defaults (the baseline)
     default_us: float      # baseline median wall time (same harness/run)
     modeled_best_us: float     # best modeled cost over all candidates
     modeled_default_us: float  # modeled cost of the baseline
@@ -448,7 +435,7 @@ def tune_matmul(m: int, k: int, n: int, fmt: DBBFormat, *,
     return _search(
         kind, sig, matmul_candidates(m, k, n, fmt, keep=keep),
         lambda t: modeled_matmul_cost(m, k, n, fmt, t, itemsize, cal=cal),
-        build, default_matmul_tiles(m, k, n, fmt, kind == core.KIND_MATMUL_TC),
+        build, core.default_matmul_tiles(m, k, n, fmt.bz, dtype),
         top_k=top_k, reps=reps, warmup=warmup, cache=cache, save=save,
     )
 
@@ -515,7 +502,7 @@ def tune_conv(batch: int, h: int, w: int, c: int, f: int, kh: int, kw: int,
         kind, sig, conv_candidates(ho, wo, f, keep=keep),
         lambda t: modeled_conv_cost(batch, ho, wo, c, f, kh, kw, sh, sw,
                                     mfmt, t, itemsize, cal=cal),
-        build, default_conv_tiles(ho, wo, f),
+        build, core.default_conv_tiles(ho, wo, f),
         top_k=top_k, reps=reps, warmup=warmup, cache=cache, save=save,
     )
 
@@ -528,7 +515,7 @@ def tune_conv(batch: int, h: int, w: int, c: int, f: int, kh: int, kw: int,
 def tiles_for_matmul(m, k, n, fmt, dtype, *, mode: str = "cache", cache=None,
                      top_k: int = 4, reps: int = 3) -> dict:
     """Resolve tiles for a matmul launch under a tuning ``mode``:
-    ``'off'`` (pick_tile defaults), ``'cache'`` (registry/cache hits only,
+    ``'off'`` (the default tiles), ``'cache'`` (registry/cache hits only,
     never search), ``'search'`` (search on miss and persist)."""
     if mode == "off":
         return {}

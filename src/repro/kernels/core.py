@@ -16,13 +16,30 @@ This module owns that plumbing once: the init/accumulate/store pattern
 requantize-to-int8, all executed once where the hardware's requantizer
 sits, DESIGN.md §9), K-innermost grid construction and the fp32 VMEM
 scratch + output BlockSpec boilerplate (:func:`os_matmul_call`), tile-size
-resolution (:func:`resolve_tile` strict / :func:`pick_tile` permissive),
-and interpret-mode dispatch (:func:`default_interpret` — kernels validate
-in interpret mode on CPU and compile unchanged on TPU).
+resolution (:func:`resolve_tile` strict / :func:`pick_tile` permissive /
+:func:`pick_tile_padded` aligned defaults), the activation mux
+(:func:`dbb_mux`), the conv tap loads (:func:`conv_tap`), and
+interpret-mode dispatch (:func:`default_interpret`).
+
+Interpret mode (CPU) accepts any block shape; the TPU compiler does not.
+The rules it enforces, and that the default tiles here follow:
+
+* a block's last dim is a multiple of 128 lanes, or the whole array dim;
+* its second-to-last dim is a multiple of 8 sublanes, or the whole dim
+  (int8 operand tiles are padded to 32-row packs, :func:`sublanes`);
+* no ``dynamic_slice`` of a loaded value and no reshape that splits the
+  lane axis (e.g. ``(bm, kb·bz) → (bm, kb, bz)``) inside a kernel;
+* no strided ref load of 8-bit data (stride-2 convs are phase-split in
+  the wrapper instead, :func:`phase_split`).
+
+``tests/test_tpu_compile.py`` compiles the serving-path kernels for a
+described v5e chip, so a body or tiling that breaks these rules fails on
+the CPU, without the chip.
 """
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional, Sequence
 
 import jax
@@ -36,7 +53,8 @@ QMAX = 127  # symmetric int8 clip range for the requantize epilogue
 
 
 def default_interpret() -> bool:
-    """Interpret (CPU validation) unless a real TPU backend is present."""
+    """Interpret mode (the kernel bodies run in Python, any block shape
+    accepted) unless the default backend is a TPU."""
     return jax.default_backend() != "tpu"
 
 
@@ -73,38 +91,82 @@ def pick_tile(dim: int, tile: int) -> int:
     return t
 
 
-def resolve_or_pick(dim: int, tile, default: int, name: str,
+def resolve_or_pick(dim: int, tile, default: int, name: str, *, align: int,
                     tuned: int | None = None) -> int:
     """``tile`` is None → the ``tuned`` size from the autotune registry when
-    it divides, else :func:`pick_tile` of the default; otherwise the strict
+    it divides, else :func:`aligned_divisor` of the default (the whole dim
+    when no ``align`` multiple divides); otherwise the strict
     :func:`resolve_tile` (an explicit request that does not divide is still
     a caller error)."""
     if tile is None:
         if tuned is not None and 0 < tuned <= dim and dim % tuned == 0:
             return int(tuned)
-        return pick_tile(dim, default)
+        return aligned_divisor(dim, default, align)
     return resolve_tile(dim, tile, name)
 
 
-def pick_tile_padded(dim: int, tile: int) -> tuple:
-    """``(t, padded_dim)`` — :func:`pick_tile` when it lands on a usable
-    divisor; otherwise the requested tile with the ragged edge zero-padded
-    (the caller pads the operand to ``padded_dim`` and slices the result).
+LANES = 128  # last-dim width of one TPU vector register tile
 
-    This is the fix for the divisor-fallback pathology: a dimension like
-    2·p (p prime) has no divisor near the default, and :func:`pick_tile`'s
-    whole-dimension fallback builds one enormous VMEM tile. Padding to the
-    requested tile keeps the grid shape sane at the cost of (padded-dim)/dim
-    wasted compute — exact everywhere, since padded rows/columns are zero.
+
+def sublanes(dtype) -> int:
+    """Rows of one native tile for ``dtype``: 8 for 32-bit, 16 for 16-bit
+    and 32 for 8-bit operands (narrow types pack along sublanes)."""
+    return 8 * 4 // jnp.dtype(dtype).itemsize
+
+
+def aligned_divisor(dim: int, tile: int, align: int) -> int:
+    """Largest multiple of ``align`` that is ≤ ``max(tile, align)`` and
+    divides ``dim``; the whole ``dim`` (always a legal block) when none
+    does."""
+    for t in range(max(tile, align) // align * align, 0, -align):
+        if dim % t == 0:
+            return t
+    return dim
+
+
+def pick_tile_padded(dim: int, tile: int, align: int) -> tuple:
+    """``(t, padded_dim)`` — a default tile the TPU compiler accepts.
+
+    The whole dimension when it fits in ``tile`` (a full-extent block is
+    always legal); else the largest multiple of ``align`` in
+    ``[tile/2, tile]`` that divides ``dim``; else ``tile`` rounded down to
+    ``align`` with the ragged edge zero-padded (the caller pads the
+    operand to ``padded_dim`` and slices the result — exact, since padded
+    rows/columns are zero). So N=1000 at ``(256, 128)`` pads to 1024
+    rather than taking the divisor 250, which is no lane multiple.
     """
-    t = pick_tile(dim, tile)
-    if tile // 4 <= t <= 2 * tile or t == dim <= 2 * tile:
-        return t, dim
-    t = min(tile, dim)
+    if dim <= tile:
+        return dim, dim
+    t = max(align, tile // align * align)
+    for c in range(t, t // 2 - 1, -align):
+        if c > 0 and dim % c == 0:
+            return c, dim
     return t, -(-dim // t) * t
 
 
-def pad_tile(dim: int, tile, default: int) -> tuple:
+def default_kb(nb: int, bz: int) -> int:
+    """Default K blocks per grid step: the activation tile (bm, kb·bz) is
+    lane-aligned (kb·bz a multiple of 128), or the whole K in one step."""
+    align = math.lcm(bz, LANES) // bz
+    return aligned_divisor(nb, align, align)
+
+
+def default_matmul_tiles(m: int, k: int, n: int, bz: int, dtype) -> dict:
+    """The untuned ``(bm, bn, kb)`` of one compressed-matmul launch:
+    bm sublane-aligned for the operand dtype, bn lane-aligned (N padded
+    when no aligned divisor exists), kb from :func:`default_kb`."""
+    return {"bm": pick_tile_padded(m, 128, sublanes(dtype))[0],
+            "bn": pick_tile_padded(n, 256, LANES)[0],
+            "kb": default_kb(k // bz, bz)}
+
+
+def default_conv_tiles(ho: int, wo: int, f: int) -> dict:
+    """The untuned ``(bf, tile_h, tile_w)`` of one fused-conv launch: a
+    lane-aligned F block (or all of F) over the whole output map."""
+    return {"bf": aligned_divisor(f, 128, LANES), "tile_h": ho, "tile_w": wo}
+
+
+def pad_tile(dim: int, tile, default: int, align: int = 1) -> tuple:
     """Permissive ops-level tile resolution with a zero-pad escape hatch.
 
     ``(t, padded_dim)``: None → :func:`pick_tile_padded` of the default;
@@ -115,7 +177,7 @@ def pad_tile(dim: int, tile, default: int) -> tuple:
     ``ops.*`` entry points pad-and-slice.
     """
     if tile is None:
-        return pick_tile_padded(dim, default)
+        return pick_tile_padded(dim, default, align)
     t = max(1, min(int(tile), dim))
     return t, -(-dim // t) * t
 
@@ -252,7 +314,7 @@ def epilogue_plan(n: int, bn: int, *, scales=None, bias=None, relu=False,
     ep = Epilogue(scales is not None, bias is not None, bool(relu),
                   out_scale is not None)
     operands, specs = [], []
-    spec = pl.BlockSpec((1, bn), lambda i, j, s: (0, j))
+    spec = pl.BlockSpec((1, bn), lambda *g: (0, g[1]))  # N is grid axis 1
     for v, present in ((scales, ep.has_scale), (bias, ep.has_bias),
                        (out_scale, ep.has_out_scale)):
         if present:
@@ -327,17 +389,24 @@ def os_accumulate(acc_ref, o_ref, contribution, *, grid_axis: int, scale=None,
 
     @pl.when(pl.program_id(grid_axis) == pl.num_programs(grid_axis) - 1)
     def _store():
-        acc = acc_ref[...]
-        if scale is not None:
-            acc = acc.astype(jnp.float32) * scale
-        if bias is not None:
-            acc = acc.astype(jnp.float32) + bias
-        if relu:
-            acc = jnp.maximum(acc, jnp.zeros((), acc.dtype))
-        if out_scale is not None:
-            acc = jnp.clip(jnp.round(acc.astype(jnp.float32) / out_scale),
-                           -QMAX, QMAX)
-        o_ref[...] = acc.reshape(o_ref.shape).astype(o_ref.dtype)
+        store_epilogue(acc_ref[...], o_ref, scale=scale, bias=bias,
+                       relu=relu, out_scale=out_scale)
+
+
+def store_epilogue(acc, o_ref, *, scale=None, bias=None, relu: bool = False,
+                   out_scale=None):
+    """The accumulator flush: apply the fused epilogue (see
+    :func:`os_accumulate`) to ``acc`` and store it into ``o_ref``."""
+    if scale is not None:
+        acc = acc.astype(jnp.float32) * scale
+    if bias is not None:
+        acc = acc.astype(jnp.float32) + bias
+    if relu:
+        acc = jnp.maximum(acc, jnp.zeros((), acc.dtype))
+    if out_scale is not None:
+        acc = jnp.clip(jnp.round(acc.astype(jnp.float32) / out_scale),
+                       -QMAX, QMAX)
+    o_ref[...] = acc.reshape(o_ref.shape).astype(o_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -407,21 +476,66 @@ def extract_conv_tiles(xp: jax.Array, *, bh, bw, sh, sw, kh, kw, th, tw):
     return t.transpose(0, 1, 3, 2, 4, 5).reshape(n * th * tw, bh_in, bw_in, c)
 
 
-def conv_patch(x: jax.Array, dy, dx, *, bh, bw, sh, sw):
-    """In-VMEM shifted (strided) view of one kernel tap — the IM2COL unit.
+def phase_split(tiles: jax.Array, sh: int, sw: int) -> jax.Array:
+    """``(T, bh_in, bw_in, C)`` input tiles → ``(T, sh·sw, Hq, Wq, C)``
+    stride phases: phase ``(p, q)`` holds rows ``p::sh`` and columns
+    ``q::sw``. Every tap of a strided conv is then a *contiguous* window
+    of one phase (:func:`conv_tap`), so the kernel needs no strided load,
+    which the TPU compiler refuses for 8-bit data. Stride 1 is one phase
+    (a free reshape)."""
+    t, h, w, c = tiles.shape
+    hq, wq = -(-h // sh), -(-w // sw)
+    if sh == sw == 1:
+        return tiles.reshape(t, 1, h, w, c)
+    x = jnp.pad(tiles, ((0, 0), (0, hq * sh - h), (0, wq * sw - w), (0, 0)))
+    x = x.reshape(t, hq, sh, wq, sw, c).transpose(0, 2, 4, 1, 3, 5)
+    return x.reshape(t, sh * sw, hq, wq, c)
 
-    ``x``: (bh_in, bw_in, C) input tile already resident in VMEM; ``dy, dx``
-    may be traced scalars (tap index from ``pl.program_id``). Returns the
-    (bh·bw, C) activation matrix for that tap without materializing the
-    kh·kw-duplicated im2col tensor anywhere.
-    """
-    c = x.shape[-1]
-    hs = (bh - 1) * sh + 1
-    ws = (bw - 1) * sw + 1
-    patch = jax.lax.dynamic_slice(x, (dy, dx, 0), (hs, ws, c))
-    if sh > 1 or sw > 1:
-        patch = jax.lax.slice(patch, (0, 0, 0), (hs, ws, c), (sh, sw, 1))
-    return patch.reshape(bh * bw, c)
+
+def conv_tap(x_ref, dy: int, dx: int, *, bh, bw, sh, sw):
+    """The (bh·bw, C) activation matrix of kernel tap ``(dy, dx)`` — the
+    IM2COL unit. ``x_ref`` is the phase-split VMEM input tile
+    ``(1, sh·sw, Hq, Wq, C)`` (:func:`phase_split`); the tap is one static
+    contiguous window of one phase, loaded straight from the ref, so the
+    kh·kw-duplicated im2col tensor is never materialized."""
+    p = (dy % sh) * sw + dx % sw
+    patch = x_ref[0, p, pl.ds(dy // sh, bh), pl.ds(dx // sw, bw), :]
+    return patch.reshape(bh * bw, patch.shape[-1])
+
+
+def mux_positions(indices: jax.Array, kb: int, bz: int) -> jax.Array:
+    """``(nb, nnz)`` intra-block positions → ``(nb·nnz, 1)`` int32: the
+    row of its K tile (``kb`` blocks of ``bz``) that each compressed-K
+    column reads — the operand :func:`dbb_mux` selects with."""
+    nb, _ = indices.shape
+    blk = (jnp.arange(nb, dtype=jnp.int32) % kb) * bz
+    return (blk[:, None] + indices.astype(jnp.int32)).reshape(-1, 1)
+
+
+def dbb_mux(a: jax.Array, pos: jax.Array) -> jax.Array:
+    """The activation mux of the paper's S8DP1 lane as one 2-D selection
+    matmul: ``a`` (m, kt) × one-hot ``[iota == pos]`` (c, kt), contracted
+    on kt, gives the (m, c) compressed-K tile ``a[:, pos[j]]``.
+
+    Exact: every output is one operand value times 1 (int8 accumulates in
+    int32, fp32 at full precision), cast back to the operand dtype. No
+    lane axis is split, which is what lets the TPU compiler take it."""
+    c, kt = pos.shape[0], a.shape[1]
+    sel = (jax.lax.broadcasted_iota(jnp.int32, (c, kt), 1) == pos).astype(a.dtype)
+    return mxu_dot(a, sel, contract_rhs=1).astype(a.dtype)
+
+
+def mxu_dot(a: jax.Array, b: jax.Array, *, contract_rhs: int = 0) -> jax.Array:
+    """``a @ b`` (or ``a @ b.T`` with ``contract_rhs=1``) into the
+    datapath's accumulator: exact int32 for int8 operands, fp32 for float
+    ones. fp32 operands multiply at full fp32 precision, as in interpret
+    mode — the TPU's default would round them to bf16 first."""
+    f32 = jnp.dtype(a.dtype) == jnp.float32
+    return jax.lax.dot_general(
+        a, b.astype(a.dtype), (((1,), (contract_rhs,)), ((), ())),
+        preferred_element_type=acc_dtype_for(a.dtype),
+        precision=jax.lax.Precision.HIGHEST if f32 else None,
+    )
 
 
 def os_matmul_call(

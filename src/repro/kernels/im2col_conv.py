@@ -14,9 +14,13 @@ their 6×2 line buffer; a full-tile VMEM buffer does strictly better).
 Layout: NHWC input, HWIO weights. Strides, even kernels, SAME/VALID/
 explicit padding and spatial H×W output tiling (bounded VMEM for large
 feature maps) are all supported; geometry and the shifted-view tap come
-from :mod:`repro.kernels.core` (DESIGN.md §6). The kernel tap (dy, dx) is
-the innermost grid axis, so the shared output-stationary accumulator
-pattern applies unchanged.
+from :mod:`repro.kernels.core` (DESIGN.md §6). The grid is (output tile,
+F block); the kh·kw taps are a static loop inside each step, each a
+static window of the VMEM input tile accumulated output-stationary in a
+VMEM scratch. The TPU compiler takes no ``dynamic_slice`` of a loaded
+value and no strided load of 8-bit data, so the tap offsets are static
+and a strided conv reads a stride-phase split of its input tile
+(``core.phase_split``, one XLA pass in the wrapper) instead of striding.
 """
 from __future__ import annotations
 
@@ -34,7 +38,8 @@ def plan_conv(x, kh, kw, *, stride, padding, tile_h=None, tile_w=None):
     """Host-side conv planning shared by the dense and VDBB fused kernels.
 
     Pads ``x`` to the exact input footprint, extracts halo'd spatial tiles
-    (no-op when untiled), and returns ``(tiles, geom)`` where geom carries
+    (no-op when untiled), splits them into stride phases, and returns
+    ``(tiles, geom)``: tiles ``(N·th·tw, sh·sw, Hq, Wq, C)`` and geom with
     every static the kernels and BlockSpecs need.
     """
     n, h, w, c = x.shape
@@ -54,11 +59,17 @@ def plan_conv(x, kh, kw, *, stride, padding, tile_h=None, tile_w=None):
         ),
     )[:, :need_h, :need_w, :]
     xt = core.extract_conv_tiles(xp, bh=bh, bw=bw, sh=sh, sw=sw, kh=kh, kw=kw, th=th, tw=tw)
+    xt = core.phase_split(xt, sh, sw)
     geom = dict(
         n=n, c=c, ho=ho, wo=wo, sh=sh, sw=sw, bh=bh, bw=bw, th=th, tw=tw,
-        bh_in=(bh - 1) * sh + kh, bw_in=(bw - 1) * sw + kw, kh=kh, kw=kw,
+        kh=kh, kw=kw,
     )
     return xt, geom
+
+
+def conv_in_spec(xt):
+    """Input BlockSpec: one whole phase-split tile per spatial grid index."""
+    return pl.BlockSpec((1, *xt.shape[1:]), lambda p, j: (p, 0, 0, 0, 0))
 
 
 def conv_out_spec(geom, bf):
@@ -66,24 +77,37 @@ def conv_out_spec(geom, bf):
     th, tw = geom["th"], geom["tw"]
     return pl.BlockSpec(
         (1, geom["bh"], geom["bw"], bf),
-        lambda p, j, t: (p // (th * tw), (p % (th * tw)) // tw, p % tw, j),
+        lambda p, j: (p // (th * tw), (p % (th * tw)) // tw, p % tw, j),
     )
 
 
-def _im2col_conv_kernel(x_ref, w_ref, *rest, kw, sh, sw, bh, bw, ep=None):
-    """Grid: (N·th·tw, F/bf, kh·kw). x: (1, bh_in, bw_in, C); w: (1, C, bf).
-    One kernel tap per innermost grid step — the shifted-view im2col;
+def tap_geom(geom) -> dict:
+    """The statics :func:`conv_taps` needs, from a :func:`plan_conv` geom."""
+    return {k: geom[k] for k in ("kh", "kw", "sh", "sw", "bh", "bw")}
+
+
+def conv_taps(x_ref, tap_contribution, acc_ref, *, kh, kw, sh, sw, bh, bw):
+    """Accumulate every kernel tap of one output tile into ``acc_ref``:
+    ``tap_contribution(t, patch)`` maps tap ``t``'s (bh·bw, C) activation
+    matrix (:func:`core.conv_tap`) to its (bh·bw, bf) partial sum. The
+    taps are a static loop, so every window offset is a constant."""
+    for t in range(kh * kw):
+        patch = core.conv_tap(x_ref, t // kw, t % kw, bh=bh, bw=bw, sh=sh, sw=sw)
+        contrib = tap_contribution(t, patch)
+        if t == 0:
+            acc_ref[...] = contrib
+        else:
+            acc_ref[...] += contrib
+
+
+def _im2col_conv_kernel(x_ref, w_ref, *rest, geom, ep=None):
+    """Grid: (N·th·tw, F/bf). x: (1, sh·sw, Hq, Wq, C); w: (kh·kw, C, bf);
     ``rest`` carries the optional (1, bf) fp32 epilogue rows named by the
     static ``ep`` (scale/bias/out_scale — DESIGN.md §9)."""
     flush, o_ref, acc_ref = core.split_epilogue(ep, rest)
-    t = pl.program_id(2)
-    patch = core.conv_patch(x_ref[0], t // kw, t % kw, bh=bh, bw=bw, sh=sh, sw=sw)
-    contrib = jax.lax.dot(
-        patch,
-        w_ref[0].astype(patch.dtype),
-        preferred_element_type=core.acc_dtype_for(patch.dtype),
-    )
-    core.os_accumulate(acc_ref, o_ref, contrib, grid_axis=2, **flush)
+    conv_taps(x_ref, lambda t, patch: core.mxu_dot(patch, w_ref[t]), acc_ref,
+              **geom)
+    core.store_epilogue(acc_ref[...], o_ref, **flush)
 
 
 def im2col_conv(
@@ -99,7 +123,7 @@ def im2col_conv(
     bf: int | None = None,
     tile_h: int | None = None,
     tile_w: int | None = None,
-    interpret: bool | None = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Fused im2col conv. x: (N, H, W, C); w: (kh, kw, C, F). The optional
     epilogue (``scales``/``bias``/``relu``/``out_scale``, DESIGN.md §9)
@@ -114,23 +138,20 @@ def im2col_conv(
         sig = core.conv_sig(n, ho, wo, c, f, kh, kw, sh, sw, 0, 0, x.dtype)
         bf, tile_h, tile_w = core.tuned_conv_tiles(core.KIND_CONV_DENSE, sig, ho, wo, f)
     xt, g = plan_conv(x, kh, kw, stride=stride, padding=padding, tile_h=tile_h, tile_w=tile_w)
-    bf = core.resolve_or_pick(f, bf, 128, "bf")
+    bf = core.resolve_or_pick(f, bf, 128, "bf", align=core.LANES)
     w3 = w.reshape(kh * kw, c, f)
-    grid = (n * g["th"] * g["tw"], f // bf, kh * kw)
+    grid = (n * g["th"] * g["tw"], f // bf)
     acc_dtype = core.acc_dtype_for(x.dtype)  # int32 on the int8 path (§8)
     ep, e_ops, e_specs, out_dtype = core.epilogue_plan(
         f, bf, scales=scales, bias=bias, relu=relu, out_scale=out_scale,
         acc_dtype=acc_dtype, in_dtype=x.dtype,
     )
     return pl.pallas_call(
-        functools.partial(
-            _im2col_conv_kernel, kw=kw, sh=g["sh"], sw=g["sw"], bh=g["bh"],
-            bw=g["bw"], ep=ep,
-        ),
+        functools.partial(_im2col_conv_kernel, geom=tap_geom(g), ep=ep),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, g["bh_in"], g["bw_in"], c), lambda p, j, t: (p, 0, 0, 0)),
-            pl.BlockSpec((1, c, bf), lambda p, j, t: (t, 0, j)),
+            conv_in_spec(xt),
+            pl.BlockSpec((kh * kw, c, bf), lambda p, j: (0, 0, j)),
             *e_specs,
         ],
         out_specs=conv_out_spec(g, bf),

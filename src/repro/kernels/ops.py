@@ -1,8 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels with mode dispatch.
 
-``interpret`` defaults to True unless a real TPU backend is present (see
-kernels/core.py), so the same call sites validate on CPU and run compiled
-on TPU.
+``interpret`` defaults to True unless the default backend is a TPU
+(``core.default_interpret``): the same call sites run in interpret mode
+on the CPU and compiled on a TPU. The compiled path holds only block
+shapes and bodies the TPU compiler accepts (kernels/core.py);
+``tests/test_tpu_compile.py`` checks that without the chip.
 
 Dtype dispatch (DESIGN.md §8): the same entry points accept fp32/bf16 or
 int8 operands. Integer operands run the int8 datapath — exact int32 OS
@@ -77,8 +79,8 @@ def _matmul_dispatch(a, w, scales, bm, bn, kb, interpret, *, bias=None,
         bm, bn, kb = tuned.get("bm"), tuned.get("bn"), tuned.get("kb")
         if kb is not None and (k // fmt.bz) % kb != 0:
             kb = None  # a tuned K tile must divide exactly; fall back
-    bm, mp = core.pad_tile(m, bm, 128)
-    bn, n_pad = core.pad_tile(n, bn, 256)
+    bm, mp = core.pad_tile(m, bm, 128, core.sublanes(a.dtype))
+    bn, n_pad = core.pad_tile(n, bn, 256, core.LANES)
     if mp != m:
         a = jnp.pad(a, ((0, mp - m), (0, 0)))
     values = w.values

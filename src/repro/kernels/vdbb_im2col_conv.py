@@ -12,10 +12,21 @@ in-VMEM transforms in one kernel:
   compute:    MXU matmuls at nnz/bz occupancy (tc) or dense (bw)
 
 The conv weight (kh, kw, C, F) is DBB-compressed along K = kh·kw·C with
-C % bz == 0, so every bz-block lies inside a single kernel tap and the
-tap (dy, dx) — the innermost grid axis — streams exactly its own C/bz
-compressed blocks per step. Geometry, tiling, and the output-stationary
-accumulator all come from :mod:`repro.kernels.core` (DESIGN.md §6).
+C % bz == 0, so every bz-block lies inside a single kernel tap and tap
+(dy, dx) reads exactly its own C/bz compressed blocks. Geometry, tiling,
+and the output-stationary accumulator all come from
+:mod:`repro.kernels.core` (DESIGN.md §6).
+
+Tiling, as the TPU compiler enforces it: the grid is (output tile, F
+block) with bf a multiple of 128 or all of F; one step holds the whole
+(kh·kw, cb·nnz, bf) compressed weight block, whose middle dim is the
+whole array dim and so needs no alignment; the kh·kw taps run as a
+static loop inside the step, each a static contiguous window of the
+stride-phase-split input tile (no ``dynamic_slice``, no strided 8-bit
+load). The per-tap mux is the 2-D selection matmul ``core.dbb_mux`` —
+a ``(bh·bw, C) → (bh·bw, cb, bz)`` reshape splits the lane axis, which
+the compiler refuses. The (bh, bw, C) → (bh·bw, C) tap reshape wants bw
+a multiple of 8.
 
 Both pattern-sharing modes are provided, mirroring ``vdbb_matmul``:
 ``vdbb_im2col_conv_tc`` (group-shared patterns, compressed-K compute) and
@@ -33,7 +44,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.vdbb import DBBFormat, DBBWeight
 from repro.kernels import core
-from repro.kernels.im2col_conv import conv_out_spec, plan_conv
+from repro.kernels.im2col_conv import (
+    conv_in_spec, conv_out_spec, conv_taps, plan_conv, tap_geom,
+)
 from repro.kernels.vdbb_matmul import dbb_expand_block
 
 
@@ -57,34 +70,20 @@ def _conv_weight_geometry(dw: DBBWeight, kh: int, kw: int):
 # ---------------------------------------------------------------------------
 
 
-def _vdbb_conv_tc_kernel(
-    x_ref, v_ref, idx_ref, *rest, bz, nnz, kw, sh, sw, bh, bw, ep=None
-):
-    """Grid: (N·th·tw, F/bf, kh·kw). x: (1, bh_in, bw_in, C);
-    v: (1, cb·nnz, bf); idx: (1, cb, nnz) int32; ``rest`` carries the
-    optional (1, bf) fp32 epilogue rows named by the static ``ep``
-    (scale/bias/out_scale — DESIGN.md §9)."""
+def _vdbb_conv_tc_kernel(x_ref, v_ref, pos_ref, *rest, geom, ep=None):
+    """Grid: (N·th·tw, F/bf). x: (1, sh·sw, Hq, Wq, C); v: (kh·kw, cb·nnz,
+    bf); pos: (kh·kw, cb·nnz, 1) int32 — per tap, the channel each
+    compressed-K column reads; ``rest`` carries the optional (1, bf) fp32
+    epilogue rows named by the static ``ep`` (scale/bias/out_scale —
+    DESIGN.md §9)."""
     flush, o_ref, acc_ref = core.split_epilogue(ep, rest)
-    t = pl.program_id(2)
-    patch = core.conv_patch(x_ref[0], t // kw, t % kw, bh=bh, bw=bw, sh=sh, sw=sw)
-    c = patch.shape[-1]
-    cb = c // bz
-    pref = core.acc_dtype_for(patch.dtype)  # int32 for int8 operands
-    a = patch.reshape(bh * bw, cb, bz)
-    idx = idx_ref[0]  # (cb, nnz)
-    # The activation mux: one-hot gather A[:, b, idx[b, j]] -> compressed K.
-    onehot = jax.nn.one_hot(idx, bz, dtype=a.dtype)  # (cb, nnz, bz)
-    ac = jax.lax.dot_general(
-        a,
-        onehot,
-        dimension_numbers=(((2,), (2,)), ((1,), (0,))),
-        preferred_element_type=pref,
-    )  # (cb, bh*bw, nnz)
-    ac = ac.transpose(1, 0, 2).reshape(bh * bw, cb * nnz).astype(a.dtype)
-    contrib = jax.lax.dot(
-        ac, v_ref[0].astype(a.dtype), preferred_element_type=pref
-    )
-    core.os_accumulate(acc_ref, o_ref, contrib, grid_axis=2, **flush)
+
+    def tap(t, patch):
+        # The activation mux: patch[:, pos[t, j]] -> compressed K.
+        return core.mxu_dot(core.dbb_mux(patch, pos_ref[t]), v_ref[t])
+
+    conv_taps(x_ref, tap, acc_ref, **geom)
+    core.store_epilogue(acc_ref[...], o_ref, **flush)
 
 
 # ---------------------------------------------------------------------------
@@ -92,26 +91,18 @@ def _vdbb_conv_tc_kernel(
 # ---------------------------------------------------------------------------
 
 
-def _vdbb_conv_bw_kernel(
-    x_ref, v_ref, idx_ref, *rest, bz, nnz, kw, sh, sw, bh, bw, ep=None
-):
-    """Grid: (N·th·tw, F/bf, kh·kw). x: (1, bh_in, bw_in, C);
-    v/idx: (1, cb·nnz, bf) — per-column patterns; ``rest`` carries the
+def _vdbb_conv_bw_kernel(x_ref, v_ref, idx_ref, *rest, bz, geom, ep=None):
+    """Grid: (N·th·tw, F/bf). x: (1, sh·sw, Hq, Wq, C); v/idx: (kh·kw,
+    nnz, cb, bf) — per-column patterns, slot-major; ``rest`` carries the
     optional (1, bf) fp32 epilogue rows named by ``ep`` (DESIGN.md §9)."""
     flush, o_ref, acc_ref = core.split_epilogue(ep, rest)
-    t = pl.program_id(2)
-    patch = core.conv_patch(x_ref[0], t // kw, t % kw, bh=bh, bw=bw, sh=sh, sw=sw)
-    bf = o_ref.shape[-1]
-    cb = patch.shape[-1] // bz
-    v = v_ref[0].reshape(cb, nnz, bf)
-    idx = idx_ref[0].reshape(cb, nnz, bf)
-    wd = dbb_expand_block(v, idx, bz)  # (C, bf), the "late mux"
-    contrib = jax.lax.dot(
-        patch,
-        wd.astype(patch.dtype),
-        preferred_element_type=core.acc_dtype_for(patch.dtype),
-    )
-    core.os_accumulate(acc_ref, o_ref, contrib, grid_axis=2, **flush)
+
+    def tap(t, patch):
+        wd = dbb_expand_block(v_ref[t], idx_ref[t], bz)  # (C, bf), the "late mux"
+        return core.mxu_dot(patch, wd)
+
+    conv_taps(x_ref, tap, acc_ref, **geom)
+    core.store_epilogue(acc_ref[...], o_ref, **flush)
 
 
 # ---------------------------------------------------------------------------
@@ -133,14 +124,14 @@ def _tuned_conv_defaults(kind, x, fmt, kh, kw, f, stride, padding,
     return core.tuned_conv_tiles(kind, sig, ho, wo, f)
 
 
-def _launch(kernel, x, operands, wspecs, fmt, kh, kw, *, stride, padding, bf,
+def _launch(kernel, x, operands, wspecs, kh, kw, *, stride, padding, bf,
             tile_h, tile_w, out_dtype, interpret, scales=None, bias=None,
             relu=False, out_scale=None):
     n = x.shape[0]
     f = operands[0].shape[-1]
     xt, g = plan_conv(x, kh, kw, stride=stride, padding=padding,
                       tile_h=tile_h, tile_w=tile_w)
-    grid = (n * g["th"] * g["tw"], f // bf, kh * kw)
+    grid = (n * g["th"] * g["tw"], f // bf)
     acc_dtype = core.acc_dtype_for(x.dtype)  # int32 on the int8 path
     ep, e_ops, e_specs, out_dtype = core.epilogue_plan(
         f, bf, scales=scales, bias=bias, relu=relu, out_scale=out_scale,
@@ -149,15 +140,9 @@ def _launch(kernel, x, operands, wspecs, fmt, kh, kw, *, stride, padding, bf,
     operands = (*operands, *e_ops)
     wspecs = [*wspecs, *e_specs]
     return pl.pallas_call(
-        functools.partial(
-            kernel, bz=fmt.bz, nnz=fmt.nnz, kw=kw,
-            sh=g["sh"], sw=g["sw"], bh=g["bh"], bw=g["bw"], ep=ep,
-        ),
+        functools.partial(kernel, geom=tap_geom(g), ep=ep),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, g["bh_in"], g["bw_in"], g["c"]), lambda p, j, t: (p, 0, 0, 0)),
-            *wspecs,
-        ],
+        in_specs=[conv_in_spec(xt), *wspecs],
         out_specs=conv_out_spec(g, bf),
         out_shape=jax.ShapeDtypeStruct((n, g["ho"], g["wo"], f), out_dtype),
         scratch_shapes=[pltpu.VMEM((g["bh"] * g["bw"], bf), acc_dtype)],
@@ -183,7 +168,7 @@ def vdbb_im2col_conv_tc(
     tile_h: int | None = None,
     tile_w: int | None = None,
     out_dtype=None,
-    interpret: bool | None = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Fused sparse conv, group-shared patterns. x: (N, H, W, C);
     values: (nb, nnz, F); indices: (nb, nnz) with nb = kh·kw·C/bz.
@@ -196,15 +181,16 @@ def vdbb_im2col_conv_tc(
     bf, tile_h, tile_w = _tuned_conv_defaults(
         core.KIND_CONV_TC, x, fmt, kh, kw, f, stride, padding, bf, tile_h, tile_w
     )
-    bf = core.resolve_or_pick(f, bf, 128, "bf")
+    bf = core.resolve_or_pick(f, bf, 128, "bf", align=core.LANES)
     v = values.reshape(kh * kw, cb * nnz, f)
-    idx = indices.astype(jnp.int32).reshape(kh * kw, cb, nnz)
+    # per tap, the channel of the (·, C) patch each compressed column reads
+    pos = core.mux_positions(indices, cb, fmt.bz).reshape(kh * kw, cb * nnz, 1)
     wspecs = [
-        pl.BlockSpec((1, cb * nnz, bf), lambda p, j, t: (t, 0, j)),
-        pl.BlockSpec((1, cb, nnz), lambda p, j, t: (t, 0, 0)),
+        pl.BlockSpec((kh * kw, cb * nnz, bf), lambda p, j: (0, 0, j)),
+        pl.BlockSpec((kh * kw, cb * nnz, 1), lambda p, j: (0, 0, 0)),
     ]
     return _launch(
-        _vdbb_conv_tc_kernel, x, (v, idx), wspecs, fmt, kh, kw,
+        _vdbb_conv_tc_kernel, x, (v, pos), wspecs, kh, kw,
         stride=stride, padding=padding, bf=bf, tile_h=tile_h, tile_w=tile_w,
         out_dtype=out_dtype, interpret=interpret, scales=scales, bias=bias,
         relu=relu, out_scale=out_scale,
@@ -229,7 +215,7 @@ def vdbb_im2col_conv_bw(
     tile_h: int | None = None,
     tile_w: int | None = None,
     out_dtype=None,
-    interpret: bool | None = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Fused sparse conv, per-column patterns. values/indices: (nb, nnz, F).
     int8 + epilogue as in :func:`vdbb_im2col_conv_tc`."""
@@ -239,15 +225,14 @@ def vdbb_im2col_conv_bw(
     bf, tile_h, tile_w = _tuned_conv_defaults(
         core.KIND_CONV_BW, x, fmt, kh, kw, f, stride, padding, bf, tile_h, tile_w
     )
-    bf = core.resolve_or_pick(f, bf, 128, "bf")
-    v = values.reshape(kh * kw, cb * nnz, f)
-    idx = indices.astype(jnp.int32).reshape(kh * kw, cb * nnz, f)
-    wspecs = [
-        pl.BlockSpec((1, cb * nnz, bf), lambda p, j, t: (t, 0, j)),
-        pl.BlockSpec((1, cb * nnz, bf), lambda p, j, t: (t, 0, j)),
-    ]
+    bf = core.resolve_or_pick(f, bf, 128, "bf", align=core.LANES)
+    # (kh·kw, nnz, cb, F): per tap, slot-major blocks (dbb_expand_block)
+    v = values.reshape(kh * kw, cb, nnz, f).transpose(0, 2, 1, 3)
+    idx = indices.astype(jnp.int32).reshape(kh * kw, cb, nnz, f).transpose(0, 2, 1, 3)
+    spec = pl.BlockSpec((kh * kw, nnz, cb, bf), lambda p, j: (0, 0, 0, j))
     return _launch(
-        _vdbb_conv_bw_kernel, x, (v, idx), wspecs, fmt, kh, kw,
+        functools.partial(_vdbb_conv_bw_kernel, bz=fmt.bz), x, (v, idx),
+        [spec, spec], kh, kw,
         stride=stride, padding=padding, bf=bf, tile_h=tile_h, tile_w=tile_w,
         out_dtype=out_dtype, interpret=interpret, scales=scales, bias=bias,
         relu=relu, out_scale=out_scale,

@@ -31,9 +31,16 @@ epilogue the raw int32 accumulator is returned.
 
 Tiling taxonomy (paper's A×B×C_M×N → BlockSpec): bm×bn is the TPE array
 footprint (output tile), bz=B is the block size, kb is how many blocks
-stream per grid step. MXU alignment wants bm, bn multiples of 128 and
-kb·nnz (tc) / kb·bz (bw) multiples of the lane width on real hardware;
-interpret mode (CPU validation) accepts any shapes.
+stream per grid step. Interpret mode (CPU validation) accepts any shapes;
+the TPU compiler enforces, per block, a last dim that is a multiple of
+128 or the whole array dim, and a second-to-last dim that is a multiple
+of 8 or the whole dim. Hence the defaults: bn a multiple of 128 (the ops
+layer pads N=1000 to 1024 rather than take bn=250), bm a multiple of 8
+(32 for int8) or all of M, and kb·bz a multiple of 128 (kb=16 at bz=8)
+or all of K. Inside the body the compiler refuses a reshape that splits
+the lane axis, so the tc mux never forms a (bm, kb, bz) view of the A
+tile: it is one 2-D selection matmul (``core.dbb_mux``), and the bw
+expand is 2-D too.
 """
 from __future__ import annotations
 
@@ -57,34 +64,43 @@ def _check_compressed_operands(a, values, fmt):
     return m, k, nb, n
 
 
+def _resolve_tiles(kind, a, m, k, n, fmt, bm, bn, kb):
+    """``(bm, bn, kb)`` of one launch: explicit tiles must divide exactly;
+    when all are None, a tuned registry entry that divides, else the
+    aligned defaults — bm a sublane multiple for the operand dtype, bn a
+    lane multiple, kb·bz a lane multiple — or the whole dimension when no
+    aligned divisor exists (a full-extent block is always legal)."""
+    tuned = {}
+    if bm is None and bn is None and kb is None:
+        tuned = core.lookup_tiles(
+            kind, core.matmul_sig(m, k, n, fmt.bz, fmt.nnz, a.dtype)) or {}
+    nb = k // fmt.bz
+    kb_align = core.default_kb(nb, fmt.bz)
+    return (
+        core.resolve_or_pick(m, bm, 128, "bm", align=core.sublanes(a.dtype),
+                             tuned=tuned.get("bm")),
+        core.resolve_or_pick(n, bn, 256, "bn", align=core.LANES,
+                             tuned=tuned.get("bn")),
+        core.resolve_or_pick(nb, kb, kb_align, "kb", align=kb_align,
+                             tuned=tuned.get("kb")),
+    )
+
+
 # ---------------------------------------------------------------------------
 # tc mode: gather-compressed-K (group-shared pattern)
 # ---------------------------------------------------------------------------
 
 
-def _vdbb_tc_kernel(a_ref, v_ref, idx_ref, *rest, bz, nnz, kb, ep=None):
+def _vdbb_tc_kernel(a_ref, v_ref, pos_ref, *rest, ep=None):
     """Grid: (M/bm, N/bn, NB/kb). a: (bm, kb*bz); v: (kb*nnz, bn);
-    idx: (kb, nnz) int32; acc: (bm, bn) f32/i32 VMEM scratch; ``rest``
-    carries the optional (1, bn) fp32 epilogue rows named by the static
-    ``ep`` (scale/bias/out_scale — DESIGN.md §9)."""
+    pos: (kb*nnz, 1) int32 — the A-tile column each compressed-K column
+    reads (``core.mux_positions``); acc: (bm, bn) f32/i32 VMEM scratch;
+    ``rest`` carries the optional (1, bn) fp32 epilogue rows named by the
+    static ``ep`` (scale/bias/out_scale — DESIGN.md §9)."""
     flush, o_ref, acc_ref = core.split_epilogue(ep, rest)
-    bm = a_ref.shape[0]
-    pref = core.acc_dtype_for(a_ref.dtype)  # int32 for int8 operands
-    a = a_ref[...].reshape(bm, kb, bz)
-    idx = idx_ref[...]  # (kb, nnz)
-    # The activation mux: one-hot gather A[:, k, idx[k, j]] -> (bm, kb, nnz).
-    onehot = jax.nn.one_hot(idx, bz, dtype=a.dtype)  # (kb, nnz, bz)
-    ac = jax.lax.dot_general(
-        a,
-        onehot,
-        dimension_numbers=(((2,), (2,)), ((1,), (0,))),
-        preferred_element_type=pref,
-    )  # (kb, bm, nnz)
-    # exact cast back: gathered values are the original int8/float operands
-    ac = ac.transpose(1, 0, 2).reshape(bm, kb * nnz).astype(a.dtype)
-    contrib = jax.lax.dot(
-        ac, v_ref[...].astype(a.dtype), preferred_element_type=pref
-    )
+    # The activation mux: A[:, pos[j]] -> the (bm, kb*nnz) compressed-K tile.
+    ac = core.dbb_mux(a_ref[...], pos_ref[...])
+    contrib = core.mxu_dot(ac, v_ref[...])
     core.os_accumulate(acc_ref, o_ref, contrib, grid_axis=2, **flush)
 
 
@@ -102,34 +118,28 @@ def vdbb_matmul_tc(
     bn: int | None = None,
     kb: int | None = None,
     out_dtype=None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """A (M, K) × compressed W -> (M, N). values: (nb, nnz, N);
     indices: (nb, nnz) int (pattern shared across N). int8 operands
     accumulate in exact int32; ``scales`` (N,) / ``bias`` (N,) / ``relu``
     / ``out_scale`` (scalar or (N,)) fuse the layer epilogue into the
     accumulator flush (DESIGN.md §9; out int8 when requantizing). Default
-    tiles fall back to the largest dividing size (``core.pick_tile``)."""
+    tiles are aligned divisors (``core.aligned_divisor``); explicit ones
+    must divide exactly."""
     m, k, nb, n = _check_compressed_operands(a, values, fmt)
     bz, nnz = fmt.bz, fmt.nnz
-    tuned = {}
-    if bm is None and bn is None and kb is None:
-        tuned = core.lookup_tiles(
-            core.KIND_MATMUL_TC, core.matmul_sig(m, k, n, bz, nnz, a.dtype)
-        ) or {}
-    bm = core.resolve_or_pick(m, bm, 128, "bm", tuned=tuned.get("bm"))
-    bn = core.resolve_or_pick(n, bn, 256, "bn", tuned=tuned.get("bn"))
-    kb = core.resolve_or_pick(nb, kb, 16, "kb", tuned=tuned.get("kb"))
+    bm, bn, kb = _resolve_tiles(core.KIND_MATMUL_TC, a, m, k, n, fmt, bm, bn, kb)
     v2 = values.reshape(nb * nnz, n)
-    idx = indices.astype(jnp.int32)
+    pos = core.mux_positions(indices, kb, bz)
     acc_dtype = core.acc_dtype_for(a.dtype)
     ep, e_ops, e_specs, out_dtype = core.epilogue_plan(
         n, bn, scales=scales, bias=bias, relu=relu, out_scale=out_scale,
         acc_dtype=acc_dtype, in_dtype=a.dtype, out_dtype=out_dtype,
     )
     return core.os_matmul_call(
-        functools.partial(_vdbb_tc_kernel, bz=bz, nnz=nnz, kb=kb, ep=ep),
-        (a, v2, idx, *e_ops),
+        functools.partial(_vdbb_tc_kernel, ep=ep),
+        (a, v2, pos, *e_ops),
         m=m,
         n=n,
         bm=bm,
@@ -138,7 +148,7 @@ def vdbb_matmul_tc(
         in_specs=[
             pl.BlockSpec((bm, kb * bz), lambda i, j, s: (i, s)),
             pl.BlockSpec((kb * nnz, bn), lambda i, j, s: (s, j)),
-            pl.BlockSpec((kb, nnz), lambda i, j, s: (s, 0)),
+            pl.BlockSpec((kb * nnz, 1), lambda i, j, s: (s, 0)),
             *e_specs,
         ],
         out_dtype=out_dtype,
@@ -153,33 +163,41 @@ def vdbb_matmul_tc(
 
 
 def dbb_expand_block(v, idx, bz):
-    """In-VMEM scatter-expand of a compressed (kb, nnz, bn) block to dense
-    (kb*bz, bn) — the "late mux" right before the MAC:
-    wd[k, i, n] = sum_j [idx[k, j, n] == i] * v[k, j, n].
+    """In-VMEM scatter-expand of a compressed block to dense (kb*bz, bn)
+    — the "late mux" right before the MAC:
+    wd[k*bz + i, n] = sum_j [idx[j, k, n] == i] * v[j, k, n].
 
-    Dtype-preserving (int8 stays int8: positions within a block-column are
-    distinct, so each output element receives at most one non-zero)."""
-    kb, nnz, bn = v.shape
-    i_iota = jax.lax.broadcasted_iota(jnp.int32, (kb, bz, nnz, bn), 1)
-    sel = (idx[:, None, :, :] == i_iota).astype(v.dtype)
-    wd = (sel * v[:, None, :, :]).sum(axis=2).astype(v.dtype)  # (kb, bz, bn)
-    return wd.reshape(kb * bz, bn)
+    ``v``/``idx``: (nnz, kb, bn) (slot-major, as the wrappers lay them
+    out). Each slot's (kb, bn) row block is repeated bz times down the
+    sublanes by a one-hot (kb*bz, kb) matmul — exact, and 2-D throughout,
+    so no lane or sublane axis is split. Dtype-preserving (positions
+    within a block-column are distinct, so each output element receives
+    at most one non-zero)."""
+    nnz, kb, bn = v.shape
+    rows = kb * bz
+    r = jax.lax.broadcasted_iota(jnp.int32, (rows, kb), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (rows, kb), 1)
+    rep = (r // bz == c).astype(jnp.float32)
+    slot = (jax.lax.broadcasted_iota(jnp.int32, (rows, bn), 0) % bz).astype(jnp.float32)
+
+    def repeat(x):
+        return jax.lax.dot(rep, x.astype(jnp.float32),
+                           precision=jax.lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
+
+    wd = jnp.zeros((rows, bn), jnp.float32)
+    for j in range(nnz):
+        wd = wd + jnp.where(repeat(idx[j]) == slot, repeat(v[j]), 0.0)
+    return wd.astype(v.dtype)
 
 
-def _vdbb_bw_kernel(a_ref, v_ref, idx_ref, *rest, bz, nnz, kb, ep=None):
-    """Grid: (M/bm, N/bn, NB/kb). a: (bm, kb*bz); v: (kb*nnz, bn);
-    idx: (kb*nnz, bn) int32 — per-column patterns; ``rest`` carries the
-    optional (1, bn) fp32 epilogue rows named by ``ep`` (DESIGN.md §9)."""
+def _vdbb_bw_kernel(a_ref, v_ref, idx_ref, *rest, bz, ep=None):
+    """Grid: (M/bm, N/bn, NB/kb). a: (bm, kb*bz); v/idx: (nnz, kb, bn)
+    (idx int32) — per-column patterns; ``rest`` carries the optional
+    (1, bn) fp32 epilogue rows named by ``ep`` (DESIGN.md §9)."""
     flush, o_ref, acc_ref = core.split_epilogue(ep, rest)
-    bn = o_ref.shape[1]
-    v = v_ref[...].reshape(kb, nnz, bn)
-    idx = idx_ref[...].reshape(kb, nnz, bn)
-    wd = dbb_expand_block(v, idx, bz)
-    contrib = jax.lax.dot(
-        a_ref[...],
-        wd.astype(a_ref.dtype),
-        preferred_element_type=core.acc_dtype_for(a_ref.dtype),
-    )
+    wd = dbb_expand_block(v_ref[...], idx_ref[...], bz)
+    contrib = core.mxu_dot(a_ref[...], wd)
     core.os_accumulate(acc_ref, o_ref, contrib, grid_axis=2, **flush)
 
 
@@ -197,31 +215,24 @@ def vdbb_matmul_bw(
     bn: int | None = None,
     kb: int | None = None,
     out_dtype=None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """A (M, K) × compressed W -> (M, N). values/indices: (nb, nnz, N).
     int8 + epilogue (``scales``/``bias``/``relu``/``out_scale``) as in
     :func:`vdbb_matmul_tc`."""
     m, k, nb, n = _check_compressed_operands(a, values, fmt)
     bz, nnz = fmt.bz, fmt.nnz
-    tuned = {}
-    if bm is None and bn is None and kb is None:
-        tuned = core.lookup_tiles(
-            core.KIND_MATMUL_BW, core.matmul_sig(m, k, n, bz, nnz, a.dtype)
-        ) or {}
-    bm = core.resolve_or_pick(m, bm, 128, "bm", tuned=tuned.get("bm"))
-    bn = core.resolve_or_pick(n, bn, 256, "bn", tuned=tuned.get("bn"))
-    kb = core.resolve_or_pick(nb, kb, 8, "kb", tuned=tuned.get("kb"))
-    v2 = values.reshape(nb * nnz, n)
-    idx2 = indices.astype(jnp.int32).reshape(nb * nnz, n)
+    bm, bn, kb = _resolve_tiles(core.KIND_MATMUL_BW, a, m, k, n, fmt, bm, bn, kb)
+    v3 = values.transpose(1, 0, 2)  # (nnz, nb, N): slot-major
+    idx3 = indices.astype(jnp.int32).transpose(1, 0, 2)
     acc_dtype = core.acc_dtype_for(a.dtype)
     ep, e_ops, e_specs, out_dtype = core.epilogue_plan(
         n, bn, scales=scales, bias=bias, relu=relu, out_scale=out_scale,
         acc_dtype=acc_dtype, in_dtype=a.dtype, out_dtype=out_dtype,
     )
     return core.os_matmul_call(
-        functools.partial(_vdbb_bw_kernel, bz=bz, nnz=nnz, kb=kb, ep=ep),
-        (a, v2, idx2, *e_ops),
+        functools.partial(_vdbb_bw_kernel, bz=bz, ep=ep),
+        (a, v3, idx3, *e_ops),
         m=m,
         n=n,
         bm=bm,
@@ -229,8 +240,8 @@ def vdbb_matmul_bw(
         k_steps=nb // kb,
         in_specs=[
             pl.BlockSpec((bm, kb * bz), lambda i, j, s: (i, s)),
-            pl.BlockSpec((kb * nnz, bn), lambda i, j, s: (s, j)),
-            pl.BlockSpec((kb * nnz, bn), lambda i, j, s: (s, j)),
+            pl.BlockSpec((nnz, kb, bn), lambda i, j, s: (0, s, j)),
+            pl.BlockSpec((nnz, kb, bn), lambda i, j, s: (0, s, j)),
             *e_specs,
         ],
         out_dtype=out_dtype,

@@ -8,17 +8,26 @@ slower inter-pod links — DCN-friendly).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: the partitioner places
+    shardings, and ``with_sharding_constraint`` may name the axes (jax ≥
+    0.9 defaults new meshes to ``Explicit`` axes, which refuse both)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for CPU integration tests (requires host-device override)."""
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def tp_degree(mesh) -> int:

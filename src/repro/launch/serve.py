@@ -27,7 +27,9 @@ at ~50% of measured capacity). The server runs under the §15
 checkpoint every N requests — an atomic plan swap mid-traffic. Reports
 p50/p99 latency, sustained throughput, aggregation shape, supervisor
 state (restarts / requeued / reloads / demoted buckets / health), and
-the zero-retrace check:
+the zero-retrace check. It exits 1 when a request failed or expired, a
+bucket was demoted, or health is not ``ready`` (shed requests are
+reported, not failures):
 
   PYTHONPATH=src python -m repro.launch.serve --arch sparse-cnn-tiny --smoke \
       --server --max-batch 8 --max-wait-ms 5 --requests 64 --reload-every 24
@@ -251,6 +253,15 @@ def serve_cnn_continuous(args, model, qparams, xpool):
           f"client timeout {timeout_s:.1f}s (derived)  "
           f"retraces after warmup: {sup.retraces_after_warmup}  "
           f"health: {health['status']}")
+    problems = [f"{v} request(s) {k}" for k, v in sorted(failures.items())
+                if k != "Overloaded"]  # a shed is admission, not a failure
+    if demoted:
+        problems.append(f"buckets demoted {sorted(demoted)}")
+    if health["status"] != "ready":
+        problems.append(f"health {health['status']}")
+    if problems:
+        print(f"[serve] FAILED: {'; '.join(problems)}")
+        raise SystemExit(1)
     return results
 
 
@@ -292,6 +303,9 @@ def serve_lm_plan(args):
 
 
 def main(argv=None):
+    from repro.xla_utils import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -306,7 +320,7 @@ def main(argv=None):
                     help="CNN: serve through a frozen plan (--no-plan = per-call path)")
     ap.add_argument("--tune", choices=("off", "cache", "search"), default="cache",
                     help="CNN plan tile resolution: autotune cache hits only "
-                         "(default), full search, or pick_tile defaults")
+                         "(default), full search, or the default tiles")
     ap.add_argument("--lm-plan", action="store_true",
                     help="LM: serve prefill through a frozen ModelPlan "
                          "(DESIGN §13) instead of the decode loop")

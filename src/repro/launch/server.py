@@ -351,10 +351,11 @@ class CNNServer:
     >>> srv.stats.summary()["p99_us"], srv.retraces_after_warmup  # -> ..., 0
 
     ``mesh=`` turns on data-parallel dispatch: padded buckets are placed
-    with the ``cnn_serve_rules`` batch-axis ``NamedSharding`` before the
-    plan runs (``multi_pod=`` selects the ('pod','data') axes). Build
-    the plan set with ``dp=mesh data size`` so every bucket shards
-    evenly.
+    with the ``cnn_serve_rules`` batch-axis ``NamedSharding`` and each
+    bucket's plan runs under ``shard_map`` on that axis (``PlanSet.shard``),
+    so every device serves its own rows (``multi_pod=`` selects the
+    ('pod','data') axes). Build the plan set with ``dp=mesh data size``
+    so every bucket shards evenly.
 
     Robustness knobs (DESIGN.md §14): ``max_queue`` bounds admitted
     in-system samples (None = unbounded), ``shed`` picks the overload
@@ -414,6 +415,7 @@ class CNNServer:
         self.on_crash = on_crash
         self._inflight: dict = {}    # id(p) -> p, dispatcher thread only
         self._put = None
+        self._shard = None
         if mesh is not None:
             from jax.sharding import NamedSharding
 
@@ -422,6 +424,8 @@ class CNNServer:
             spec = data_pspec(cnn_serve_rules(multi_pod=multi_pod))
             sharding = NamedSharding(mesh, spec)
             self._put = lambda xb: jax.device_put(xb, sharding)
+            self._shard = (mesh, spec)
+            self.plan_set = self.for_mesh(plan_set)
         self._batcher = MicroBatcher(self.max_batch, self.max_wait_s)
         self._q: _queue.Queue = _queue.Queue()
         self._thread: Optional[threading.Thread] = None
@@ -434,6 +438,14 @@ class CNNServer:
         self._depth = 0                 # admitted samples not yet resolved
         self._bucket_time_s: Optional[float] = None  # EMA of serve time
         self._ran = False
+
+    def for_mesh(self, plan_set):
+        """The set this server dispatches for ``plan_set``: sharded over
+        the mesh's batch axes (``PlanSet.shard``) when the server has a
+        mesh and the set is not sharded yet, else ``plan_set`` itself."""
+        if self._shard is None or plan_set.sharded:
+            return plan_set
+        return plan_set.shard(*self._shard)
 
     # ------------------------------------------------------- lifecycle
     def start(self, *, fresh_stats: bool = True) -> "CNNServer":
@@ -654,6 +666,7 @@ class CNNServer:
             raise ValueError(
                 f"swap sample spec {new_set.sample_spec} != admission "
                 f"contract {self.plan_set.sample_spec}")
+        new_set = self.for_mesh(new_set)
         with self._lock:
             self.plan_set = new_set
             self.stats.warmup_traces = new_set.trace_count

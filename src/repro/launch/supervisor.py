@@ -254,7 +254,7 @@ class Supervisor:
         try:
             tree, manifest = restore(ckpt_dir, self._template, step=step,
                                      fallback=fallback)
-            new_set = self._rebuild(tree)
+            new_set = self._srv.for_mesh(self._rebuild(tree))
             if (old.sample_spec is not None
                     and new_set.sample_spec != old.sample_spec):
                 raise ValueError(

@@ -254,7 +254,7 @@ class SparseCNN:
 
         Resolves every layer's tuned tile config (autotune registry →
         persistent cache → search when ``tune='search'``; ``'cache'``
-        never searches, ``'off'`` keeps pick_tile defaults), stages each
+        never searches, ``'off'`` keeps the default tiles), stages each
         layer's serving closure with its weight buffers frozen in —
         replicating exactly the path :meth:`apply` takes for these params,
         including the §9 int8-resident chain when calibrated quantized

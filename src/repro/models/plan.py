@@ -93,6 +93,9 @@ class ModelPlan:
     # instead of poisoning a co-batch. None for plans built before the
     # spec was known (validation is then skipped).
     sample_spec: Optional[Tuple[Tuple[int, ...], str]] = None
+    # (mesh, PartitionSpec of the batch axis) for data-parallel serving:
+    # the chain then runs per device on its own rows (see PlanSet.shard)
+    shard: Optional[Tuple[Any, Any]] = None
 
     def __post_init__(self):
         stages = tuple(l.run for l in self.layers)
@@ -104,12 +107,23 @@ class ModelPlan:
                 x = run(x)
             return x
 
-        object.__setattr__(self, "_serve", jax.jit(chain))
+        fn = chain
+        if self.shard is not None:
+            mesh, spec = self.shard
+            fn = jax.shard_map(chain, mesh=mesh, in_specs=spec,
+                               out_specs=spec, check_vma=False)
+        object.__setattr__(self, "_serve", jax.jit(fn))
         object.__setattr__(self, "_traces", traces)
 
     def serve(self, x):
         """Steady-state serving: one dispatch, no checks, no params."""
         return self._serve(x)
+
+    def lower(self, x):
+        """``jax.jit(...).lower`` of the staged chain at ``x``'s shape:
+        ``.compile()`` it to read the program the device runs (e.g. its
+        Pallas ``tpu_custom_call`` ops). Counts as a trace."""
+        return self._serve.lower(x)
 
     @property
     def trace_count(self) -> int:
@@ -247,6 +261,21 @@ class PlanSet:
 
     def __call__(self, x):
         return self.serve(x)
+
+    def shard(self, mesh, spec) -> "PlanSet":
+        """This ladder served data-parallel on ``mesh``: every bucket's
+        chain runs under ``shard_map`` with its batch axis split per
+        ``spec``, so each device serves its own rows. XLA's partitioner
+        cannot split a Pallas kernel — left to it, every device would run
+        the whole batch. Each bucket must be a multiple of the data-axis
+        size (``make_buckets(dp=)``)."""
+        plans = {b: dataclasses.replace(p, shard=(mesh, spec))
+                 for b, p in self.plans.items()}
+        return dataclasses.replace(self, plans=plans)
+
+    @property
+    def sharded(self) -> bool:
+        return any(p.shard is not None for p in self.plans.values())
 
     def warmup(self, sample_shape: Optional[Tuple[int, ...]] = None,
                dtype=jnp.float32, *, put=None) -> int:

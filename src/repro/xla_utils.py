@@ -11,11 +11,35 @@ noisy CPU showed medians of 7 swinging ±70% between batches while mins
 of 30 stayed within ±3%). Comparisons between two programs should
 additionally be **interleaved** (A, B, A, B, …) so environment drift
 cancels out of the ratio: :func:`interleaved_time_us`.
+
+:func:`use_compile_cache` places JAX's persistent compilation cache.
 """
 from __future__ import annotations
 
+import os
+import pathlib
 import statistics
 import time
+
+# <checkout>/.cache/jax — fixed, so a later run in the same checkout finds
+# the programs an earlier one compiled (the path is part of the cache key)
+CHECKOUT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[2] / ".cache" / "jax"
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here. Otherwise the cache goes to the fixed in-checkout
+    :data:`CHECKOUT_CACHE_DIR` (listed in ``.gitignore``). Call it before
+    the first compile."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", str(CHECKOUT_CACHE_DIR))
+    return str(CHECKOUT_CACHE_DIR)
 
 _STATS = ("median", "min", "p25", "mean")
 
@@ -114,13 +138,9 @@ def noise_frac(samples) -> float:
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """Normalize ``Compiled.cost_analysis()`` across jax versions: newer jax
-    returns the per-module properties dict directly, older versions (e.g.
-    0.4.x) wrap it in a 1-element list."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    """The compiled program's cost-analysis properties (flops, bytes
+    accessed, ...) as a dict."""
+    return compiled.cost_analysis()
 
 
 def hlo_op_breakdown(fn, *args) -> dict:

@@ -134,10 +134,14 @@ class TestTuneCache:
 
 class TestPadToTile:
     def test_pick_tile_padded(self):
-        assert core.pick_tile_padded(200, 128) == (100, 200)  # good divisor
-        assert core.pick_tile_padded(96, 128) == (96, 96)     # whole dim
+        assert core.pick_tile_padded(200, 128, 4) == (100, 200)  # good divisor
+        # no multiple of 8 in [64, 128] divides 200: pad to an aligned tile
+        assert core.pick_tile_padded(200, 128, 8) == (128, 256)
+        assert core.pick_tile_padded(96, 128, 8) == (96, 96)     # whole dim
         # 2·prime beyond 2x the default: pad instead of one huge tile
-        assert core.pick_tile_padded(514, 128) == (128, 640)
+        assert core.pick_tile_padded(514, 128, 8) == (128, 640)
+        # the 1000-class head: N pads to 1024, never bn=250 (no lane multiple)
+        assert core.pick_tile_padded(1000, 256, 128) == (256, 1024)
 
     def test_pad_tile_explicit(self):
         assert core.pad_tile(130, 64, 128) == (64, 192)  # non-divisor pads

@@ -50,7 +50,8 @@ from repro.optim.adamw import OptConfig, init_state
 from repro.sharding.rules import make_rules
 from repro.train.step import make_serve_step, make_train_step
 assert len(jax.devices()) == 8
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 """
 
 

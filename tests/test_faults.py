@@ -296,3 +296,35 @@ def test_stop_timeout_abandons_drain(served):
     assert outcomes["cancelled"] > 0 and outcomes["done"] > 0
     assert sum(outcomes.values()) == 8
     srv.stats.assert_accounting()
+
+
+# ------------------------------------------------ the serve CLI exit code
+@pytest.mark.parametrize("poison", [False, True], ids=["clean", "poisoned"])
+def test_serve_cli_exit_code(monkeypatch, tmp_path, poison):
+    """`python -m repro.launch.serve --server` exits 1 when a request
+    fails (here: one poisoned request, injected at the server's fault
+    seam), and returns normally (exit 0) when every request is answered."""
+    import functools
+
+    from repro.launch import serve, server
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    inj = FaultInjector()
+    if poison:  # serve.py's request pool: PRNGKey(1) normals, --batch rows
+        cfg = smoke_cnn_config("sparse-cnn-tiny")
+        pool = np.asarray(jax.random.normal(
+            jax.random.PRNGKey(1),
+            (4, cfg.image_size, cfg.image_size, cfg.in_channels)))
+        inj.poison(pool[0][None])
+    monkeypatch.setattr(server, "CNNServer",
+                        functools.partial(server.CNNServer, faults=inj))
+    argv = ["--arch", "sparse-cnn-tiny", "--smoke", "--server", "--batch", "4",
+            "--max-batch", "4", "--requests", "8", "--rate", "400",
+            "--tune", "off"]
+    if poison:
+        with pytest.raises(SystemExit) as e:
+            serve.main(argv)
+        assert e.value.code == 1
+    else:
+        results = serve.main(argv)
+        assert len(results) == 8 and all(r is not None for r in results)

@@ -306,7 +306,7 @@ class TestRaggedShapes:
         # dividing: no padding, requested tile honored
         assert core.pad_tile(64, 32, 128) == (32, 64)
         assert core.pad_tile(64, None, 128) == (64, 64)
-        assert core.pick_tile_padded(128, 128) == (128, 128)
+        assert core.pick_tile_padded(128, 128, 128) == (128, 128)
         # ragged: padded up to the next tile multiple
         assert core.pad_tile(67, 16, 128) == (16, 80)
         # oversized explicit tile clamps to the dimension

@@ -233,3 +233,29 @@ class TestTrainingIntegration:
         t._preempted = True  # simulate SIGTERM delivery
         t.run(params, opt_state, 0)
         assert store.latest_step(tmp_path) is not None  # flushed before exit
+
+
+# ------------------------------------------------ persistent compile cache
+@pytest.mark.parametrize("env_set", [True, False], ids=["env", "checkout"])
+def test_use_compile_cache(monkeypatch, tmp_path, env_set):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; without
+    it the cache goes to the fixed in-checkout directory."""
+    from repro import xla_utils
+
+    before = jax.config.jax_compilation_cache_dir
+    if env_set:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = xla_utils.use_compile_cache()
+        if env_set:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            repo = pathlib.Path(__file__).resolve().parents[1]
+            assert got == str(repo / ".cache" / "jax")
+            assert jax.config.jax_compilation_cache_dir == got
+            assert ".cache/" in (repo / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -549,10 +549,12 @@ def os_matmul_call(
     k_steps: int,
     in_specs: Sequence[pl.BlockSpec],
     out_dtype,
+    name: str,
     acc_dtype=jnp.float32,
     interpret: bool | None = None,
 ):
-    """Launch an output-stationary (M, N) matmul-shaped kernel.
+    """Launch an output-stationary (M, N) matmul-shaped kernel named
+    ``name`` (the kernel's name in a device profile).
 
     Builds the K-innermost grid ``(m//bm, n//bn, k_steps)``, the ``(bm, bn)``
     output BlockSpec and the VMEM accumulator scratch (fp32, or int32 for
@@ -570,4 +572,5 @@ def os_matmul_call(
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
         interpret=resolve_interpret(interpret),
+        name=name,
     )(*operands)

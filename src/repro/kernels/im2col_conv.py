@@ -158,4 +158,5 @@ def im2col_conv(
         out_shape=jax.ShapeDtypeStruct((n, g["ho"], g["wo"], f), out_dtype),
         scratch_shapes=[pltpu.VMEM((g["bh"] * g["bw"], bf), acc_dtype)],
         interpret=core.resolve_interpret(interpret),
+        name="im2col_conv",
     )(xt, w3, *e_ops)

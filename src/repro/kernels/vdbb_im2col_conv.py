@@ -124,8 +124,8 @@ def _tuned_conv_defaults(kind, x, fmt, kh, kw, f, stride, padding,
     return core.tuned_conv_tiles(kind, sig, ho, wo, f)
 
 
-def _launch(kernel, x, operands, wspecs, kh, kw, *, stride, padding, bf,
-            tile_h, tile_w, out_dtype, interpret, scales=None, bias=None,
+def _launch(kernel, name, x, operands, wspecs, kh, kw, *, stride, padding,
+            bf, tile_h, tile_w, out_dtype, interpret, scales=None, bias=None,
             relu=False, out_scale=None):
     n = x.shape[0]
     f = operands[0].shape[-1]
@@ -147,6 +147,7 @@ def _launch(kernel, x, operands, wspecs, kh, kw, *, stride, padding, bf,
         out_shape=jax.ShapeDtypeStruct((n, g["ho"], g["wo"], f), out_dtype),
         scratch_shapes=[pltpu.VMEM((g["bh"] * g["bw"], bf), acc_dtype)],
         interpret=core.resolve_interpret(interpret),
+        name=name,
     )(xt, *operands)
 
 
@@ -190,7 +191,7 @@ def vdbb_im2col_conv_tc(
         pl.BlockSpec((kh * kw, cb * nnz, 1), lambda p, j: (0, 0, 0)),
     ]
     return _launch(
-        _vdbb_conv_tc_kernel, x, (v, pos), wspecs, kh, kw,
+        _vdbb_conv_tc_kernel, "vdbb_im2col_conv_tc", x, (v, pos), wspecs, kh, kw,
         stride=stride, padding=padding, bf=bf, tile_h=tile_h, tile_w=tile_w,
         out_dtype=out_dtype, interpret=interpret, scales=scales, bias=bias,
         relu=relu, out_scale=out_scale,
@@ -231,7 +232,8 @@ def vdbb_im2col_conv_bw(
     idx = indices.astype(jnp.int32).reshape(kh * kw, cb, nnz, f).transpose(0, 2, 1, 3)
     spec = pl.BlockSpec((kh * kw, nnz, cb, bf), lambda p, j: (0, 0, 0, j))
     return _launch(
-        functools.partial(_vdbb_conv_bw_kernel, bz=fmt.bz), x, (v, idx),
+        functools.partial(_vdbb_conv_bw_kernel, bz=fmt.bz), "vdbb_im2col_conv_bw",
+        x, (v, idx),
         [spec, spec], kh, kw,
         stride=stride, padding=padding, bf=bf, tile_h=tile_h, tile_w=tile_w,
         out_dtype=out_dtype, interpret=interpret, scales=scales, bias=bias,

@@ -152,6 +152,7 @@ def vdbb_matmul_tc(
             *e_specs,
         ],
         out_dtype=out_dtype,
+        name="vdbb_matmul_tc",
         acc_dtype=acc_dtype,
         interpret=interpret,
     )
@@ -245,6 +246,7 @@ def vdbb_matmul_bw(
             *e_specs,
         ],
         out_dtype=out_dtype,
+        name="vdbb_matmul_bw",
         acc_dtype=acc_dtype,
         interpret=interpret,
     )

@@ -253,6 +253,11 @@ def serve_cnn_continuous(args, model, qparams, xpool):
           f"client timeout {timeout_s:.1f}s (derived)  "
           f"retraces after warmup: {sup.retraces_after_warmup}  "
           f"health: {health['status']}")
+    waited = s["queue_wait_s"] / max(s["dispatched_requests"], 1)
+    print(f"[serve] host: queue wait {waited * 1e3:.2f}ms per request "
+          f"({s['dispatched_requests']} dispatched)  warm-up "
+          f"{s['warmup_s']:.2f}s  gc {s['gc_collections']} passes "
+          f"{s['gc_s'] * 1e3:.1f}ms")
     problems = [f"{v} request(s) {k}" for k, v in sorted(failures.items())
                 if k != "Overloaded"]  # a shed is admission, not a failure
     if demoted:

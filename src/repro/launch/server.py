@@ -72,6 +72,7 @@ and ``repro.launch.serve --server`` drive identical traffic shapes.
 from __future__ import annotations
 
 import dataclasses
+import gc
 import queue as _queue
 import threading
 import time
@@ -81,6 +82,7 @@ from typing import Callable, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 # ------------------------------------------------------- typed failures
@@ -286,6 +288,12 @@ class ServerStats:
     reloads: int = 0      # hot plan-set swaps (Supervisor.reload)
     demotions: int = 0    # buckets demoted to the ref fallback path
     promotions: int = 0   # buckets re-promoted by a recovery probe
+    # --- where host time goes (time.monotonic sums, always on)
+    queue_wait_s: float = 0.0   # Σ dispatch start − arrival, per request
+    dispatched_requests: int = 0  # requests that reached a dispatch
+    warmup_s: float = 0.0       # CNNServer.warmup's plan_set.warmup, all buckets
+    gc_collections: int = 0     # garbage-collector passes while running
+    gc_s: float = 0.0           # and the seconds they took
 
     @property
     def accounted(self) -> int:
@@ -305,7 +313,8 @@ class ServerStats:
     def summary(self) -> dict:
         """p50/p99 latency (µs) of completed requests, goodput
         (requests/s over first-arrival → last-completion), shed rate,
-        terminal counters, aggregation shape."""
+        terminal counters, aggregation shape, and the host-time counters
+        (queue wait, warm-up, garbage collection)."""
         lat_us = np.asarray(self.latencies_s, dtype=np.float64) * 1e6
         span = (
             (self.last_done - self.first_arrival)
@@ -321,7 +330,6 @@ class ServerStats:
             "batches": self.batches,
             "p50_us": round(float(np.percentile(lat_us, 50)), 1) if len(lat_us) else None,
             "p99_us": round(float(np.percentile(lat_us, 99)), 1) if len(lat_us) else None,
-            "mean_us": round(float(lat_us.mean()), 1) if len(lat_us) else None,
             "throughput_rps": round(self.completed / span, 2) if span > 0 else None,
             "shed_rate": round(self.rejected / self.submitted, 4)
             if self.submitted else 0.0,
@@ -333,6 +341,11 @@ class ServerStats:
             "reloads": self.reloads,
             "demotions": self.demotions,
             "promotions": self.promotions,
+            "queue_wait_s": self.queue_wait_s,
+            "dispatched_requests": self.dispatched_requests,
+            "warmup_s": self.warmup_s,
+            "gc_collections": self.gc_collections,
+            "gc_s": self.gc_s,
         }
 
 
@@ -438,6 +451,8 @@ class CNNServer:
         self._depth = 0                 # admitted samples not yet resolved
         self._bucket_time_s: Optional[float] = None  # EMA of serve time
         self._ran = False
+        self._gc_span = None  # the host.gc span of the pass under way
+        self._gc_t0 = 0.0
 
     def for_mesh(self, plan_set):
         """The set this server dispatches for ``plan_set``: sharded over
@@ -488,6 +503,7 @@ class CNNServer:
         self._ran = True
         self._abandon.clear()
         self._closed = False
+        gc.callbacks.append(self._on_gc)
         self._thread = threading.Thread(
             target=self._loop, name="cnn-serve-dispatch", daemon=True
         )
@@ -510,6 +526,7 @@ class CNNServer:
             self._abandon.set()  # drain loop cancels the rest and exits
             self._thread.join()
         self._thread = None
+        gc.callbacks.remove(self._on_gc)
 
     def __enter__(self) -> "CNNServer":
         return self.start()
@@ -528,7 +545,9 @@ class CNNServer:
         the plan set's own sample spec."""
         if sample_shape is None and self.plan_set.sample_spec is not None:
             sample_shape, dtype = self.plan_set.sample_spec
+        t0 = time.monotonic()
         self.plan_set.warmup(tuple(sample_shape), dtype, put=self._put)
+        self.stats.warmup_s += time.monotonic() - t0
         cap = self.plan_set.buckets[-1]
         xb = np.zeros((cap,) + tuple(sample_shape), dtype)
         t0 = time.monotonic()
@@ -557,45 +576,46 @@ class CNNServer:
             raise InvalidRequest(
                 f"request must be (n, ...) with n >= 1: {x.shape}")
         n = int(x.shape[0])
-        now = time.monotonic()
-        with self._lock:
-            if self._crashed is not None:
-                raise ServerCrashed(
-                    f"server crashed: {self._crashed!r} (restart with start())")
-            if self._thread is None or self._closed:
-                raise RuntimeError(
-                    "server is not running (use `with CNNServer(...)`)")
-            self.stats.submitted += n  # offered, whatever happens next
-            if self.stats.first_arrival is None:
-                self.stats.first_arrival = now
-        try:
-            if deadline_s is not None and deadline_s <= 0:
-                raise InvalidRequest(f"deadline_s must be > 0: {deadline_s}")
-            if self._validate and self.plan_set.sample_spec is not None:
-                validate_request(x, self.plan_set.sample_spec)
-        except InvalidRequest:
+        with TraceAnnotation("serve.submit", samples=n):
+            now = time.monotonic()
             with self._lock:
-                self.stats.rejected += n  # rejected alone — no co-batch harm
-            raise
-        fut: Future = Future()
-        p = _Pending(x=x, n=n, arrival=now, future=fut,
-                     deadline=None if deadline_s is None else now + deadline_s)
-        with self._lock:
-            if self.max_queue is not None and self._depth + n > self.max_queue:
-                if self.shed == "reject":
-                    self.stats.rejected += n
-                    raise Overloaded(
-                        f"queue full ({self._depth}/{self.max_queue} samples)",
-                        retry_after_s=self._retry_after_locked())
-                while (self._depth + n > self.max_queue
-                       and not self._closed and self._crashed is None):
-                    self._space.wait()
-                if self._closed or self._crashed is not None:
-                    self.stats.rejected += n
-                    raise RuntimeError("server stopped while backpressured")
-            self._depth += n
-            self._q.put(p)  # inside the lock: nothing can trail a crash drain
-        return fut
+                if self._crashed is not None:
+                    raise ServerCrashed(
+                        f"server crashed: {self._crashed!r} (restart with start())")
+                if self._thread is None or self._closed:
+                    raise RuntimeError(
+                        "server is not running (use `with CNNServer(...)`)")
+                self.stats.submitted += n  # offered, whatever happens next
+                if self.stats.first_arrival is None:
+                    self.stats.first_arrival = now
+            try:
+                if deadline_s is not None and deadline_s <= 0:
+                    raise InvalidRequest(f"deadline_s must be > 0: {deadline_s}")
+                if self._validate and self.plan_set.sample_spec is not None:
+                    validate_request(x, self.plan_set.sample_spec)
+            except InvalidRequest:
+                with self._lock:
+                    self.stats.rejected += n  # rejected alone — no co-batch harm
+                raise
+            fut: Future = Future()
+            p = _Pending(x=x, n=n, arrival=now, future=fut,
+                         deadline=None if deadline_s is None else now + deadline_s)
+            with self._lock:
+                if self.max_queue is not None and self._depth + n > self.max_queue:
+                    if self.shed == "reject":
+                        self.stats.rejected += n
+                        raise Overloaded(
+                            f"queue full ({self._depth}/{self.max_queue} samples)",
+                            retry_after_s=self._retry_after_locked())
+                    while (self._depth + n > self.max_queue
+                           and not self._closed and self._crashed is None):
+                        self._space.wait()
+                    if self._closed or self._crashed is not None:
+                        self.stats.rejected += n
+                        raise RuntimeError("server stopped while backpressured")
+                self._depth += n
+                self._q.put(p)  # inside the lock: nothing can trail a crash drain
+            return fut
 
     def serve_batch(self, x):
         """Synchronous bucketed serve (no queue): pad → bucket plan →
@@ -823,7 +843,8 @@ class CNNServer:
             if dl is not None:
                 timeout = max(0.0, dl - time.monotonic())
             try:
-                items = [self._q.get(timeout=timeout)]
+                with TraceAnnotation("serve.wait"):
+                    items = [self._q.get(timeout=timeout)]
             except _queue.Empty:
                 items = []  # max-wait expired with nothing new queued
             # Greedily drain whatever arrived while the last batch was in
@@ -889,60 +910,95 @@ class CNNServer:
             # re-execution could double side effects / double-serve).
             for p in live:
                 self._inflight[id(p)] = p
+                self.stats.queue_wait_s += now - p.arrival
+            self.stats.dispatched_requests += len(live)
             self._run(live)
             self._inflight.clear()
 
     def _run(self, batch: List[_Pending]) -> None:
-        try:
-            if self._faults is not None:
-                self._faults.pre_dispatch(batch)  # plan-exception seam
-            # Host-side assembly (numpy): concatenating/padding/slicing k
-            # request arrays as jax ops would XLA-compile a fresh glue op
-            # per (k, sizes) signature mid-traffic — a latency spike the
-            # warmed bucket plans exist to avoid. As numpy it is a
-            # memcpy, and serve_batch's host fast path keeps it that way
-            # end to end (the only device work is the bucket dispatch).
+        with TraceAnnotation("serve.batch", requests=len(batch),
+                             samples=sum(p.n for p in batch)):
+            try:
+                y = self._serve(batch)
+            except Exception as e:  # noqa: BLE001 — isolate, don't kill the loop
+                err = e
+            else:
+                self._resolve(batch, y)
+                return
+        if len(batch) == 1:
+            self._fail(p=batch[0], exc=err, kind="failed")
+            return
+        # Blast-radius isolation: bisect. Each half pads up to an
+        # already-warmed bucket, so innocent co-batched requests complete
+        # bit-identically to a fault-free run (batch rows are independent)
+        # with zero new traces, and recursion pins the exception on exactly
+        # the poison request(s).
+        mid = (len(batch) + 1) // 2
+        self._run(batch[:mid])
+        self._run(batch[mid:])
+
+    def _serve(self, batch: List[_Pending]):
+        """One batch's logits (numpy), through the fault seams."""
+        if self._faults is not None:
+            self._faults.pre_dispatch(batch)  # plan-exception seam
+        # Host-side assembly (numpy): concatenating/padding/slicing k
+        # request arrays as jax ops would XLA-compile a fresh glue op per
+        # (k, sizes) signature mid-traffic — a latency spike the warmed
+        # bucket plans exist to avoid. As numpy it is a memcpy, and
+        # serve_batch's host fast path keeps it that way end to end (the
+        # only device work is the bucket dispatch).
+        with TraceAnnotation("serve.assemble"):
             xs = [np.asarray(p.x) for p in batch]
             xb = xs[0] if len(xs) == 1 else np.concatenate(xs, axis=0)
-            if self._faults is not None:
-                xb = self._faults.pre_serve(batch, xb)  # slow/NaN seam
-            t0 = time.monotonic()
-            y = self.serve_batch(xb)  # numpy in -> numpy out, completed
-            self._note_service_time(time.monotonic() - t0)
-            if self._faults is not None:
-                y = self._faults.post_serve(batch, y)  # NaN-activation seam
-        except Exception as e:  # noqa: BLE001 — isolate, don't kill the loop
-            if len(batch) == 1:
-                self._fail(p=batch[0], exc=e, kind="failed")
-                return
-            # Blast-radius isolation: bisect. Each half pads up to an
-            # already-warmed bucket, so innocent co-batched requests
-            # complete bit-identically to a fault-free run (batch rows
-            # are independent) with zero new traces, and recursion pins
-            # the exception on exactly the poison request(s).
-            mid = (len(batch) + 1) // 2
-            self._run(batch[:mid])
-            self._run(batch[mid:])
-            return
-        done = time.monotonic()
-        off = 0
-        clean = True
-        for p in batch:
-            yp = y[off : off + p.n]
-            off += p.n
-            if (self._check_outputs
-                    and np.issubdtype(np.asarray(yp).dtype, np.floating)
-                    and not np.isfinite(yp).all()):
-                # fail only the offending request — its co-batch is fine
-                self._fail(p, NumericalFault(
-                    f"non-finite logits for request of {p.n} sample(s)"),
-                    kind="failed")
-                clean = False
-            else:
-                self._complete(p, yp, done)
-        if clean:
-            with self._lock:
-                self._degraded = False  # a clean batch clears the flag
+        if self._faults is not None:
+            xb = self._faults.pre_serve(batch, xb)  # slow/NaN seam
+        t0 = time.monotonic()
+        y = self.serve_batch(xb)  # numpy in -> numpy out, completed
+        self._note_service_time(time.monotonic() - t0)
+        if self._faults is not None:
+            y = self._faults.post_serve(batch, y)  # NaN-activation seam
+        return y
+
+    def _resolve(self, batch: List[_Pending], y) -> None:
+        """Slice each request's logits off ``y`` and settle its future
+        (client callbacks run here, on the dispatcher thread)."""
+        with TraceAnnotation("serve.complete"):
+            done = time.monotonic()
+            off = 0
+            clean = True
+            for p in batch:
+                yp = y[off : off + p.n]
+                off += p.n
+                if (self._check_outputs
+                        and np.issubdtype(np.asarray(yp).dtype, np.floating)
+                        and not np.isfinite(yp).all()):
+                    # fail only the offending request — its co-batch is fine
+                    self._fail(p, NumericalFault(
+                        f"non-finite logits for request of {p.n} sample(s)"),
+                        kind="failed")
+                    clean = False
+                else:
+                    self._complete(p, yp, done)
+            if clean:
+                with self._lock:
+                    self._degraded = False  # a clean batch clears the flag
+
+    # ------------------------------------------------------ host.gc span
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """``gc.callbacks`` hook, installed while the dispatcher runs:
+        each collector pass in the process is a ``host.gc`` span and
+        counts into ``gc_collections`` / ``gc_s``. Passes never overlap,
+        and each starts and stops on one thread."""
+        if phase == "start":
+            self._gc_t0 = time.monotonic()
+            self._gc_span = TraceAnnotation("host.gc",
+                                            generation=info["generation"])
+            self._gc_span.__enter__()
+        elif self._gc_span is not None:
+            self._gc_span.__exit__(None, None, None)
+            self._gc_span = None
+            self.stats.gc_collections += 1
+            self.stats.gc_s += time.monotonic() - self._gc_t0
 
     # ----------------------------------------------- terminal outcomes
     def _complete(self, p: _Pending, y, done: float) -> None:

@@ -39,6 +39,7 @@ from typing import Any, Callable, Mapping, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 class StalePlanError(RuntimeError):
@@ -98,13 +99,16 @@ class ModelPlan:
     shard: Optional[Tuple[Any, Any]] = None
 
     def __post_init__(self):
-        stages = tuple(l.run for l in self.layers)
+        stages = tuple((l.name, l.run) for l in self.layers)
         traces = {"count": 0}
 
         def chain(x):
             traces["count"] += 1  # runs at trace time only, not per dispatch
-            for run in stages:
-                x = run(x)
+            for name, run in stages:
+                # names the stage's device ops in a profile (op metadata
+                # only: the compiled program is the same)
+                with jax.named_scope(name):
+                    x = run(x)
             return x
 
         fn = chain
@@ -243,19 +247,24 @@ class PlanSet:
         while i < n:
             take = min(cap, n - i)
             b = self.bucket_for(take)
-            xb = x[i : i + take]
-            if take < b:
-                pad = [(0, b - take)] + [(0, 0)] * (x.ndim - 1)
-                xb = xp.pad(xb, pad)
-            if put is not None:
-                xb = put(xb)
-            if on_dispatch is not None:
-                on_dispatch(b, take)
-            y = (self.plans[b].serve(xb) if dispatch is None
-                 else dispatch(b, xb))
-            if host:
-                y = np.asarray(y)  # block + gather once, slice on the host
-            outs.append(y if take == b else y[:take])
+            with TraceAnnotation("plan.dispatch", bucket=b, n_real=take):
+                xb = x[i : i + take]
+                if take < b:
+                    pad = [(0, b - take)] + [(0, 0)] * (x.ndim - 1)
+                    xb = xp.pad(xb, pad)
+                if put is not None:
+                    xb = put(xb)
+                if on_dispatch is not None:
+                    on_dispatch(b, take)
+                # enqueue: includes the synchronous host->device input copy
+                with TraceAnnotation("plan.launch"):
+                    y = (self.plans[b].serve(xb) if dispatch is None
+                         else dispatch(b, xb))
+                if host:
+                    # block + gather once, slice on the host
+                    with TraceAnnotation("plan.fetch"):
+                        y = np.asarray(y)
+                outs.append(y if take == b else y[:take])
             i += take
         return outs[0] if len(outs) == 1 else xp.concatenate(outs, axis=0)
 
@@ -292,8 +301,9 @@ class PlanSet:
                     "sample_spec")
             sample_shape, dtype = self.sample_spec
         for b in self.buckets:
-            xb = np.zeros((b,) + tuple(sample_shape), dtype)
-            self.serve(xb, put=put)
+            with TraceAnnotation("plan.warmup", bucket=b):
+                xb = np.zeros((b,) + tuple(sample_shape), dtype)
+                self.serve(xb, put=put)
         return self.trace_count
 
     # ------------------------------------------------------- introspection

@@ -4,8 +4,12 @@ The paper's hardware IM2COL unit sits *after* SRAM, expanding the activation
 stream 3× right before the datapath so the SRAM never stores or re-reads the
 im2col-duplicated pixels. The TPU-native analogue: read the raw (H, W, C)
 activation tile from HBM exactly once into VMEM and materialize the im2col
-expansion only as *shifted views* feeding the MXU — the conv becomes
-kh·kw shifted (HW, C)×(C, F) matmuls accumulated output-stationary.
+expansion there alone: the kh·kw shifted views of the tile go side by side
+along the lanes of one (HW, kh·kw·C) patch, and the conv is one
+(HW, kh·kw·C)×(kh·kw·C, F) matmul per chunk of output rows (kernel
+``im2col_conv_packed``). A dot per tap would contract only C, and a
+contraction far below the MXU's 128 rows still pays a whole pass (six at
+fp32 ``HIGHEST``): the C=3 stem's nine taps of K=3 become one dot of K=27.
 
 HBM activation traffic: H·W·C  (vs kh·kw·H·W·C for explicit im2col+GEMM,
 i.e. 9× less for 3×3 — the paper reports 3× average SRAM-read reduction for
@@ -16,11 +20,11 @@ explicit padding and spatial H×W output tiling (bounded VMEM for large
 feature maps) are all supported; geometry and the shifted-view tap come
 from :mod:`repro.kernels.core` (DESIGN.md §6). The grid is (output tile,
 F block); the kh·kw taps are a static loop inside each step, each a
-static window of the VMEM input tile accumulated output-stationary in a
-VMEM scratch. The TPU compiler takes no ``dynamic_slice`` of a loaded
-value and no strided load of 8-bit data, so the tap offsets are static
-and a strided conv reads a stride-phase split of its input tile
-(``core.phase_split``, one XLA pass in the wrapper) instead of striding.
+static window of the VMEM input tile. The TPU compiler
+takes no ``dynamic_slice`` of a loaded value and no strided load of 8-bit
+data, so the tap offsets are static and a strided conv reads a
+stride-phase split of its input tile (``core.phase_split``, one XLA pass
+in the wrapper) instead of striding.
 """
 from __future__ import annotations
 
@@ -29,7 +33,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import core
 
@@ -100,14 +103,28 @@ def conv_taps(x_ref, tap_contribution, acc_ref, *, kh, kw, sh, sw, bh, bw):
             acc_ref[...] += contrib
 
 
-def _im2col_conv_kernel(x_ref, w_ref, *rest, geom, ep=None):
-    """Grid: (N·th·tw, F/bf). x: (1, sh·sw, Hq, Wq, C); w: (kh·kw, C, bf);
-    ``rest`` carries the optional (1, bf) fp32 epilogue rows named by the
-    static ``ep`` (scale/bias/out_scale — DESIGN.md §9)."""
-    flush, o_ref, acc_ref = core.split_epilogue(ep, rest)
-    conv_taps(x_ref, lambda t, patch: core.mxu_dot(patch, w_ref[t]), acc_ref,
-              **geom)
-    core.store_epilogue(acc_ref[...], o_ref, **flush)
+# output pixels per packed dot: the tile's rows go in static chunks of about
+# this many, whatever K. On v5e one bucket-128 stem call took 2.33–2.52 ms,
+# launch included, in chunks of 64 to 1024 pixels (1024 the slowest) and
+# 2.67–2.85 ms as one 4096-pixel patch; at K ≥ 1152, chunks of 16–32 pixels
+# ran up to a quarter slower than per-tap dots, 256–512 as fast (PERF.md §6).
+PACKED_PIXELS = 512
+
+
+def _im2col_conv_packed_kernel(x_ref, w_ref, *rest, geom, ep=None):
+    """Grid: (N·th·tw, F/bf). x: (1, sh·sw, Hq, Wq, C); w: (kh·kw·C, bf).
+    The kh·kw taps of ``rh`` output rows sit side by side along the lanes
+    of one (rh·bw, kh·kw·C) patch, one MXU contraction each; no
+    accumulator scratch."""
+    flush, o_ref, _ = core.split_epilogue(ep, (*rest, None))
+    kh, kw, sh, sw, bh, bw = (geom[k] for k in ("kh", "kw", "sh", "sw", "bh", "bw"))
+    rh = core.aligned_divisor(bh, PACKED_PIXELS // bw, 1)
+    w = w_ref[...]
+    for r in range(0, bh, rh):  # tap row dy + r·sh: phase dy % sh, row dy // sh + r
+        patch = jnp.concatenate(
+            [core.conv_tap(x_ref, t // kw + r * sh, t % kw, bh=rh, bw=bw, sh=sh, sw=sw)
+             for t in range(kh * kw)], axis=1)
+        core.store_epilogue(core.mxu_dot(patch, w), o_ref.at[:, pl.ds(r, rh)], **flush)
 
 
 def im2col_conv(
@@ -139,7 +156,6 @@ def im2col_conv(
         bf, tile_h, tile_w = core.tuned_conv_tiles(core.KIND_CONV_DENSE, sig, ho, wo, f)
     xt, g = plan_conv(x, kh, kw, stride=stride, padding=padding, tile_h=tile_h, tile_w=tile_w)
     bf = core.resolve_or_pick(f, bf, 128, "bf", align=core.LANES)
-    w3 = w.reshape(kh * kw, c, f)
     grid = (n * g["th"] * g["tw"], f // bf)
     acc_dtype = core.acc_dtype_for(x.dtype)  # int32 on the int8 path (§8)
     ep, e_ops, e_specs, out_dtype = core.epilogue_plan(
@@ -147,16 +163,16 @@ def im2col_conv(
         acc_dtype=acc_dtype, in_dtype=x.dtype,
     )
     return pl.pallas_call(
-        functools.partial(_im2col_conv_kernel, geom=tap_geom(g), ep=ep),
+        functools.partial(_im2col_conv_packed_kernel, geom=tap_geom(g), ep=ep),
         grid=grid,
         in_specs=[
             conv_in_spec(xt),
-            pl.BlockSpec((kh * kw, c, bf), lambda p, j: (0, 0, j)),
+            # row (dy·kw + dx)·C + c: the patch's lanes
+            pl.BlockSpec((kh * kw * c, bf), lambda p, j: (0, j)),
             *e_specs,
         ],
         out_specs=conv_out_spec(g, bf),
         out_shape=jax.ShapeDtypeStruct((n, g["ho"], g["wo"], f), out_dtype),
-        scratch_shapes=[pltpu.VMEM((g["bh"] * g["bw"], bf), acc_dtype)],
         interpret=core.resolve_interpret(interpret),
-        name="im2col_conv",
-    )(xt, w3, *e_ops)
+        name="im2col_conv_packed",
+    )(xt, w.reshape(kh * kw * c, f), *e_ops)

@@ -55,6 +55,20 @@ class TestDenseConvEdgeCases:
             (1, 12, 12, 8, 16, 5, 3, 1, "SAME", (6, 4)),  # spatial tiling
             (2, 16, 16, 3, 8, 3, 3, 2, "SAME", (4, 4)),   # tiling + stride
             (1, 7, 7, 5, 8, 4, 4, 3, ((1, 2), (2, 1)), None),  # explicit pad
+            (2, 9, 9, 1, 8, 3, 3, 1, "SAME", None),       # C=1, packed taps
+            (1, 11, 11, 1, 8, 3, 3, 2, "VALID", None),    # C=1, stride 2
+            (1, 34, 34, 3, 16, 3, 3, 1, "VALID", None),   # the stem's C=3, 2 row chunks
+            (1, 64, 64, 3, 16, 3, 3, 2, "SAME", None),    # C=3, stride 2, 2 row chunks
+            (1, 48, 48, 3, 8, 3, 3, 1, "SAME", (24, 48)), # tiled, 3 chunks of 8 rows
+            (1, 8, 8, 16, 8, 3, 3, 1, "SAME", None),      # K = 3·3·16 = 144 > 128
+            (1, 12, 12, 15, 8, 3, 3, 1, "SAME", None),    # K = 135, just over a lane tile
+            (1, 8, 8, 33, 8, 2, 2, 1, "VALID", None),     # K = 132, even kernel
+            (1, 10, 10, 9, 8, 5, 3, 1, "SAME", None),     # K = 135, 5x3 kernel
+            (1, 32, 32, 3, 8, 7, 7, 2, "SAME", None),     # 7x7x3 stem, K = 147
+            (1, 32, 32, 64, 8, 3, 3, 1, "SAME", None),    # K = 576, 2 row chunks
+            (1, 8, 8, 128, 8, 3, 3, 1, "SAME", None),     # K = 1152, lane-aligned taps
+            (2, 12, 12, 32, 8, 3, 3, 2, "SAME", None),    # K = 288, stride 2
+            (1, 16, 16, 14, 8, 3, 3, 1, "SAME", (8, 16)), # K = 126, tiled
         ],
     )
     def test_allclose_vs_lax(self, n, h, w, c, f, kh, kw, stride, padding, tiles):
@@ -69,6 +83,30 @@ class TestDenseConvEdgeCases:
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), **TOLS[jnp.float32]
         )
+
+    @pytest.mark.parametrize(
+        "c,stride,padding,h",
+        [(1, 1, "SAME", 10), (1, 2, "VALID", 10), (3, 1, "VALID", 10),
+         (3, 2, "SAME", 10), (3, 1, "SAME", 40),  # 40: 4 chunks of 10 rows
+         (16, 1, "SAME", 10)],  # 3·3·16 = 144: K over one lane tile
+    )
+    def test_fused_epilogue_int8_vs_lax(self, c, stride, padding, h):
+        """bias + ReLU + requantize, as the stem runs it. Summation order may
+        differ from the oracle's, so a code may be one off, but only where
+        the fp32 value sits within 1e-5 of a rounding boundary."""
+        k1, k2, k3 = jax.random.split(jax.random.PRNGKey(3), 3)
+        x = jax.random.normal(k1, (2, h, h, c), jnp.float32)
+        wk = jax.random.normal(k2, (3, 3, c, 16), jnp.float32)
+        bias = jax.random.normal(k3, (16,), jnp.float32)
+        y = ref.conv_lax_ref(x, wk, stride=stride, padding=padding) + bias
+        out_scale = float(jnp.max(y)) / 127  # calibrated to the max
+        got = im2col_conv(x, wk, bias=bias, relu=True, out_scale=out_scale,
+                          stride=stride, padding=padding, bf=8)
+        assert got.dtype == jnp.int8
+        v = np.asarray(jnp.maximum(y, 0) / out_scale)
+        off = np.abs(np.asarray(got, np.int32) - np.clip(np.round(v), -127, 127))
+        near = np.abs(np.abs(v - np.floor(v)) - 0.5) < 1e-5
+        assert off.max() <= 1 and np.all(near[off == 1])
 
     @pytest.mark.parametrize(
         "stride,kh", [(2, 3), (1, 2)]  # strided + even kernel, bf16 numerics

@@ -10,6 +10,8 @@ The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library,
 and every test worker imports every test file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -68,16 +70,33 @@ def _weight(k, n, dtype, sharding, fmt=FMT):
 def _assert_kernel(fn, *args):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    return compiled.as_text()
 
 
-def test_stem_dense_conv(one_chip):
-    """The fp32 stem (3→64, 64×64) with the fused bias/ReLU/requantize."""
+@pytest.mark.parametrize("n", [8, 128])  # 128: the benchmark's bucket
+def test_stem_dense_conv(one_chip, n):
+    """The fp32 stem (3→64, 64×64) with the fused bias/ReLU/requantize,
+    its 9 taps × 3 channels packed into one contraction."""
     s = one_chip
-    _assert_kernel(
+    hlo = _assert_kernel(
         lambda x, w, b, o: ops.fused_im2col_conv(
             x, w, bias=b, relu=True, out_scale=o, interpret=False),
-        _spec((8, 64, 64, 3), jnp.float32, s), _spec((3, 3, 3, 64), jnp.float32, s),
+        _spec((n, 64, 64, 3), jnp.float32, s), _spec((3, 3, 3, 64), jnp.float32, s),
         _spec((64,), jnp.float32, s), _spec((), jnp.float32, s))
+    assert re.search(r"%im2col_conv_packed[.\d]* = ", hlo)
+
+
+# (H, C, F, stride): dense convs whose packed contraction K = 9·C spans
+# more than one lane tile: unaligned (C=16), two row chunks (C=64), aligned
+@pytest.mark.parametrize("h,c,f,stride", [(32, 16, 64, 1), (32, 64, 128, 1),
+                                          (16, 128, 128, 2)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int8], ids=["fp32", "int8"])
+def test_wide_dense_conv(one_chip, h, c, f, stride, dtype):
+    s = one_chip
+    hlo = _assert_kernel(
+        lambda x, w: ops.fused_im2col_conv(x, w, stride=stride, interpret=False),
+        _spec((8, h, h, c), dtype, s), _spec((3, 3, c, f), dtype, s))
+    assert re.search(r"%im2col_conv_packed[.\d]* = ", hlo)
 
 
 # (H, C, F, stride): l3 (stride 1) and l2 (stride 2) of sparse-cnn-s

@@ -1,6 +1,7 @@
 from repro.configs.registry import ARCHS, get_config, smoke_config  # noqa: F401
 from repro.configs.cnn import (  # noqa: F401
     CNN_ARCHS,
+    cnn_model,
     get_cnn_config,
     smoke_cnn_config,
 )

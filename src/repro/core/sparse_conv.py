@@ -102,6 +102,8 @@ class DBBConv2d:
                 window_strides=_pair(self.stride),
                 padding=self.padding,
                 dimension_numbers=("NHWC", "HWIO", "NHWC"),
+                # fp32 at fp32: a TPU's default would round to bf16 first
+                precision=jax.lax.Precision.HIGHEST,
             )
         if self.use_bias:
             y = y + params["b"].astype(y.dtype)
@@ -140,8 +142,8 @@ class DBBConv2d:
         )
 
     def quant_serve(self, params: dict, x: jax.Array, *, relu: bool = False,
-                    out_scale=None, bf=None, tile_h=None,
-                    tile_w=None) -> jax.Array:
+                    out_scale=None, residual=None, residual_scale=None,
+                    bf=None, tile_h=None, tile_w=None) -> jax.Array:
         """One-kernel INT8 serving conv with the fused epilogue (§9).
 
         The whole layer — int8 conv, dequant, bias (from ``params``),
@@ -152,6 +154,9 @@ class DBBConv2d:
         ``aq`` or dynamically) or already int8-resident codes from the
         previous layer's epilogue (requires a calibrated ``aq``). Returns
         int8 codes when ``out_scale`` is given, fp32 otherwise.
+        ``residual`` (int8 codes shaped like the output) at
+        ``residual_scale`` is a residual block's shortcut, added in the
+        same flush after the bias and before the ReLU.
         ``bf``/``tile_h``/``tile_w`` pin explicit launch tiles (the §10
         frozen-plan path); None keeps the registry/pick defaults.
         """
@@ -163,7 +168,8 @@ class DBBConv2d:
 
             return ops.quant_conv(
                 x, qw, self.kh, self.kw, aq, bias=b, relu=relu,
-                out_scale=out_scale, stride=_pair(self.stride),
+                out_scale=out_scale, residual=residual,
+                residual_scale=residual_scale, stride=_pair(self.stride),
                 padding=self.padding, bf=bf, tile_h=tile_h, tile_w=tile_w,
             )
         from repro.kernels.ref import quant_epilogue_ref, sparse_conv_int_ref
@@ -174,14 +180,15 @@ class DBBConv2d:
             stride=_pair(self.stride), padding=self.padding,
         )
         return quant_epilogue_ref(
-            acc, s_a * qw.scales, bias=b, relu=relu, out_scale=out_scale
+            acc, s_a * qw.scales, bias=b, relu=relu, out_scale=out_scale,
+            residual=residual, residual_scale=residual_scale,
         )
 
     # ------------------------------------------------------- frozen plans
     def make_plan(self, params: dict, *, batch: int, h: int, w: int,
                   relu: bool = False, out_scale=None, fused: bool = False,
-                  tune: str = "cache", cache=None, top_k: int = 4,
-                  reps: int = 3):
+                  residual_scale=None, tune: str = "cache", cache=None,
+                  top_k: int = 4, reps: int = 3):
         """Stage this layer's serving step once (DESIGN.md §10).
 
         Resolves the tuned tile config for this exact launch signature
@@ -192,7 +199,10 @@ class DBBConv2d:
         takes for these params (``fused=True`` = the §9 int8-resident
         chain step, so a plan built from calibrated quantized params is
         bit-identical to the unplanned chain); ``tiles`` is the resolved
-        config (empty on reference/XLA paths).
+        config (empty on reference/XLA paths). With ``residual_scale``
+        (the §9 chain only) ``run`` is ``(x, residual) -> y``: the layer
+        closes a residual block and adds the shortcut's int8 codes, at
+        that scale, in its flush.
         """
         from repro.kernels.core import (
             conv_geometry, default_conv_tiles, default_interpret,
@@ -225,8 +235,13 @@ class DBBConv2d:
             # closure never depends on ambient registry state at trace time
             _, _, (ho, wo) = conv_geometry(h, w, self.kh, self.kw,
                                            self.stride, self.padding)
-            tiles = default_conv_tiles(ho, wo, self.out_channels)
-        if quant and fused:
+            tiles = default_conv_tiles(ho, wo, self.out_channels, self.kh, self.kw)
+        if quant and fused and residual_scale is not None:
+            def run(x, residual):
+                return self.quant_serve(params, x, relu=relu,
+                                        out_scale=out_scale, residual=residual,
+                                        residual_scale=residual_scale, **tiles)
+        elif quant and fused:
             def run(x):
                 return self.quant_serve(params, x, relu=relu,
                                         out_scale=out_scale, **tiles)

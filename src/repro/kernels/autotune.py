@@ -502,7 +502,7 @@ def tune_conv(batch: int, h: int, w: int, c: int, f: int, kh: int, kw: int,
         kind, sig, conv_candidates(ho, wo, f, keep=keep),
         lambda t: modeled_conv_cost(batch, ho, wo, c, f, kh, kw, sh, sw,
                                     mfmt, t, itemsize, cal=cal),
-        build, core.default_conv_tiles(ho, wo, f),
+        build, core.default_conv_tiles(ho, wo, f, kh, kw),
         top_k=top_k, reps=reps, warmup=warmup, cache=cache, save=save,
     )
 

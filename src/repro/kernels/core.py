@@ -12,10 +12,10 @@ array's *output-stationary* dataflow (DESIGN.md §2, §6):
 
 This module owns that plumbing once: the init/accumulate/store pattern
 (:func:`os_accumulate`), the fused flush epilogue (:class:`Epilogue` /
-:func:`epilogue_plan` / :func:`split_epilogue` — dequant scale, bias, ReLU,
-requantize-to-int8, all executed once where the hardware's requantizer
-sits, DESIGN.md §9), K-innermost grid construction and the fp32 VMEM
-scratch + output BlockSpec boilerplate (:func:`os_matmul_call`), tile-size
+:func:`epilogue_plan` / :func:`split_epilogue` — dequant scale, bias, a
+residual block's shortcut, ReLU, requantize-to-int8, all executed once
+where the hardware's requantizer sits, DESIGN.md §9), K-innermost grid
+construction and the fp32 VMEM scratch + output BlockSpec boilerplate (:func:`os_matmul_call`), tile-size
 resolution (:func:`resolve_tile` strict / :func:`pick_tile` permissive /
 :func:`pick_tile_padded` aligned defaults), the activation mux
 (:func:`dbb_mux`), the conv tap loads (:func:`conv_tap`), and
@@ -160,10 +160,18 @@ def default_matmul_tiles(m: int, k: int, n: int, bz: int, dtype) -> dict:
             "kb": default_kb(k // bz, bz)}
 
 
-def default_conv_tiles(ho: int, wo: int, f: int) -> dict:
-    """The untuned ``(bf, tile_h, tile_w)`` of one fused-conv launch: a
-    lane-aligned F block (or all of F) over the whole output map."""
-    return {"bf": aligned_divisor(f, 128, LANES), "tile_h": ho, "tile_w": wo}
+def default_bf(f: int, kh: int = 3, kw: int = 3) -> int:
+    """The untuned F block of a fused conv: lane-aligned 128 (or all of F),
+    and all of F for a 1×1 conv, whose activation mux (``dbb_mux``) would
+    otherwise run again for every F block — whole-F blocks ran 8–29 %
+    faster on v5e at ResNet-50's 1×1 shapes (PERF.md §3)."""
+    return aligned_divisor(f, f if kh == kw == 1 else 128, LANES)
+
+
+def default_conv_tiles(ho: int, wo: int, f: int, kh: int = 3, kw: int = 3) -> dict:
+    """The untuned ``(bf, tile_h, tile_w)`` of one fused-conv launch: the
+    :func:`default_bf` block over the whole output map."""
+    return {"bf": default_bf(f, kh, kw), "tile_h": ho, "tile_w": wo}
 
 
 def pad_tile(dim: int, tile, default: int, align: int = 1) -> tuple:
@@ -284,47 +292,68 @@ class Epilogue:
     """Static plan of the fused accumulator-flush epilogue (DESIGN.md §9).
 
     Flags name which fused operands ride after the compute operands — in
-    (scale, bias, out_scale) order, each a (1, N) fp32 row — plus the
-    static ReLU flag. Built host-side by :func:`epilogue_plan`, consumed
-    kernel-side by :func:`split_epilogue`; hashable, so it threads into
-    kernels via ``functools.partial``.
+    (scale, bias, residual, residual_scale, out_scale) order: each row a
+    (1, N) fp32 operand, the residual an int8 tile shaped like the output
+    tile — plus the static ReLU flag. Built host-side by
+    :func:`epilogue_plan`, consumed kernel-side by :func:`split_epilogue`;
+    hashable, so it threads into kernels via ``functools.partial``.
     """
 
     has_scale: bool = False
     has_bias: bool = False
     relu: bool = False
     has_out_scale: bool = False
+    has_residual: bool = False
 
     @property
     def n_operands(self) -> int:
-        return int(self.has_scale) + int(self.has_bias) + int(self.has_out_scale)
+        return (int(self.has_scale) + int(self.has_bias)
+                + 2 * int(self.has_residual) + int(self.has_out_scale))
 
 
 def epilogue_plan(n: int, bn: int, *, scales=None, bias=None, relu=False,
-                  out_scale=None, acc_dtype, in_dtype, out_dtype=None):
+                  out_scale=None, acc_dtype, in_dtype, out_dtype=None,
+                  residual=None, residual_scale=None, residual_spec=None):
     """Resolve the fused-epilogue request into kernel-launch pieces.
 
     Returns ``(ep, operands, specs, out_dtype)``: the static
     :class:`Epilogue` (None when nothing was requested), the (1, n) fp32
-    operand rows (a scalar ``out_scale`` broadcasts across N) with their
-    (1, bn) BlockSpecs indexed on the N grid axis, and the resolved output
-    dtype — int8 when requantizing, fp32 when scale/bias/ReLU touch the
-    accumulator, else the raw accumulator dtype (the pre-epilogue default).
+    operand rows (a scalar ``out_scale`` or ``residual_scale`` broadcasts
+    across N) with their (1, bn) BlockSpecs indexed on the N grid axis,
+    and the resolved output dtype — int8 when requantizing, fp32 when
+    scale/bias/ReLU touch the accumulator, else the raw accumulator dtype
+    (the pre-epilogue default).
+
+    ``residual`` (int8 codes shaped like the output, read through
+    ``residual_spec``, the output's BlockSpec) with ``residual_scale`` adds
+    a shortcut branch after the bias and before the ReLU: a bottleneck
+    block's closing conv adds its shortcut in the flush.
     """
+    if (residual is None) != (residual_scale is None):
+        raise ValueError("a residual needs its scale, and a scale its residual")
     ep = Epilogue(scales is not None, bias is not None, bool(relu),
-                  out_scale is not None)
+                  out_scale is not None, residual is not None)
     operands, specs = [], []
     spec = pl.BlockSpec((1, bn), lambda *g: (0, g[1]))  # N is grid axis 1
-    for v, present in ((scales, ep.has_scale), (bias, ep.has_bias),
-                       (out_scale, ep.has_out_scale)):
+
+    def row(v):
+        r = jnp.asarray(v, jnp.float32).reshape(1, -1)
+        operands.append(jnp.broadcast_to(r, (1, n)))
+        specs.append(spec)
+
+    for v, present in ((scales, ep.has_scale), (bias, ep.has_bias)):
         if present:
-            row = jnp.asarray(v, jnp.float32).reshape(1, -1)
-            operands.append(jnp.broadcast_to(row, (1, n)))
-            specs.append(spec)
+            row(v)
+    if ep.has_residual:
+        operands.append(residual)
+        specs.append(residual_spec)
+        row(residual_scale)
+    if ep.has_out_scale:
+        row(out_scale)
     if out_dtype is None:
         if ep.has_out_scale:
             out_dtype = jnp.int8
-        elif ep.has_scale or ep.has_bias:
+        elif ep.has_scale or ep.has_bias or ep.has_residual:
             out_dtype = jnp.float32  # dequant/bias move the tile to fp32
         elif acc_dtype == jnp.dtype(jnp.int32):
             out_dtype = jnp.int32  # raw (or relu-only) int32 stays exact
@@ -339,9 +368,9 @@ def split_epilogue(ep: Epilogue | None, rest):
     """Split a kernel's trailing refs into flush kwargs + (o_ref, acc_ref).
 
     ``rest`` is ``[*epilogue_refs, o_ref, acc_ref]`` with the epilogue
-    refs in (scale, bias, out_scale) order, exactly as
-    :func:`epilogue_plan` appended them. Returns ``(flush, o_ref,
-    acc_ref)`` where ``flush`` feeds straight into
+    refs in (scale, bias, residual, residual_scale, out_scale) order,
+    exactly as :func:`epilogue_plan` appended them. Returns ``(flush,
+    o_ref, acc_ref)`` where ``flush`` feeds straight into
     ``os_accumulate(..., **flush)``.
     """
     n = ep.n_operands if ep is not None else 0
@@ -352,6 +381,9 @@ def split_epilogue(ep: Epilogue | None, rest):
         bias=refs.pop(0)[...] if ep is not None and ep.has_bias else None,
         relu=ep is not None and ep.relu,
     )
+    if ep is not None and ep.has_residual:
+        flush["residual"] = refs.pop(0)[...]
+        flush["residual_scale"] = refs.pop(0)[...]
     flush["out_scale"] = (
         refs.pop(0)[...] if ep is not None and ep.has_out_scale else None
     )
@@ -359,7 +391,8 @@ def split_epilogue(ep: Epilogue | None, rest):
 
 
 def os_accumulate(acc_ref, o_ref, contribution, *, grid_axis: int, scale=None,
-                  bias=None, relu: bool = False, out_scale=None):
+                  bias=None, relu: bool = False, out_scale=None, residual=None,
+                  residual_scale=None):
     """Output-stationary accumulation step.
 
     Zeroes ``acc_ref`` on the first step of the reduction grid axis
@@ -375,6 +408,9 @@ def os_accumulate(acc_ref, o_ref, contribution, *, grid_axis: int, scale=None,
     * ``scale`` (fp32, broadcastable, e.g. a (1, bn) per-output-column
       row): dequantization — the int32 accumulator becomes fp32 · scale.
     * ``bias`` (fp32 row): per-output-channel bias add.
+    * ``residual`` (int8 codes shaped like ``o_ref``) · ``residual_scale``
+      (fp32 row): the shortcut branch of a residual block, dequantized and
+      added.
     * ``relu`` (static): clamp at zero.
     * ``out_scale`` (fp32 row): requantize-to-int8 — the next layer's
       activation scale; the store clips round(acc / out_scale) into
@@ -390,17 +426,23 @@ def os_accumulate(acc_ref, o_ref, contribution, *, grid_axis: int, scale=None,
     @pl.when(pl.program_id(grid_axis) == pl.num_programs(grid_axis) - 1)
     def _store():
         store_epilogue(acc_ref[...], o_ref, scale=scale, bias=bias,
-                       relu=relu, out_scale=out_scale)
+                       relu=relu, out_scale=out_scale, residual=residual,
+                       residual_scale=residual_scale)
 
 
 def store_epilogue(acc, o_ref, *, scale=None, bias=None, relu: bool = False,
-                   out_scale=None):
+                   out_scale=None, residual=None, residual_scale=None):
     """The accumulator flush: apply the fused epilogue (see
-    :func:`os_accumulate`) to ``acc`` and store it into ``o_ref``."""
+    :func:`os_accumulate`) to ``acc`` and store it into ``o_ref``. The
+    residual is added in the output tile's shape, so its int8 codes are
+    never reshaped."""
     if scale is not None:
         acc = acc.astype(jnp.float32) * scale
     if bias is not None:
         acc = acc.astype(jnp.float32) + bias
+    if residual is not None:
+        acc = (acc.astype(jnp.float32).reshape(residual.shape)
+               + residual.astype(jnp.float32) * residual_scale)
     if relu:
         acc = jnp.maximum(acc, jnp.zeros((), acc.dtype))
     if out_scale is not None:

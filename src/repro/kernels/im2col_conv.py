@@ -47,6 +47,12 @@ def plan_conv(x, kh, kw, *, stride, padding, tile_h=None, tile_w=None):
     """
     n, h, w, c = x.shape
     (sh, sw), (ph, pw), (ho, wo) = core.conv_geometry(h, w, kh, kw, stride, padding)
+    if kh == kw == 1 and (sh, sw) != (1, 1):
+        # a strided 1×1 conv reads one pixel in sh·sw: subsample, then run
+        # it at stride 1 (no stride-phase split of the whole input)
+        x = jnp.pad(x, ((0, 0), ph, pw, (0, 0)))[:, ::sh, ::sw][:, :ho, :wo]
+        n, h, w, c = x.shape
+        (sh, sw), (ph, pw) = (1, 1), ((0, 0), (0, 0))
     bh = core.resolve_tile(ho, tile_h or ho, "tile_h")
     bw = core.resolve_tile(wo, tile_w or wo, "tile_w")
     th, tw = ho // bh, wo // bw

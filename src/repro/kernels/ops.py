@@ -263,6 +263,8 @@ def quant_conv(
     bias: jax.Array | None = None,
     relu: bool = False,
     out_scale=None,
+    residual: jax.Array | None = None,
+    residual_scale=None,
     stride=1,
     padding="SAME",
     bf: int | None = None,
@@ -279,12 +281,15 @@ def quant_conv(
     padding is exact under the symmetric scheme). Dequantization, bias,
     ReLU and the requantize at ``out_scale`` all fuse into the
     accumulator flush — one kernel per conv layer (DESIGN.md §9).
+    ``residual`` (int8 codes shaped like the output) at ``residual_scale``
+    is a residual block's shortcut, added after the bias, before the ReLU.
     """
     interpret = _default_interpret() if interpret is None else interpret
     xq, s_a = resolve_quant_input(x, act_scale)
     return _vconv.vdbb_im2col_conv(
         xq, qw.as_dbb(), kh, kw, scales=s_a * qw.scales, bias=bias, relu=relu,
-        out_scale=out_scale, stride=stride, padding=padding, bf=bf,
+        out_scale=out_scale, residual=residual, residual_scale=residual_scale,
+        stride=stride, padding=padding, bf=bf,
         tile_h=tile_h, tile_w=tile_w, interpret=interpret,
     )
 

@@ -54,20 +54,25 @@ def vdbb_matmul_int_ref(a: jax.Array, values: jax.Array, indices: jax.Array,
 
 
 def quant_epilogue_ref(acc: jax.Array, scale, *, bias=None, relu=False,
-                       out_scale=None) -> jax.Array:
+                       out_scale=None, residual=None,
+                       residual_scale=None) -> jax.Array:
     """Integer-oracle layer epilogue (DESIGN.md §9): the exact fp32 ops the
     kernels fuse into the accumulator flush, in dataflow order —
-    dequantize → bias → ReLU → requantize-to-int8.
+    dequantize → bias → + residual → ReLU → requantize-to-int8.
 
     ``acc``: raw int32 OS accumulator (last axis = output channels);
     ``scale``: fused dequant ``act_scale · w_scale[n]``, broadcast on the
     last axis; ``out_scale``: the next layer's activation scale — when
     given the result is int8 codes in ±127, bit-exact against the fused
     kernels. Without it the fp32 epilogue output is returned.
+    ``residual``: int8 codes shaped like ``acc`` (a residual block's
+    shortcut), dequantized at ``residual_scale`` and added.
     """
     y = acc.astype(jnp.float32) * scale
     if bias is not None:
         y = y + bias.astype(jnp.float32)
+    if residual is not None:
+        y = y + residual.astype(jnp.float32) * residual_scale
     if relu:
         y = jnp.maximum(y, 0.0)
     if out_scale is not None:

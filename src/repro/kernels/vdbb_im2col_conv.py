@@ -25,8 +25,12 @@ static loop inside the step, each a static contiguous window of the
 stride-phase-split input tile (no ``dynamic_slice``, no strided 8-bit
 load). The per-tap mux is the 2-D selection matmul ``core.dbb_mux`` —
 a ``(bh·bw, C) → (bh·bw, cb, bz)`` reshape splits the lane axis, which
-the compiler refuses. The (bh, bw, C) → (bh·bw, C) tap reshape wants bw
-a multiple of 8.
+the compiler refuses. A 1×1 conv is the same kernel with one tap (a
+strided one reads its input subsampled, ``plan_conv``); the int8 maps of
+ResNet-50 (56, 28, 14 and 7 wide) compile for v5e as they are, W unpadded
+(``tests/test_tpu_compile.py``). Calls are named by kind
+(:func:`kernel_name`): ``vdbb_im2col_conv_tc`` for a k×k conv,
+``…_1x1`` for a pointwise one, ``…_res`` where the flush adds a residual.
 
 Both pattern-sharing modes are provided, mirroring ``vdbb_matmul``:
 ``vdbb_im2col_conv_tc`` (group-shared patterns, compressed-K compute) and
@@ -124,18 +128,27 @@ def _tuned_conv_defaults(kind, x, fmt, kh, kw, f, stride, padding,
     return core.tuned_conv_tiles(kind, sig, ho, wo, f)
 
 
+def kernel_name(base: str, kh: int, kw: int, residual) -> str:
+    """The Pallas call's name in a device profile: ``base``, with ``_1x1``
+    for a pointwise conv and ``_res`` when the flush adds a residual, so a
+    trace tells the 1×1, k×k and residual-fused calls apart."""
+    return base + ("_1x1" if kh == kw == 1 else "") + ("_res" if residual is not None else "")
+
+
 def _launch(kernel, name, x, operands, wspecs, kh, kw, *, stride, padding,
             bf, tile_h, tile_w, out_dtype, interpret, scales=None, bias=None,
-            relu=False, out_scale=None):
+            relu=False, out_scale=None, residual=None, residual_scale=None):
     n = x.shape[0]
     f = operands[0].shape[-1]
     xt, g = plan_conv(x, kh, kw, stride=stride, padding=padding,
                       tile_h=tile_h, tile_w=tile_w)
     grid = (n * g["th"] * g["tw"], f // bf)
     acc_dtype = core.acc_dtype_for(x.dtype)  # int32 on the int8 path
+    out_spec = conv_out_spec(g, bf)
     ep, e_ops, e_specs, out_dtype = core.epilogue_plan(
         f, bf, scales=scales, bias=bias, relu=relu, out_scale=out_scale,
         acc_dtype=acc_dtype, in_dtype=x.dtype, out_dtype=out_dtype,
+        residual=residual, residual_scale=residual_scale, residual_spec=out_spec,
     )
     operands = (*operands, *e_ops)
     wspecs = [*wspecs, *e_specs]
@@ -143,11 +156,11 @@ def _launch(kernel, name, x, operands, wspecs, kh, kw, *, stride, padding,
         functools.partial(kernel, geom=tap_geom(g), ep=ep),
         grid=grid,
         in_specs=[conv_in_spec(xt), *wspecs],
-        out_specs=conv_out_spec(g, bf),
+        out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((n, g["ho"], g["wo"], f), out_dtype),
         scratch_shapes=[pltpu.VMEM((g["bh"] * g["bw"], bf), acc_dtype)],
         interpret=core.resolve_interpret(interpret),
-        name=name,
+        name=kernel_name(name, kh, kw, residual),
     )(xt, *operands)
 
 
@@ -163,6 +176,8 @@ def vdbb_im2col_conv_tc(
     bias: jax.Array | None = None,
     relu: bool = False,
     out_scale=None,
+    residual: jax.Array | None = None,
+    residual_scale=None,
     stride=1,
     padding="SAME",
     bf: int | None = None,
@@ -175,14 +190,16 @@ def vdbb_im2col_conv_tc(
     values: (nb, nnz, F); indices: (nb, nnz) with nb = kh·kw·C/bz.
     int8 operands accumulate in exact int32; ``scales`` (F,) / ``bias``
     (F,) / ``relu`` / ``out_scale`` fuse the layer epilogue into the
-    accumulator flush (DESIGN.md §9; out int8 when requantizing)."""
+    accumulator flush (DESIGN.md §9; out int8 when requantizing), and
+    ``residual`` (int8, shaped like the output) · ``residual_scale`` adds
+    a residual block's shortcut there, after the bias and before the ReLU."""
     nb, nnz, f = values.shape
     c = nb * fmt.bz // (kh * kw)
     cb = c // fmt.bz
     bf, tile_h, tile_w = _tuned_conv_defaults(
         core.KIND_CONV_TC, x, fmt, kh, kw, f, stride, padding, bf, tile_h, tile_w
     )
-    bf = core.resolve_or_pick(f, bf, 128, "bf", align=core.LANES)
+    bf = core.resolve_or_pick(f, bf, core.default_bf(f, kh, kw), "bf", align=core.LANES)
     v = values.reshape(kh * kw, cb * nnz, f)
     # per tap, the channel of the (·, C) patch each compressed column reads
     pos = core.mux_positions(indices, cb, fmt.bz).reshape(kh * kw, cb * nnz, 1)
@@ -194,7 +211,8 @@ def vdbb_im2col_conv_tc(
         _vdbb_conv_tc_kernel, "vdbb_im2col_conv_tc", x, (v, pos), wspecs, kh, kw,
         stride=stride, padding=padding, bf=bf, tile_h=tile_h, tile_w=tile_w,
         out_dtype=out_dtype, interpret=interpret, scales=scales, bias=bias,
-        relu=relu, out_scale=out_scale,
+        relu=relu, out_scale=out_scale, residual=residual,
+        residual_scale=residual_scale,
     )
 
 
@@ -210,6 +228,8 @@ def vdbb_im2col_conv_bw(
     bias: jax.Array | None = None,
     relu: bool = False,
     out_scale=None,
+    residual: jax.Array | None = None,
+    residual_scale=None,
     stride=1,
     padding="SAME",
     bf: int | None = None,
@@ -226,7 +246,7 @@ def vdbb_im2col_conv_bw(
     bf, tile_h, tile_w = _tuned_conv_defaults(
         core.KIND_CONV_BW, x, fmt, kh, kw, f, stride, padding, bf, tile_h, tile_w
     )
-    bf = core.resolve_or_pick(f, bf, 128, "bf", align=core.LANES)
+    bf = core.resolve_or_pick(f, bf, core.default_bf(f, kh, kw), "bf", align=core.LANES)
     # (kh·kw, nnz, cb, F): per tap, slot-major blocks (dbb_expand_block)
     v = values.reshape(kh * kw, cb, nnz, f).transpose(0, 2, 1, 3)
     idx = indices.astype(jnp.int32).reshape(kh * kw, cb, nnz, f).transpose(0, 2, 1, 3)
@@ -237,7 +257,8 @@ def vdbb_im2col_conv_bw(
         [spec, spec], kh, kw,
         stride=stride, padding=padding, bf=bf, tile_h=tile_h, tile_w=tile_w,
         out_dtype=out_dtype, interpret=interpret, scales=scales, bias=bias,
-        relu=relu, out_scale=out_scale,
+        relu=relu, out_scale=out_scale, residual=residual,
+        residual_scale=residual_scale,
     )
 
 

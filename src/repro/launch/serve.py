@@ -50,8 +50,8 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro.configs import CNN_ARCHS, get_cnn_config, get_config, make_batch, \
-    smoke_cnn_config, smoke_config
+from repro.configs import CNN_ARCHS, cnn_model, get_cnn_config, get_config, \
+    make_batch, smoke_cnn_config, smoke_config
 from repro.models.model import LM
 from repro.train.step import make_prefill, make_serve_step
 
@@ -99,14 +99,12 @@ def generate(model: LM, params, prompt_batch, *, gen_len: int, max_len: int):
 
 def serve_cnn(args):
     """INT8 CNN serving through a frozen plan (DESIGN.md §10)."""
-    from repro.models.cnn import SparseCNN
-
     cfgf = smoke_cnn_config if args.smoke else get_cnn_config
     sparsity = None if args.dense else args.sparsity
     cfg = dataclasses.replace(
         cfgf(args.arch, sparsity=sparsity), kernel_mode="pallas"
     )
-    model = SparseCNN(cfg)
+    model = cnn_model(cfg)
     params = model.compress(model.init(jax.random.PRNGKey(0)))
     xb = jax.random.normal(
         jax.random.PRNGKey(1),

@@ -18,7 +18,7 @@ reference path.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional, Sequence
+from typing import Any, Mapping, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +28,102 @@ from repro.core.sparse_conv import DBBConv2d
 from repro.core.sparse_linear import DBBLinear, PruneSchedule
 from repro.core.vdbb import DBBFormat, DENSE
 from repro.kernels.core import _pair, default_interpret
+
+
+class CNNLifecycle:
+    """The lifecycle the CNN families share, over their named layers.
+
+    A family (a frozen dataclass with a ``cfg`` that has ``name`` and
+    ``kernel_mode``) defines :meth:`named_layers` — ``[(name, module)]``
+    in forward order, each module a :class:`DBBConv2d` or
+    :class:`DBBLinear` whose params sit at ``params[name]`` — and
+    ``plan(params, *, batch, ...)``; constrain, compress, quantize (with
+    its calibrated scale per layer), the bucketed plan set and its
+    reference fallback follow from those.
+    """
+
+    def named_layers(self) -> list:
+        raise NotImplementedError
+
+    def init(self, key) -> dict:
+        layers = self.named_layers()
+        keys = jax.random.split(key, len(layers))
+        return {name: m.init(k) for (name, m), k in zip(layers, keys)}
+
+    def constrain(self, params: dict, step=None, schedule: Optional[PruneSchedule] = None) -> dict:
+        return {name: m.constrain(params[name], step, schedule)
+                for name, m in self.named_layers()}
+
+    def compress(self, params: dict) -> dict:
+        return {name: m.compress_params(params[name]) for name, m in self.named_layers()}
+
+    def quantize(self, params: dict, stats=None) -> dict:
+        """INT8 serving conversion of compressed params (DESIGN.md §8).
+
+        ``stats`` (optional): calibration :class:`ActStats` — one per layer
+        in :meth:`named_layers` order, or a mapping from layer name —
+        measured on the activation each layer *reads*, whose ``absmax``
+        becomes that layer's static per-tensor activation scale. Without
+        stats, activation scales are dynamic (computed per batch). Dense
+        layers (the C=3 stem) stay fp32, like the paper's uncompressed
+        first layer.
+        """
+        from repro.core.quant import act_scale_from_stats
+
+        layers = self.named_layers()
+        if stats is not None and not isinstance(stats, Mapping):
+            if len(stats) != len(layers):
+                raise ValueError(
+                    f"calibration stats for {len(stats)} layers, model has {len(layers)}"
+                )
+            stats = {name: st for (name, _), st in zip(layers, stats)}
+        return {
+            name: m.quantize(params[name], act_scale=(
+                None if stats is None else act_scale_from_stats(stats[name])))
+            for name, m in layers
+        }
+
+    def plan_set(self, params: dict, *, max_batch: Optional[int] = None,
+                 buckets: Optional[Sequence[int]] = None, dp: int = 1,
+                 tune: str = "cache", cache=None, top_k: int = 4,
+                 reps: int = 3):
+        """Freeze a bucketed serving plan set (DESIGN.md §11).
+
+        One ``plan`` per batch-size bucket, all sharing the same
+        tune cache and params fingerprint. ``buckets`` defaults to the
+        power-of-two ladder ``make_buckets(max_batch, dp=dp)``; ``dp``
+        (the data-parallel degree the set will be served at) forces
+        every bucket to shard evenly over a mesh's data axis. The
+        returned :class:`~repro.models.plan.PlanSet` serves any batch
+        size retrace-free after warmup: ragged batches pad up to the
+        nearest bucket and slice back, bit-identical to per-request
+        serving.
+        """
+        from repro.models.plan import build_plan_set, resolve_tune_cache
+
+        cache = resolve_tune_cache(tune, cache)  # one parse for all buckets
+        return build_plan_set(
+            self.cfg.name, params,
+            lambda b: self.plan(params, batch=b, tune=tune, cache=cache,
+                                top_k=top_k, reps=reps),
+            max_batch=max_batch, buckets=buckets, dp=dp,
+        )
+
+    def fallback_plan_set(self, params: dict, primary, *, verify: bool = True):
+        """Per-bucket degradation closures for the §15 self-healing tier:
+        re-stage ``primary``'s bucket ladder on the reference
+        (gather/integer-oracle) kernel path from the *same* quantized
+        params, verify bit-compat per bucket, and return the
+        ``{bucket: serve}`` mapping ``CNNServer(fallback=...)`` consumes.
+        The params fingerprint is content-based, so the ref restage pins
+        to the identical weights — a demoted bucket serves the same
+        numbers through a different backend, not a different model."""
+        from repro.models.plan import fallback_closures
+
+        ref_model = type(self)(dataclasses.replace(self.cfg, kernel_mode="ref"))
+        ref_set = ref_model.plan_set(params, buckets=primary.buckets,
+                                     tune="off")
+        return fallback_closures(primary, ref_set, verify=verify)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -64,7 +160,7 @@ class CNNConfig:
 
 
 @dataclasses.dataclass(frozen=True)
-class SparseCNN:
+class SparseCNN(CNNLifecycle):
     cfg: CNNConfig
 
     # ------------------------------------------------------------- defs
@@ -98,10 +194,8 @@ class SparseCNN:
         )
         return out
 
-    def init(self, key) -> dict:
-        layers = self.layers()
-        keys = jax.random.split(key, len(layers))
-        return {f"l{i}": m.init(k) for i, (m, k) in enumerate(zip(layers, keys))}
+    def named_layers(self) -> list:
+        return [(f"l{i}", m) for i, m in enumerate(self.layers())]
 
     # ---------------------------------------------------------- forward
     def __call__(self, params: dict, x: jax.Array) -> jax.Array:
@@ -293,87 +387,6 @@ class SparseCNN:
         pb.stage(f"l{n}", "linear", head.make_plan, params[f"l{n}"],
                  batch=batch, fused=fused)
         return pb.build()
-
-    def plan_set(self, params: dict, *, max_batch: Optional[int] = None,
-                 buckets: Optional[Sequence[int]] = None, dp: int = 1,
-                 tune: str = "cache", cache=None, top_k: int = 4,
-                 reps: int = 3):
-        """Freeze a bucketed serving plan set (DESIGN.md §11).
-
-        One :meth:`plan` per batch-size bucket, all sharing the same
-        tune cache and params fingerprint. ``buckets`` defaults to the
-        power-of-two ladder ``make_buckets(max_batch, dp=dp)``; ``dp``
-        (the data-parallel degree the set will be served at) forces
-        every bucket to shard evenly over a mesh's data axis. The
-        returned :class:`~repro.models.plan.PlanSet` serves any batch
-        size retrace-free after warmup: ragged batches pad up to the
-        nearest bucket and slice back, bit-identical to per-request
-        serving.
-        """
-        from repro.models.plan import build_plan_set, resolve_tune_cache
-
-        cache = resolve_tune_cache(tune, cache)  # one parse for all buckets
-        return build_plan_set(
-            self.cfg.name, params,
-            lambda b: self.plan(params, batch=b, tune=tune, cache=cache,
-                                top_k=top_k, reps=reps),
-            max_batch=max_batch, buckets=buckets, dp=dp,
-        )
-
-    def fallback_plan_set(self, params: dict, primary, *, verify: bool = True):
-        """Per-bucket degradation closures for the §15 self-healing tier:
-        re-stage ``primary``'s bucket ladder on the reference
-        (gather/integer-oracle) kernel path from the *same* quantized
-        params, verify bit-compat per bucket, and return the
-        ``{bucket: serve}`` mapping ``CNNServer(fallback=...)`` consumes.
-        The params fingerprint is content-based, so the ref restage pins
-        to the identical weights — a demoted bucket serves the same
-        numbers through a different backend, not a different model."""
-        import dataclasses as _dc
-
-        from repro.models.plan import fallback_closures
-
-        ref_model = SparseCNN(_dc.replace(self.cfg, kernel_mode="ref"))
-        ref_set = ref_model.plan_set(params, buckets=primary.buckets,
-                                     tune="off")
-        return fallback_closures(primary, ref_set, verify=verify)
-
-    # ------------------------------------------- the paper's technique
-    def constrain(self, params: dict, step=None, schedule: Optional[PruneSchedule] = None) -> dict:
-        out = {}
-        for i, m in enumerate(self.layers()):
-            out[f"l{i}"] = m.constrain(params[f"l{i}"], step, schedule)
-        return out
-
-    def compress(self, params: dict) -> dict:
-        out = {}
-        for i, m in enumerate(self.layers()):
-            out[f"l{i}"] = m.compress_params(params[f"l{i}"])
-        return out
-
-    def quantize(self, params: dict, stats=None) -> dict:
-        """INT8 serving conversion of compressed params (DESIGN.md §8).
-
-        ``stats`` (optional): per-layer calibration :class:`ActStats` from
-        ``apply(params, x_cal, collect_act_stats=True)`` — one per layer,
-        measured on the activation each layer *reads*, whose ``absmax``
-        becomes that layer's static per-tensor activation scale. Without
-        stats, activation scales are dynamic (computed per batch). Dense
-        layers (the C=3 stem) stay fp32, like the paper's uncompressed
-        first layer.
-        """
-        from repro.core.quant import act_scale_from_stats
-
-        layers = self.layers()
-        if stats is not None and len(stats) != len(layers):
-            raise ValueError(
-                f"calibration stats for {len(stats)} layers, model has {len(layers)}"
-            )
-        out = {}
-        for i, m in enumerate(layers):
-            scale = act_scale_from_stats(stats[i]) if stats is not None else None
-            out[f"l{i}"] = m.quantize(params[f"l{i}"], act_scale=scale)
-        return out
 
     # ------------------------------------------------------------ costs
     def layer_costs(self, batch: int, *, bits: int = 8, act_bits=None,

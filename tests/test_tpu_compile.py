@@ -1,4 +1,5 @@
-"""The serving-path kernels compile for a TPU v5e chip, at sparse-cnn-s shapes.
+"""The serving-path kernels compile for a TPU v5e chip, at the shapes of
+sparse-cnn-s and of ResNet-50 v1.5 (bucket 128, 224×224, 4/8 DBB).
 
 Interpret mode (every other kernel test) accepts block shapes and kernel
 bodies the TPU compiler refuses. These tests hand each kernel to that
@@ -23,6 +24,7 @@ from repro.kernels import ops
 
 FMT = DBBFormat(8, 3, "matrix")  # sparse-cnn-s: 3/8 DBB, shared patterns
 FMT_BW = DBBFormat(8, 3, None)   # paper-faithful per-column patterns
+FMT_R50 = DBBFormat(8, 4, "matrix")  # ResNet-50: 4/8 DBB
 
 
 @pytest.fixture(scope="module")
@@ -137,3 +139,106 @@ def test_bw_vdbb_matmul(one_chip, dtype):
         w = w.as_dbb()
     _assert_kernel(lambda a, w: ops.vdbb_matmul(a, w, interpret=False),
                    _spec((256, 512), dtype, s), w)
+
+
+# ---------------------------------------------------------------------------
+# ResNet-50 v1.5, bucket 128: every distinct launch of the served plan
+# ---------------------------------------------------------------------------
+
+B = 128
+P0, P1 = ((0, 0), (0, 0)), ((1, 1), (1, 1))
+
+
+def _kernels(hlo):
+    """Names of the Pallas kernels in a compiled program."""
+    return set(re.findall(r"%([a-z0-9_]+?)(?:\.\d+)? = \S+ custom-call\(", hlo))
+
+
+def test_resnet50_stem(one_chip):
+    """The 7×7/2 fp32 stem at 224² (padding 3) with its bias, ReLU and
+    requantize, then the 3×3/2 max-pool on the int8 codes: XLA's own conv
+    at full fp32 precision, as the model serves it (a Pallas conv would
+    read the 3-channel input padded to 128 lanes)."""
+    from repro.core.sparse_conv import DBBConv2d
+    from repro.models.resnet import SparseResNet, max_pool
+
+    from repro.configs import get_cnn_config
+
+    stem = SparseResNet(get_cnn_config("sparse-resnet50", sparsity=0.5)).stem()
+    assert stem.kernel_mode == "ref" and isinstance(stem, DBBConv2d)
+    s = one_chip
+
+    def fn(x, w, b, o):
+        run, _ = stem.make_plan({"w": w, "b": b}, batch=B, h=224, w=224, relu=True,
+                                out_scale=o, fused=True, tune="off")
+        return max_pool(run(x))
+
+    compiled = jax.jit(fn).lower(
+        _spec((B, 224, 224, 3), jnp.float32, s), _spec((7, 7, 3, 64), jnp.float32, s),
+        _spec((64,), jnp.float32, s), _spec((), jnp.float32, s)).compile()
+    hlo = compiled.as_text()
+    assert "convolution(" in hlo and "s8[128,56,56,64]" in hlo
+
+
+# (H, C, F, stride): c1 at each stage's map, and the projections (the first
+# at stride 1, the rest at stride 2)
+@pytest.mark.parametrize("h,c,f,stride", [
+    (56, 256, 64, 1), (28, 512, 128, 1), (14, 1024, 256, 1), (7, 2048, 512, 1),
+    (56, 64, 256, 1), (56, 256, 512, 2), (28, 512, 1024, 2), (14, 1024, 2048, 2)],
+    ids=["c1_56", "c1_28", "c1_14", "c1_7", "proj_56", "proj_56s2", "proj_28s2",
+         "proj_14s2"])
+def test_resnet50_conv1x1(one_chip, h, c, f, stride):
+    s = one_chip
+    hlo = _assert_kernel(
+        lambda x, w, a, b, o: ops.quant_conv(
+            x, w, 1, 1, a, bias=b, relu=stride == 1 and f < c, out_scale=o,
+            stride=stride, padding=P0, interpret=False),
+        _spec((B, h, h, c), jnp.int8, s), _weight(c, f, jnp.int8, s, FMT_R50),
+        _spec((), jnp.float32, s), _spec((f,), jnp.float32, s), _spec((), jnp.float32, s))
+    assert _kernels(hlo) == {"vdbb_im2col_conv_tc_1x1"}
+
+
+# (H, C, stride): c2 at each stage's map, stride 1, and the strided first
+# block of stages 2–4 (its input map twice the output's)
+@pytest.mark.parametrize("h,c,stride", [
+    (56, 64, 1), (28, 128, 1), (14, 256, 1), (7, 512, 1),
+    (56, 128, 2), (28, 256, 2), (14, 512, 2)],
+    ids=["56", "28", "14", "7", "56s2", "28s2", "14s2"])
+def test_resnet50_conv3x3(one_chip, h, c, stride):
+    s = one_chip
+    hlo = _assert_kernel(
+        lambda x, w, a, b, o: ops.quant_conv(
+            x, w, 3, 3, a, bias=b, relu=True, out_scale=o, stride=stride,
+            padding=P1, interpret=False),
+        _spec((B, h, h, c), jnp.int8, s), _weight(9 * c, c, jnp.int8, s, FMT_R50),
+        _spec((), jnp.float32, s), _spec((c,), jnp.float32, s), _spec((), jnp.float32, s))
+    assert _kernels(hlo) == {"vdbb_im2col_conv_tc"}
+
+
+# (H, C, F): each stage's c3 with the shortcut added in its flush; the last
+# flushes fp32 into pooling
+@pytest.mark.parametrize("h,c,f", [(56, 64, 256), (28, 128, 512), (14, 256, 1024),
+                                   (7, 512, 2048)], ids=["56", "28", "14", "7"])
+def test_resnet50_residual_conv1x1(one_chip, h, c, f):
+    s = one_chip
+    last = h == 7
+    hlo = _assert_kernel(
+        lambda x, w, a, b, r, rs, o: ops.quant_conv(
+            x, w, 1, 1, a, bias=b, relu=True, out_scale=None if last else o,
+            residual=r, residual_scale=rs, padding=P0, interpret=False),
+        _spec((B, h, h, c), jnp.int8, s), _weight(c, f, jnp.int8, s, FMT_R50),
+        _spec((), jnp.float32, s), _spec((f,), jnp.float32, s),
+        _spec((B, h, h, f), jnp.int8, s), _spec((), jnp.float32, s),
+        _spec((), jnp.float32, s))
+    assert _kernels(hlo) == {"vdbb_im2col_conv_tc_1x1_res"}
+    assert re.search(rf"= {'f32' if last else 's8'}\[128,{h},{h},{f}\]", hlo)
+
+
+def test_resnet50_head(one_chip):
+    """The 2048→1000 int8 head at M = 128 (N pads to 1024 lanes)."""
+    s = one_chip
+    hlo = _assert_kernel(
+        lambda x, w, a, b: ops.quant_matmul(x, w, a, bias=b, interpret=False),
+        _spec((B, 2048), jnp.float32, s), _weight(2048, 1000, jnp.int8, s, FMT_R50),
+        _spec((), jnp.float32, s), _spec((1000,), jnp.float32, s))
+    assert _kernels(hlo) == {"vdbb_matmul_tc"}

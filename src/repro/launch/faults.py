@@ -7,7 +7,8 @@ parameter, and every injector is deterministic — poison targets are
 registered by content digest, slow/kill faults fire on configured
 dispatch ordinals — so a chaos run replays exactly.
 
-Hook seams (called by the dispatcher thread):
+Hook seams (called by the server's threads: ``post_serve`` where a
+launched batch is fetched, the others where batches are launched):
 
 - ``on_tick(n_items)`` — once per dispatcher loop iteration that has
   work to process, *before* any batching. Raising here simulates a

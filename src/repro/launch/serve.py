@@ -253,7 +253,8 @@ def serve_cnn_continuous(args, model, qparams, xpool):
           f"health: {health['status']}")
     waited = s["queue_wait_s"] / max(s["dispatched_requests"], 1)
     print(f"[serve] host: queue wait {waited * 1e3:.2f}ms per request "
-          f"({s['dispatched_requests']} dispatched)  warm-up "
+          f"({s['dispatched_requests']} dispatched)  overlapped "
+          f"{s['overlapped_batches']}/{s['batches']} batches  warm-up "
           f"{s['warmup_s']:.2f}s  gc {s['gc_collections']} passes "
           f"{s['gc_s'] * 1e3:.1f}ms")
     problems = [f"{v} request(s) {k}" for k, v in sorted(failures.items())

@@ -12,8 +12,13 @@ dispatch**:
   latency/throughput knob pair of a continuous-batching server.
 - Each aggregated batch is served through a
   :class:`~repro.models.plan.PlanSet`: pad up to the nearest batch-size
-  bucket, dispatch that bucket's pre-compiled frozen plan, slice the
-  padding off, and scatter the per-request slices back into the futures.
+  bucket, launch that bucket's pre-compiled frozen plan, and hand the
+  launched batch to a completion thread, which fetches the logits, slices
+  the padding off, and scatters the per-request slices back into the
+  futures. The dispatcher launches the next batch while the one before
+  it is still on the device: at most two batches are launched but not
+  yet fetched, so the host's work on one batch overlaps the device's
+  work on the other.
   Because every bucket was compiled at warmup, sustained variable load
   runs **zero retraces** — a contract the server *measures* (plans count
   their traces) rather than assumes, and bit-identical to serving every
@@ -72,8 +77,10 @@ and ``repro.launch.serve --server`` drive identical traffic shapes.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import queue as _queue
+import sys
 import threading
 import time
 from concurrent.futures import Future
@@ -294,6 +301,8 @@ class ServerStats:
     warmup_s: float = 0.0       # CNNServer.warmup's plan_set.warmup, all buckets
     gc_collections: int = 0     # garbage-collector passes while running
     gc_s: float = 0.0           # and the seconds they took
+    # bucket dispatches launched while an earlier batch was still unfetched
+    overlapped_batches: int = 0
 
     @property
     def accounted(self) -> int:
@@ -346,11 +355,15 @@ class ServerStats:
             "warmup_s": self.warmup_s,
             "gc_collections": self.gc_collections,
             "gc_s": self.gc_s,
+            "overlapped_batches": self.overlapped_batches,
         }
 
 
 # --------------------------------------------------------------- server
 _STOP = object()
+# batches launched but not yet fetched: enough to hide host work shorter
+# than a batch's device time; a third would only hold more inputs in HBM
+_DEPTH = 2
 
 
 class CNNServer:
@@ -379,12 +392,17 @@ class CNNServer:
     come back non-finite, and ``faults`` installs a deterministic
     injector (``repro.launch.faults``) for chaos testing.
 
-    The dispatcher blocks each batch to completion before resolving its
-    futures, so a request's measured latency (arrival → result ready)
-    includes queueing, padding, dispatch, and device time — what a
-    client would see. One batch is in flight at a time; jax's own async
-    dispatch still overlaps host-side aggregation of the next batch with
-    device compute of the current one.
+    Two threads serve: the dispatcher admits, batches, assembles and
+    launches each batch (``PlanSet.launch``, with the input copy), then
+    hands it to the completion thread, which fetches its logits
+    (``PlanSet.fetch``) and settles its futures — client callbacks run
+    there. The dispatcher launches batch N+1 while batch N is still on
+    the device, up to two batches launched but not yet fetched, so
+    completion, assembly and the input copy overlap device work. A lone
+    request is fetched as soon as its device work ends. A request's
+    measured latency (arrival → result ready) includes queueing,
+    padding, dispatch, the batch ahead of it on the device, and its own
+    device time — what a client would see.
     """
 
     def __init__(self, plan_set, *, max_batch: Optional[int] = None,
@@ -426,7 +444,9 @@ class CNNServer:
         # Supervisor requeues them across the restart. Requests inside a
         # dispatch at crash time always fail typed (at-most-once).
         self.on_crash = on_crash
-        self._inflight: dict = {}    # id(p) -> p, dispatcher thread only
+        # id(p) -> p for the batch the dispatcher is launching (or
+        # bisecting); a launched batch leaves it for the completion thread
+        self._inflight: dict = {}
         self._put = None
         self._shard = None
         if mesh is not None:
@@ -450,6 +470,14 @@ class CNNServer:
         self._degraded = False          # last dispatch hit a fault
         self._depth = 0                 # admitted samples not yet resolved
         self._bucket_time_s: Optional[float] = None  # EMA of serve time
+        self._last_fetch = 0.0          # when the latest fetch ended
+        # how late the dispatcher's timed waits have woken, at most: a
+        # deadline flush leaves this much room (at least a GIL switch)
+        self._wake_slack_s = sys.getswitchinterval()
+        self._unfetched = 0             # batches launched, not yet fetched
+        self._slot = threading.Condition(self._lock)  # signals _unfetched < _DEPTH
+        self._launched: _queue.Queue = _queue.Queue()  # to the completion thread
+        self._completer: Optional[threading.Thread] = None
         self._ran = False
         self._gc_span = None  # the host.gc span of the pass under way
         self._gc_t0 = 0.0
@@ -503,7 +531,13 @@ class CNNServer:
         self._ran = True
         self._abandon.clear()
         self._closed = False
+        self._unfetched = 0  # a crash may have left a launch uncounted
+        self._launched = _queue.Queue()
         gc.callbacks.append(self._on_gc)
+        self._completer = threading.Thread(
+            target=self._complete_loop, name="cnn-serve-complete", daemon=True
+        )
+        self._completer.start()
         self._thread = threading.Thread(
             target=self._loop, name="cnn-serve-dispatch", daemon=True
         )
@@ -552,7 +586,7 @@ class CNNServer:
         xb = np.zeros((cap,) + tuple(sample_shape), dtype)
         t0 = time.monotonic()
         self.plan_set.serve(xb, put=self._put)  # warmed: no new trace
-        self._note_service_time(time.monotonic() - t0)
+        self._note_service_time(t0, time.monotonic())
         self.stats.warmup_traces = self.plan_set.trace_count
         return self.stats.warmup_traces
 
@@ -620,10 +654,18 @@ class CNNServer:
     def serve_batch(self, x):
         """Synchronous bucketed serve (no queue): pad → bucket plan →
         slice, through the mesh sharding when set. The dispatcher and
-        direct callers (tests/bench baselines) share this one path,
-        including the §15 per-bucket demotion routing."""
-        return self.plan_set.serve(x, put=self._put, on_dispatch=self._record,
-                                   dispatch=self._bucket_dispatch)
+        direct callers (tests/bench baselines) share this one path
+        (:meth:`_launch_plans`, then ``PlanSet.fetch``), including the
+        §15 per-bucket demotion routing."""
+        return self.plan_set.fetch(self._launch_plans(x))
+
+    def _launch_plans(self, x):
+        """``PlanSet.launch`` of ``x`` on the serving set, read once: a
+        batch runs wholly on the set that launched it, whatever a
+        concurrent ``swap_plan_set`` does."""
+        ps = self.plan_set
+        return ps.launch(x, put=self._put, on_dispatch=self._record,
+                         dispatch=functools.partial(self._bucket_dispatch, ps))
 
     def requeue(self, pendings: List[_Pending]) -> int:
         """Re-enqueue requests a crash handed back (``on_crash``) after a
@@ -669,7 +711,7 @@ class CNNServer:
     def swap_plan_set(self, new_set, *, fallback=None) -> None:
         """Atomically replace the serving :class:`PlanSet` (the §15 hot
         reload). The dispatcher reads ``plan_set`` once per batch, so the
-        swap lands *between* bucket dispatches: in-flight batches finish
+        swap lands *between* batch launches: launched batches finish
         on the old plans (still alive, still compiled), every later batch
         dispatches the new ones — zero dropped or hung requests. The
         caller must pass an already-warmed set (``Supervisor.reload``
@@ -696,7 +738,7 @@ class CNNServer:
             self._demoted.clear()
 
     # ------------------------------------------- §15 bucket degradation
-    def _bucket_dispatch(self, b: int, xb):
+    def _bucket_dispatch(self, ps, b: int, xb):
         """Per-bucket dispatch with kernel-fallback demotion: a healthy
         bucket runs its compiled plan; ``demote_after`` consecutive
         compiled-dispatch faults demote the bucket to its ref fallback
@@ -715,7 +757,7 @@ class CNNServer:
         try:
             if self._faults is not None:
                 self._faults.pre_bucket(b)  # compiled-backend fault seam
-            y = self.plan_set.plans[b].serve(xb)
+            y = ps.plans[b].serve(xb)
         except Exception as e:  # noqa: BLE001 — strike, demote, or bubble
             if dem is not None:  # failed probe: stay demoted, keep serving
                 return self._fallback[b](xb)
@@ -817,28 +859,50 @@ class CNNServer:
         buckets_ahead = max(1, -(-self._depth // self.max_batch))
         return self.max_wait_s + buckets_ahead * bt
 
-    def _note_service_time(self, dt: float) -> None:
+    def _note_service_time(self, launched: float, done: float) -> None:
+        """One batch's service time into the EMA: from its launch, or from
+        the previous batch's fetch where that ended later, to its own
+        fetch. With batches overlapped that is the time between
+        successive completions, one batch's device time, not two."""
         with self._lock:
+            dt = done - max(launched, self._last_fetch)
+            self._last_fetch = done
             bt = self._bucket_time_s
             self._bucket_time_s = dt if bt is None else 0.8 * bt + 0.2 * dt
 
+    def _flush_lead_s(self) -> float:
+        """How long before a request's deadline its batch must flush: a
+        service time for each batch ahead of it on the device and one for
+        its own, plus the latest the dispatcher has woken from a timed
+        wait (the flush fires on the dispatcher's own clock)."""
+        with self._lock:
+            bt = self._bucket_time_s or 0.0
+            return bt * (1 + self._unfetched) + self._wake_slack_s
+
     def _record(self, bucket: int, n_real: int) -> None:
-        self.stats.batches += 1
-        self.stats.served_samples += bucket
-        self.stats.padded_samples += bucket - n_real
-        self.stats.bucket_counts[bucket] = self.stats.bucket_counts.get(bucket, 0) + 1
+        with self._lock:
+            self.stats.batches += 1
+            self.stats.served_samples += bucket
+            self.stats.padded_samples += bucket - n_real
+            self.stats.bucket_counts[bucket] = (
+                self.stats.bucket_counts.get(bucket, 0) + 1)
 
     def _loop(self) -> None:
         try:
             self._loop_inner()
         except BaseException as e:  # noqa: BLE001 — supervised: fail futures
             self._crash(e)
+        finally:
+            # every launched batch is fetched and settled before the
+            # dispatcher thread ends, so a joined dispatcher leaves none
+            self._launched.put(None)
+            self._completer.join()
 
     def _loop_inner(self) -> None:
         stop = None
         while stop is None:
             timeout = None
-            est = self._bucket_time_s or 0.0
+            est = self._flush_lead_s()
             dl = self._batcher.deadline(est)
             if dl is not None:
                 timeout = max(0.0, dl - time.monotonic())
@@ -847,6 +911,10 @@ class CNNServer:
                     items = [self._q.get(timeout=timeout)]
             except _queue.Empty:
                 items = []  # max-wait expired with nothing new queued
+                if timeout:
+                    late = time.monotonic() - dl
+                    with self._lock:
+                        self._wake_slack_s = max(self._wake_slack_s, late)
             # Greedily drain whatever arrived while the last batch was in
             # flight: a backlog coalesces into full buckets here instead
             # of degenerating into max-wait-expired singles.
@@ -885,10 +953,23 @@ class CNNServer:
         for p in remainder:  # non-drain or abandoned drain: cancel
             self._cancel(p)
 
+    def _release_slot(self) -> None:
+        with self._lock:
+            self._unfetched -= 1
+            self._slot.notify()
+
     def _dispatch(self, batch: List[_Pending]) -> None:
-        """Expire what already missed its deadline — *before* wasting a
-        bucket dispatch — then run the survivors."""
+        """Wait for a launch slot (fewer than ``_DEPTH`` batches
+        unfetched), expire what already missed its deadline — *before*
+        wasting a bucket dispatch — then launch the survivors and hand
+        them to the completion thread."""
+        with self._lock:
+            while self._unfetched >= _DEPTH:
+                self._slot.wait()
+            overlapped = self._unfetched > 0
+            self._unfetched += 1
         if self._abandon.is_set():  # stop(timeout_s=) gave up: cancel, fast
+            self._release_slot()
             for p in batch:
                 self._cancel(p)
             return
@@ -902,29 +983,77 @@ class CNNServer:
                     kind="expired")
             else:
                 live.append(p)
-        if live:
-            # At-most-once bookkeeping (§15): everything past this line
-            # is "inside a dispatch" — if the dispatcher dies before a
-            # request reaches a terminal outcome, _crash fails it typed
-            # instead of handing it to the supervisor for a requeue (a
-            # re-execution could double side effects / double-serve).
+        if not live:
+            self._release_slot()
+            return
+        # At-most-once bookkeeping (§15): everything past this line is
+        # "inside a dispatch" — if the dispatcher dies before a request
+        # reaches a terminal outcome, _crash fails it typed instead of
+        # handing it to the supervisor for a requeue (a re-execution could
+        # double side effects / double-serve).
+        with self._lock:
             for p in live:
                 self._inflight[id(p)] = p
                 self.stats.queue_wait_s += now - p.arrival
             self.stats.dispatched_requests += len(live)
-            self._run(live)
-            self._inflight.clear()
+        with TraceAnnotation("serve.batch", requests=len(live),
+                             samples=sum(p.n for p in live)):
+            try:
+                launched, t0 = self._launch(live)
+            except Exception as e:  # noqa: BLE001 — isolate, don't kill the loop
+                err = e
+            else:
+                with self._lock:
+                    if overlapped:
+                        self.stats.overlapped_batches += len(launched.parts)
+                    for p in live:  # the completion thread settles them now
+                        self._inflight.pop(id(p), None)
+                self._launched.put((live, launched, t0))
+                return
+        try:
+            self._isolate(live, err)
+        finally:
+            self._release_slot()
+
+    def _complete_loop(self) -> None:
+        """The completion thread: fetch each launched batch in launch
+        order, free its slot, and settle its futures."""
+        while True:
+            item = self._launched.get()
+            if item is None:
+                return
+            batch, launched, t0 = item
+            try:
+                try:
+                    y = self._fetch(batch, launched, t0)
+                except Exception as e:  # noqa: BLE001 — isolate this batch
+                    self._isolate(batch, e)  # its slot held till settled
+                    continue
+                finally:
+                    self._release_slot()
+                self._resolve(batch, y)
+            except Exception as e:  # noqa: BLE001 — never strand a waiter
+                err = ServerCrashed(f"completion failed: {e!r}")
+                for p in batch:
+                    if not p.future.done():
+                        self._fail(p, err, kind="failed")
 
     def _run(self, batch: List[_Pending]) -> None:
+        """One batch start to finish on this thread — launch, fetch,
+        settle — bisected on a fault (the isolation path)."""
         with TraceAnnotation("serve.batch", requests=len(batch),
                              samples=sum(p.n for p in batch)):
             try:
-                y = self._serve(batch)
+                y = self._fetch(batch, *self._launch(batch))
             except Exception as e:  # noqa: BLE001 — isolate, don't kill the loop
                 err = e
             else:
                 self._resolve(batch, y)
                 return
+        self._isolate(batch, err)
+
+    def _isolate(self, batch: List[_Pending], err: Exception) -> None:
+        """Pin a batch's fault on its poison request(s)."""
         if len(batch) == 1:
             self._fail(p=batch[0], exc=err, kind="failed")
             return
@@ -937,15 +1066,16 @@ class CNNServer:
         self._run(batch[:mid])
         self._run(batch[mid:])
 
-    def _serve(self, batch: List[_Pending]):
-        """One batch's logits (numpy), through the fault seams."""
+    def _launch(self, batch: List[_Pending]):
+        """Assemble one batch and launch its plans, through the fault
+        seams; returns the launch and when it started."""
         if self._faults is not None:
             self._faults.pre_dispatch(batch)  # plan-exception seam
         # Host-side assembly (numpy): concatenating/padding/slicing k
         # request arrays as jax ops would XLA-compile a fresh glue op per
         # (k, sizes) signature mid-traffic — a latency spike the warmed
         # bucket plans exist to avoid. As numpy it is a memcpy, and
-        # serve_batch's host fast path keeps it that way end to end (the
+        # the plan set's host fast path keeps it that way end to end (the
         # only device work is the bucket dispatch).
         with TraceAnnotation("serve.assemble"):
             xs = [np.asarray(p.x) for p in batch]
@@ -953,16 +1083,20 @@ class CNNServer:
         if self._faults is not None:
             xb = self._faults.pre_serve(batch, xb)  # slow/NaN seam
         t0 = time.monotonic()
-        y = self.serve_batch(xb)  # numpy in -> numpy out, completed
-        self._note_service_time(time.monotonic() - t0)
+        return self._launch_plans(xb), t0  # numpy in: numpy out at fetch
+
+    def _fetch(self, batch: List[_Pending], launched, t0: float):
+        """A launched batch's logits (numpy), through the fault seam."""
+        y = self.plan_set.fetch(launched)
+        self._note_service_time(t0, time.monotonic())
         if self._faults is not None:
             y = self._faults.post_serve(batch, y)  # NaN-activation seam
         return y
 
     def _resolve(self, batch: List[_Pending], y) -> None:
         """Slice each request's logits off ``y`` and settle its future
-        (client callbacks run here, on the dispatcher thread)."""
-        with TraceAnnotation("serve.complete"):
+        (client callbacks run here, on the thread that fetched)."""
+        with TraceAnnotation("serve.complete", requests=len(batch)):
             done = time.monotonic()
             off = 0
             clean = True
@@ -1001,38 +1135,37 @@ class CNNServer:
             self.stats.gc_s += time.monotonic() - self._gc_t0
 
     # ----------------------------------------------- terminal outcomes
-    def _complete(self, p: _Pending, y, done: float) -> None:
+    def _settle_locked(self, p: _Pending) -> None:
         self._inflight.pop(id(p), None)
+        self._depth -= p.n
+        self._space.notify_all()
+
+    def _complete(self, p: _Pending, y, done: float) -> None:
         with self._lock:
+            self._settle_locked(p)
             self.stats.latencies_s.append(done - p.arrival)
             self.stats.completed += p.n
             self.stats.last_done = done
-            self._depth -= p.n
-            self._space.notify_all()
         try:
             p.future.set_result(y)
         except Exception:  # cancelled by a racing stop(): already terminal
             pass
 
     def _fail(self, p: _Pending, exc: Exception, kind: str) -> None:
-        self._inflight.pop(id(p), None)
         with self._lock:
+            self._settle_locked(p)
             setattr(self.stats, kind, getattr(self.stats, kind) + p.n)
             if kind == "failed":
                 self._degraded = True
-            self._depth -= p.n
-            self._space.notify_all()
         try:
             p.future.set_exception(exc)
         except Exception:
             pass
 
     def _cancel(self, p: _Pending) -> None:
-        self._inflight.pop(id(p), None)
         with self._lock:
+            self._settle_locked(p)
             self.stats.failed += p.n  # never served; the identity closes
-            self._depth -= p.n
-            self._space.notify_all()
         p.future.cancel()  # waiters get CancelledError, never a hang
 
     def _crash(self, exc: BaseException) -> None:
@@ -1040,19 +1173,22 @@ class CNNServer:
         with :class:`ServerCrashed` instead of stranding their waiters.
         ``submit`` raises the same from then on (until a restart).
 
-        §15 split: requests *inside a dispatch* at crash time always fail
-        typed here (at-most-once — a requeue could silently re-execute
-        them), while admitted-but-undispatched requests are handed to the
-        ``on_crash`` callback (the Supervisor requeues them across the
-        restart) when one is installed, and failed typed otherwise."""
+        §15 split: requests *inside a dispatch* at crash time — the batch
+        the dispatcher was assembling, launching or bisecting — always
+        fail typed here (at-most-once — a requeue could silently
+        re-execute them), while admitted-but-undispatched requests are
+        handed to the ``on_crash`` callback (the Supervisor requeues them
+        across the restart) when one is installed, and failed typed
+        otherwise. Batches already launched belong to the completion
+        thread, which fetches and settles them before the dispatcher
+        thread ends: they ran once, and none is requeued."""
         with self._lock:
             self._crashed = exc
             self._closed = True
             self._space.notify_all()
+            inflight = list(self._inflight.values())
         err = ServerCrashed(f"dispatcher crashed: {exc!r}")
         err.__cause__ = exc if isinstance(exc, Exception) else None
-        inflight = list(self._inflight.values())
-        self._inflight.clear()
         for p in inflight:  # at-most-once: never silently re-executed
             self._fail(p, err, kind="failed")
         stranded = self._batcher.take()

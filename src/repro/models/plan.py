@@ -172,6 +172,16 @@ def make_buckets(max_batch: int, *, dp: int = 1) -> Tuple[int, ...]:
 
 
 @dataclasses.dataclass(frozen=True)
+class Launched:
+    """What :meth:`PlanSet.launch` enqueued: each bucket chunk's pending
+    result with its real rows and bucket, ``(y, n_real, bucket)``, in
+    order, and whether the input was numpy (results come back as numpy)."""
+
+    host: bool
+    parts: Tuple[Tuple[Any, int, int], ...]
+
+
+@dataclasses.dataclass(frozen=True)
 class PlanSet:
     """A bucket ladder of frozen plans for one model (DESIGN.md §11).
 
@@ -216,16 +226,15 @@ class PlanSet:
                 return b
         return None
 
-    def serve(self, x, *, put=None, on_dispatch=None, dispatch=None):
-        """Bucketed serving of any batch size.
+    def launch(self, x, *, put=None, on_dispatch=None,
+               dispatch=None) -> "Launched":
+        """Enqueue the bucketed plans for any batch size; returns at once.
 
-        A numpy ``x`` takes the **host-assembly fast path**: chunk/pad/
-        slice run as numpy on the host and the result comes back as
-        numpy — only the pre-warmed bucket-shaped plan dispatch ever
-        touches the device, so no glue op (pad, slice, concat) can
-        trigger a first-occurrence XLA compile mid-traffic. This is the
-        path the serving tier dispatches on. A jax ``x`` stays on-device
-        end to end and returns jax.
+        The batch is chunked at the largest bucket and each chunk is
+        zero-padded up to the smallest bucket that fits and enqueued on its
+        bucket's plan (for a numpy chunk the enqueue includes the
+        synchronous host→device input copy). :meth:`fetch` of the returned
+        handle waits for the results.
 
         ``put`` (optional) maps each padded chunk onto devices — the
         serving tier injects ``device_put`` to a mesh's data-axis
@@ -242,7 +251,7 @@ class PlanSet:
         host = isinstance(x, np.ndarray)
         xp = np if host else jnp
         cap = self.buckets[-1]
-        outs = []
+        parts = []
         i = 0
         while i < n:
             take = min(cap, n - i)
@@ -260,13 +269,38 @@ class PlanSet:
                 with TraceAnnotation("plan.launch"):
                     y = (self.plans[b].serve(xb) if dispatch is None
                          else dispatch(b, xb))
-                if host:
-                    # block + gather once, slice on the host
-                    with TraceAnnotation("plan.fetch"):
-                        y = np.asarray(y)
-                outs.append(y if take == b else y[:take])
+            parts.append((y, take, b))
             i += take
-        return outs[0] if len(outs) == 1 else xp.concatenate(outs, axis=0)
+        return Launched(host, tuple(parts))
+
+    @staticmethod
+    def fetch(launched: "Launched"):
+        """Wait for a :meth:`launch`'s results and slice the padding off:
+        numpy for a numpy input (each chunk blocked on and gathered once,
+        sliced on the host), jax, still on the device, for a jax input."""
+        outs = []
+        for y, take, b in launched.parts:
+            if launched.host:
+                with TraceAnnotation("plan.fetch", bucket=b):
+                    y = np.asarray(y)
+            outs.append(y if take == b else y[:take])
+        if len(outs) == 1:
+            return outs[0]
+        return (np if launched.host else jnp).concatenate(outs, axis=0)
+
+    def serve(self, x, *, put=None, on_dispatch=None, dispatch=None):
+        """Bucketed serving of any batch size: ``fetch(launch(x))``.
+
+        A numpy ``x`` takes the **host-assembly fast path**: chunk/pad/
+        slice run as numpy on the host and the result comes back as
+        numpy — only the pre-warmed bucket-shaped plan dispatch ever
+        touches the device, so no glue op (pad, slice, concat) can
+        trigger a first-occurrence XLA compile mid-traffic. This is the
+        path the serving tier dispatches on. A jax ``x`` stays on-device
+        end to end and returns jax. The keywords are :meth:`launch`'s.
+        """
+        return self.fetch(self.launch(x, put=put, on_dispatch=on_dispatch,
+                                      dispatch=dispatch))
 
     def __call__(self, x):
         return self.serve(x)

@@ -1,9 +1,10 @@
 """What the serving path records about itself (DESIGN.md §11, §14).
 
 - Host spans (``jax.profiler.TraceAnnotation``): under a profiler, every
-  dispatched batch is one ``serve.batch`` span holding ``serve.assemble``,
-  ``plan.dispatch`` (itself holding ``plan.launch`` and ``plan.fetch``) and
-  ``serve.complete``; warm-up marks ``plan.warmup`` per bucket, clients
+  dispatched batch is one ``serve.batch`` span on the dispatcher thread
+  holding ``serve.assemble`` and ``plan.dispatch`` (itself holding
+  ``plan.launch``), and one ``plan.fetch`` and one ``serve.complete`` on
+  the completion thread; warm-up marks ``plan.warmup`` per bucket, clients
   ``serve.submit``, the idle dispatcher ``serve.wait``, and the garbage
   collector ``host.gc``.
 - Counters (``ServerStats``, always on): queue wait per dispatched request,
@@ -51,17 +52,19 @@ def served():
 
 
 def _spans(trace_dir) -> list:
-    """(thread, name, start_ns, end_ns, attributes) of the serving spans."""
+    """(thread, name, start_ns, end_ns, attributes) of the serving spans;
+    a thread is its line's name and place (every Python thread's line is
+    named alike)."""
     (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
                                      "*.xplane.pb"))
     out = []
     for plane in ProfileData.from_file(path).planes:
         if plane.name != "/host:CPU":
             continue
-        for line in plane.lines:
+        for i, line in enumerate(plane.lines):
             for e in line.events:
                 if e.name in SPANS:
-                    out.append((line.name, e.name, e.start_ns,
+                    out.append((f"{line.name}/{i}", e.name, e.start_ns,
                                 e.start_ns + e.duration_ns, dict(e.stats)))
     return out
 
@@ -95,14 +98,30 @@ def test_serving_spans_nest_once_per_batch(served, tmp_path):
     assert len(batches) == srv.stats.batches > 0  # every request fits a bucket
     assert sum(b[4]["requests"] for b in batches) == len(sizes)
     assert sum(b[4]["samples"] for b in batches) == sum(sizes)
+    dispatches = []
     for batch in batches:
-        for name in ("serve.assemble", "plan.dispatch", "serve.complete"):
+        for name in ("serve.assemble", "plan.dispatch"):
             assert len(_inside(spans, batch, name)) == 1, name
         (dispatch,) = _inside(spans, batch, "plan.dispatch")
         assert dispatch[4]["bucket"] in plan_set.buckets
         assert 1 <= dispatch[4]["n_real"] <= dispatch[4]["bucket"]
-        for name in ("plan.launch", "plan.fetch"):
-            assert len(_inside(spans, dispatch, name)) == 1, name
+        assert len(_inside(spans, dispatch, "plan.launch")) == 1
+        for name in ("plan.fetch", "serve.complete"):  # not on this thread
+            assert not _inside(spans, batch, name), name
+        dispatches.append(dispatch)
+    # one fetch and one completion per batch, in launch order, on the
+    # completion thread: batch k's fetch has its bucket, and its
+    # completion follows that fetch
+    first = lambda rows: sorted(rows, key=lambda s: s[2])  # noqa: E731
+    serving = [s for s in named("plan.fetch") if s[0] != named("plan.warmup")[0][0]]
+    fetches, completes = first(serving), first(named("serve.complete"))
+    assert len(fetches) == len(completes) == len(batches)
+    assert ([f[4]["bucket"] for f in fetches]
+            == [d[4]["bucket"] for d in first(dispatches)])
+    assert ([c[4]["requests"] for c in completes]
+            == [b[4]["requests"] for b in first(batches)])
+    for fetch, complete in zip(fetches, completes):
+        assert fetch[0] == complete[0] and fetch[3] <= complete[2]
     warm = named("plan.warmup")
     assert sorted(s[4]["bucket"] for s in warm) == list(plan_set.buckets)
     assert len(named("serve.submit")) == len(sizes)
@@ -133,7 +152,7 @@ def test_server_host_counters(served):
     assert s.warmup_s > 0
     summary = s.summary()
     for key in ("queue_wait_s", "dispatched_requests", "warmup_s",
-                "gc_collections", "gc_s"):
+                "gc_collections", "gc_s", "overlapped_batches"):
         assert summary[key] == getattr(s, key)
     assert "mean_us" not in summary
 

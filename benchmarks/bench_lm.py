@@ -109,7 +109,7 @@ def run(report):
     qparams = model.quantize(params, stats)
 
     unplanned = jax.jit(lambda t: model.forward(qparams, {"tokens": t}))
-    plan = model.plan(qparams, batch=batch, seq=seq, tune="off")
+    plan = model.plan(qparams, batch=batch, seq=seq)
     bit = bool((plan(tokens) == unplanned(tokens)).all())
     assert bit, "frozen plan diverged from the unplanned forward"
     t_p, t_u, nz = _paired(lambda: plan.serve(tokens), lambda: unplanned(tokens))

@@ -67,14 +67,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import core
-from repro.kernels.autotune import interleaved_medians
 from repro.launch.faults import FaultInjected, FaultInjector, \
     corrupt_checkpoint
 from repro.launch.server import CNNServer, NumericalFault, Overloaded, \
     ServerCrashed, auto_rate, burst_arrivals, poisson_arrivals
 from repro.launch.supervisor import Supervisor
-from repro.xla_utils import median_time_us
+from repro.xla_utils import interleaved_time_us, median_time_us
 
 OUT_PATH = pathlib.Path(__file__).resolve().parents[1] / "BENCH_serve.json"
 # Margins shared with benchmarks/check_regression.py via the committed
@@ -114,7 +112,6 @@ def run(report, smoke: bool = True):
     from repro.configs import smoke_cnn_config
     from repro.models.cnn import SparseCNN
 
-    core.clear_tuned()
     cfg = dataclasses.replace(
         smoke_cnn_config("sparse-cnn-tiny", sparsity=0.625), kernel_mode="pallas"
     )
@@ -126,7 +123,7 @@ def run(report, smoke: bool = True):
     qparams = model.quantize(params, stats)
 
     max_batch = 8
-    plan_set = model.plan_set(qparams, max_batch=max_batch, tune="off")
+    plan_set = model.plan_set(qparams, max_batch=max_batch)
     plan_set.warmup(sample_shape)
     results = {
         "backend": jax.default_backend(),
@@ -149,7 +146,7 @@ def run(report, smoke: bool = True):
     xb = xpool[:max_batch]
     unplanned = jax.jit(lambda x: model.apply(qparams, x))
     jax.block_until_ready(unplanned(xb))  # compile outside the timing
-    plan_us, unplanned_us = interleaved_medians(
+    plan_us, unplanned_us = interleaved_time_us(
         lambda: plan_set.plans[max_batch].serve(xb), lambda: unplanned(xb),
         warmup=2, reps=9,
     )
@@ -309,8 +306,7 @@ def _selfheal_reload(report, model, qparams, plan_set, xpool, sample_shape,
     srv = CNNServer(plan_set, max_wait_ms=max_wait_ms)
     sup = Supervisor(
         srv,
-        rebuild=lambda tree: model.plan_set(tree, max_batch=max_batch,
-                                            tune="off"),
+        rebuild=lambda tree: model.plan_set(tree, max_batch=max_batch),
         template=qparams,
     )
     out = {"hung": 0}

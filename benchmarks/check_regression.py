@@ -21,14 +21,6 @@ Every artifact is first checked against a minimal schema (required keys
 present, numbers finite and positive) so a truncated or hand-edited file
 fails loudly instead of silently passing vacuous gates.
 
-``BENCH_autotune.json`` is validated as a second-line gate: the
-confirmation-pass contract (``tuned_us ≤ default_us`` — enforced by the
-search's interleaved head-to-head, with non-replicating winners demoted
-to the default) must hold in the artifact, the independent re-measured
-numbers must stay within a loose sanity margin, and plan serving must
-have been bit-identical. The bench asserts the same things first; this
-gate catches a stale or hand-edited artifact.
-
 ``BENCH_serve.json`` gates the §11 serving tier on **measured wall
 time** (the first slice of the ROADMAP "wall time is the contract"
 item): the frozen bucket plan must not lose to the jitted-once
@@ -63,7 +55,7 @@ and no slower than — the jitted unplanned forward. The int8 GEMM
 numbers are recorded but not gated (XLA:CPU has no native int8 path).
 
 Exit code 1 on any regression — run after ``python -m benchmarks.run
---smoke`` (which rewrites all four artifacts).
+--smoke`` (which rewrites all three artifacts).
 """
 from __future__ import annotations
 
@@ -76,9 +68,6 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 BASELINES = pathlib.Path(__file__).resolve().parent / "bench_baselines.json"
 _BASE = json.loads(BASELINES.read_text())
 TOLERANCE = 0.02   # absolute saved_frac slack for rounding
-# wall-time margins shared with bench_autotune via the baselines file
-NOISE_MARGIN = _BASE["autotune_noise_margin"]
-SANITY_MARGIN = _BASE["autotune_sanity_margin"]
 WALL_MARGIN = _BASE["fused_wall_margin"]
 NOISE_CAP = _BASE["fused_noise_cap"]
 HARD_FLOOR = 0.25  # the §9 acceptance criterion, regardless of baseline
@@ -104,12 +93,6 @@ SCHEMAS = {
         "noise_frac.cnn": "frac",
         "harness.reps": "num",
         "harness.stat": str,
-    },
-    "BENCH_autotune.json": {
-        "odd_gemms[].tuned_us": "num",
-        "odd_gemms[].default_us": "num",
-        "smoke_cnn.plan_us": "num",
-        "smoke_cnn.default_us": "num",
     },
     "BENCH_serve.json": {
         "plan_us": "num",
@@ -238,38 +221,6 @@ def check_fused() -> list:
                 f"{WALL_MARGIN} widened by measured noise "
                 f"{noise.get(nkey)})"
             )
-    return errors
-
-
-def check_autotune() -> list:
-    errors = []
-    path = ROOT / "BENCH_autotune.json"
-    if not path.exists():
-        return []  # informational artifact; bench_autotune asserts on its own
-    data = json.loads(path.read_text())
-    errors += schema_errors(path.name, data)
-    if errors:
-        return errors
-    for g in data.get("odd_gemms", []):
-        name = f"autotune/gemm_{g['m']}x{g['k']}x{g['n']}"
-        if g["tuned_us"] > g["default_us"]:
-            errors.append(  # the confirmation-pass contract was violated
-                f"{name}: tuned {g['tuned_us']}us > default {g['default_us']}us"
-            )
-        rt, rd = g.get("remeasured_tuned_us"), g.get("remeasured_default_us")
-        if rt is not None and rd is not None and rt > rd * SANITY_MARGIN:
-            errors.append(
-                f"{name}: independent re-measure {rt}us > {rd}us "
-                f"(sanity margin {SANITY_MARGIN}x)"
-            )
-    cnn = data.get("smoke_cnn") or {}
-    if cnn and cnn["plan_us"] > cnn["default_us"] * NOISE_MARGIN:
-        errors.append(
-            f"autotune/smoke_cnn: plan {cnn['plan_us']}us > unplanned "
-            f"{cnn['default_us']}us (margin {NOISE_MARGIN}x)"
-        )
-    if cnn and not cnn.get("bit_identical", False):
-        errors.append("autotune/smoke_cnn: plan serving not bit-identical")
     return errors
 
 
@@ -461,8 +412,7 @@ def check_lm() -> list:
 
 
 def main() -> int:
-    errors = check_fused() + check_autotune() + check_serve() \
-        + check_chaos() + check_lm()
+    errors = check_fused() + check_serve() + check_chaos() + check_lm()
     for e in errors:
         print(f"REGRESSION: {e}", file=sys.stderr)
     if not errors:

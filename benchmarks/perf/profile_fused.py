@@ -117,7 +117,7 @@ def chain_attribution(reps):
         jax.random.PRNGKey(1), (4, cfg.image_size, cfg.image_size, cfg.in_channels))
     _, stats = model.apply(params, xb, collect_act_stats=True)
     qparams = model.quantize(params, stats)
-    plan = model.plan(qparams, batch=4, tune="off")
+    plan = model.plan(qparams, batch=4)
 
     e2e = jax.jit(plan.serve)
     jax.block_until_ready(e2e(xb))

@@ -44,7 +44,6 @@ BENCHES = [
     ("kernels (VDBB matmul)", "benchmarks.bench_kernels", False),
     ("quant (INT8 datapath, DESIGN §8)", "benchmarks.bench_quant", True),
     ("fused (epilogue fusion, DESIGN §9)", "benchmarks.bench_fused", True),
-    ("autotune (tile search + frozen plans, DESIGN §10)", "benchmarks.bench_autotune", True),
     ("serve (continuous-batching tier + chaos, DESIGN §11/§14)", "benchmarks.bench_serve", True),
     ("lm (LM VDBB routing + plans, DESIGN §13)", "benchmarks.bench_lm", True),
     ("roofline (EXPERIMENTS §Roofline)", "benchmarks.roofline", True),

@@ -6,7 +6,7 @@ check every answer.
 
 One chip: ``SparseCNN.compress`` → calibrate with
 ``apply(collect_act_stats=True)`` → ``quantize`` →
-``plan_set(max_batch=8, tune="off")`` → ``CNNServer`` under ``Supervisor``,
+``plan_set(max_batch=8)`` → ``CNNServer`` under ``Supervisor``,
 with the Pallas kernels compiled for the chip. It serves a few dozen
 single-image requests — Poisson arrivals, then a burst that fills the
 largest bucket — and checks that
@@ -134,7 +134,7 @@ def build(jax, seed):
 def ref_answers(jax, ref_model, qparams, buckets, pool):
     import numpy as np
 
-    ref_set = ref_model.plan_set(qparams, buckets=buckets, tune="off")
+    ref_set = ref_model.plan_set(qparams, buckets=buckets)
     with jax.default_matmul_precision("highest"):
         return np.asarray(ref_set.serve(pool))
 
@@ -226,7 +226,7 @@ def serve_one_chip(jax, seed):
     from repro.launch.supervisor import Supervisor
 
     model, ref_model, qparams, n_compressed, pool, fp32, classes = build(jax, seed)
-    plan_set = model.plan_set(qparams, max_batch=8, tune="off")
+    plan_set = model.plan_set(qparams, max_batch=8)
     top = plan_set.buckets[-1]
     check(top >= 8, f"largest bucket {top} < 8 never runs the head kernel")
     compiled = compile_buckets(plan_set, pool.shape[1:])
@@ -257,7 +257,7 @@ def serve_four_chips(jax, devs, seed):
     model, _, qparams, _, pool, _, classes = build(jax, seed)
     mesh = auto_mesh((dp, 1), ("data", "model"), devices=devs[:dp])
     sharding = NamedSharding(mesh, P("data"))
-    plan_set = model.plan_set(qparams, max_batch=8 * dp, dp=dp, tune="off")
+    plan_set = model.plan_set(qparams, max_batch=8 * dp, dp=dp)
     top = plan_set.buckets[-1]
     single = np.asarray(plan_set.serve(pool))  # one device, same ladder
     srv = CNNServer(plan_set, max_wait_ms=5.0, mesh=mesh)

@@ -187,14 +187,12 @@ class DBBConv2d:
     # ------------------------------------------------------- frozen plans
     def make_plan(self, params: dict, *, batch: int, h: int, w: int,
                   relu: bool = False, out_scale=None, fused: bool = False,
-                  residual_scale=None, tune: str = "cache", cache=None,
-                  top_k: int = 4, reps: int = 3):
+                  residual_scale=None):
         """Stage this layer's serving step once (DESIGN.md §10).
 
-        Resolves the tuned tile config for this exact launch signature
-        (autotune registry → persistent cache → optional search, per
-        ``tune`` ∈ {'off', 'cache', 'search'}) and returns ``(run,
-        tiles)``: ``run`` is an ``x -> y`` closure with the weight buffers
+        Freezes the tiles of this exact launch (``kernels/core.py``'s
+        ``default_conv_tiles``) and returns ``(run, tiles)``: ``run`` is an
+        ``x -> y`` closure with the weight buffers
         frozen in that replicates exactly the path ``SparseCNN.apply``
         takes for these params (``fused=True`` = the §9 int8-resident
         chain step, so a plan built from calibrated quantized params is
@@ -220,19 +218,7 @@ class DBBConv2d:
             quant or compressed) and not default_interpret()
         tiled = pallas and (quant or compressed or stem_fused)
         tiles: dict = {}
-        if tiled and tune != "off":
-            from repro.kernels import autotune  # deferred: kernels optional
-
-            tiles = autotune.tiles_for_conv(
-                batch, h, w, self.in_channels, self.out_channels, self.kh,
-                self.kw, wp.fmt if (quant or compressed) else None,
-                jnp.int8 if quant else self.dtype, stride=_pair(self.stride),
-                padding=self.padding, mode=tune, cache=cache, top_k=top_k,
-                reps=reps,
-            )
-        if tiled and not tiles:
-            # freeze the default tiles explicitly, so the staged
-            # closure never depends on ambient registry state at trace time
+        if tiled:
             _, _, (ho, wo) = conv_geometry(h, w, self.kh, self.kw,
                                            self.stride, self.padding)
             tiles = default_conv_tiles(ho, wo, self.out_channels, self.kh, self.kw)
@@ -258,7 +244,7 @@ class DBBConv2d:
             from repro.kernels import ops  # deferred: kernels are optional
 
             # mirror __call__'s kernel → +bias order, with the tiles pinned
-            # into the closure (never read from the ambient registry)
+            # into the closure
             def run(x):
                 if quant:
                     y = ops.quant_conv(

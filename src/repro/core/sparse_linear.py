@@ -162,12 +162,13 @@ class DBBLinear:
 
     # ------------------------------------------------------- frozen plans
     def make_plan(self, params: dict, *, batch: int, relu: bool = False,
-                  out_scale=None, fused: bool = False, tune: str = "cache",
-                  cache=None, top_k: int = 4, reps: int = 3):
+                  out_scale=None, fused: bool = False):
         """Stage this layer's serving step once (DESIGN.md §10); the GEMM
-        twin of :meth:`DBBConv2d.make_plan`. ``batch`` is the GEMM's M
-        (the tiny-M reference fallback applies, so classifier-head-sized
-        plans carry no tiles). Returns ``(run, tiles)``."""
+        twin of :meth:`DBBConv2d.make_plan`, freezing
+        ``kernels/core.py``'s ``default_matmul_tiles``. ``batch`` is the
+        GEMM's M (the tiny-M reference fallback applies, so
+        classifier-head-sized plans carry no tiles). Returns ``(run,
+        tiles)``."""
         from repro.kernels.core import default_matmul_tiles
 
         wp = params["w"]
@@ -182,17 +183,7 @@ class DBBLinear:
                     f"(ragged K has no compressed-block layout; pad K or "
                     f"serve with kernel_mode='ref')")
         tiles: dict = {}
-        if tiled and tune != "off":
-            from repro.kernels import autotune  # deferred: kernels optional
-
-            tiles = autotune.tiles_for_matmul(
-                batch, self.in_features, self.out_features, wp.fmt,
-                jnp.int8 if quant else self.dtype,
-                mode=tune, cache=cache, top_k=top_k, reps=reps,
-            )
-        if tiled and not tiles:
-            # freeze the default tiles explicitly, so the staged
-            # closure never depends on ambient registry state at trace time
+        if tiled:
             tiles = default_matmul_tiles(
                 batch, self.in_features, self.out_features, wp.fmt.bz,
                 jnp.int8 if quant else self.dtype)

@@ -17,7 +17,9 @@ residual block's shortcut, ReLU, requantize-to-int8, all executed once
 where the hardware's requantizer sits, DESIGN.md §9), K-innermost grid
 construction and the fp32 VMEM scratch + output BlockSpec boilerplate (:func:`os_matmul_call`), tile-size
 resolution (:func:`resolve_tile` strict / :func:`pick_tile` permissive /
-:func:`pick_tile_padded` aligned defaults), the activation mux
+:func:`pick_tile_padded` aligned defaults), the tile rule every launch
+takes its tiles from (:func:`default_matmul_tiles` /
+:func:`default_conv_tiles`), the activation mux
 (:func:`dbb_mux`), the conv tap loads (:func:`conv_tap`), and
 interpret-mode dispatch (:func:`default_interpret`).
 
@@ -40,7 +42,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Optional, Sequence
+from typing import Sequence
 
 import jax
 import jax.numpy as jnp
@@ -91,16 +93,12 @@ def pick_tile(dim: int, tile: int) -> int:
     return t
 
 
-def resolve_or_pick(dim: int, tile, default: int, name: str, *, align: int,
-                    tuned: int | None = None) -> int:
-    """``tile`` is None → the ``tuned`` size from the autotune registry when
-    it divides, else :func:`aligned_divisor` of the default (the whole dim
-    when no ``align`` multiple divides); otherwise the strict
+def resolve_or_pick(dim: int, tile, default: int, name: str, *, align: int) -> int:
+    """``tile`` is None → :func:`aligned_divisor` of the default (the whole
+    dim when no ``align`` multiple divides); otherwise the strict
     :func:`resolve_tile` (an explicit request that does not divide is still
     a caller error)."""
     if tile is None:
-        if tuned is not None and 0 < tuned <= dim and dim % tuned == 0:
-            return int(tuned)
         return aligned_divisor(dim, default, align)
     return resolve_tile(dim, tile, name)
 
@@ -152,16 +150,18 @@ def default_kb(nb: int, bz: int) -> int:
 
 
 def default_matmul_tiles(m: int, k: int, n: int, bz: int, dtype) -> dict:
-    """The untuned ``(bm, bn, kb)`` of one compressed-matmul launch:
-    bm sublane-aligned for the operand dtype, bn lane-aligned (N padded
-    when no aligned divisor exists), kb from :func:`default_kb`."""
+    """The ``(bm, bn, kb)`` of one compressed-matmul launch — with
+    :func:`default_conv_tiles`, the one tile rule that plans freeze and
+    tile-less kernel calls resolve (DESIGN.md §10): bm sublane-aligned
+    for the operand dtype, bn lane-aligned (N padded when no aligned
+    divisor exists), kb from :func:`default_kb`."""
     return {"bm": pick_tile_padded(m, 128, sublanes(dtype))[0],
             "bn": pick_tile_padded(n, 256, LANES)[0],
             "kb": default_kb(k // bz, bz)}
 
 
 def default_bf(f: int, kh: int = 3, kw: int = 3) -> int:
-    """The untuned F block of a fused conv: lane-aligned 128 (or all of F),
+    """The F block of a fused conv: lane-aligned 128 (or all of F),
     and all of F for a 1×1 conv, whose activation mux (``dbb_mux``) would
     otherwise run again for every F block — whole-F blocks ran 8–29 %
     faster on v5e at ResNet-50's 1×1 shapes (PERF.md §3)."""
@@ -169,7 +169,7 @@ def default_bf(f: int, kh: int = 3, kw: int = 3) -> int:
 
 
 def default_conv_tiles(ho: int, wo: int, f: int, kh: int = 3, kw: int = 3) -> dict:
-    """The untuned ``(bf, tile_h, tile_w)`` of one fused-conv launch: the
+    """The ``(bf, tile_h, tile_w)`` of one fused-conv launch: the
     :func:`default_bf` block over the whole output map."""
     return {"bf": default_bf(f, kh, kw), "tile_h": ho, "tile_w": wo}
 
@@ -179,8 +179,9 @@ def pad_tile(dim: int, tile, default: int, align: int = 1) -> tuple:
 
     ``(t, padded_dim)``: None → :func:`pick_tile_padded` of the default;
     an explicit tile is clamped to the dimension, and one that does not
-    divide pads the ragged edge instead of raising (so autotuner
-    candidates are not restricted to exact divisors). Kernel-level
+    divide pads the ragged edge instead of raising (so
+    :func:`default_matmul_tiles` may pick a lane-aligned N tile that does
+    not divide N, as for a 1000-class head). Kernel-level
     wrappers keep :func:`resolve_tile`'s strict contract; only the
     ``ops.*`` entry points pad-and-slice.
     """
@@ -188,94 +189,6 @@ def pad_tile(dim: int, tile, default: int, align: int = 1) -> tuple:
         return pick_tile_padded(dim, default, align)
     t = max(1, min(int(tile), dim))
     return t, -(-dim // t) * t
-
-
-# ---------------------------------------------------------------------------
-# Tuned-tile registry (populated by repro.kernels.autotune; kernels.core
-# deliberately keeps zero repro-internal imports, so the registry is a plain
-# dict the autotuner writes into and the kernel entry points read from)
-# ---------------------------------------------------------------------------
-
-KIND_MATMUL_TC = "matmul_tc"
-KIND_MATMUL_BW = "matmul_bw"
-KIND_CONV_TC = "conv_tc"
-KIND_CONV_BW = "conv_bw"
-KIND_CONV_DENSE = "conv_dense"
-
-_TUNED: dict = {}
-
-
-def matmul_sig(m: int, k: int, n: int, bz: int, nnz: int, dtype) -> tuple:
-    """Shape signature of one matmul-shaped launch (kernel kind carried
-    separately): everything tile validity and performance depend on."""
-    return (int(m), int(k), int(n), int(bz), int(nnz), str(jnp.dtype(dtype)))
-
-
-def conv_sig(n: int, ho: int, wo: int, c: int, f: int, kh: int, kw: int,
-             sh: int, sw: int, bz: int, nnz: int, dtype) -> tuple:
-    """Shape signature of one fused-conv launch (``bz = nnz = 0`` for the
-    dense kernel). Output geometry (ho, wo) subsumes the padding mode."""
-    return (int(n), int(ho), int(wo), int(c), int(f), int(kh), int(kw),
-            int(sh), int(sw), int(bz), int(nnz), str(jnp.dtype(dtype)))
-
-
-def lookup_tiles(kind: str, sig: tuple) -> Optional[dict]:
-    """Measured-best tile config for (kind, sig), or None when untuned."""
-    return _TUNED.get((kind, sig))
-
-
-def tuned_conv_tiles(kind: str, sig: tuple, ho: int, wo: int, f: int) -> tuple:
-    """``(bf, tile_h, tile_w)`` from the registry, each component used only
-    when it divides its dimension (conv spatial/F tiles stay exact — the
-    pad-and-slice escape hatch is matmul-only); None components fall back
-    to the callers' defaults."""
-    t = lookup_tiles(kind, sig) or {}
-
-    def ok(v, dim):
-        return int(v) if v and dim % int(v) == 0 else None
-
-    return ok(t.get("bf"), f), ok(t.get("tile_h"), ho), ok(t.get("tile_w"), wo)
-
-
-_INVALIDATION_HOOKS: list = []
-
-
-def register_invalidation_hook(fn) -> None:
-    """Register a callback fired whenever the tuned registry changes.
-
-    Jitted entry points consult the registry only at *trace* time, so a
-    registry change must drop their jit caches or live traces keep stale
-    tile choices. kernels.core keeps zero repro-internal imports, so the
-    ops layer injects its cache-drop here at import.
-    """
-    if fn not in _INVALIDATION_HOOKS:
-        _INVALIDATION_HOOKS.append(fn)
-
-
-def _invalidate_tuned_consumers() -> None:
-    for fn in _INVALIDATION_HOOKS:
-        try:
-            fn()
-        except Exception:  # noqa: BLE001 — cache drop is best-effort
-            pass
-
-
-def set_tuned(kind: str, sig: tuple, tiles: dict) -> None:
-    """Install a tuned config; registering an *unchanged* entry is a no-op
-    (live traces already use it), anything else invalidates the consumers'
-    jit caches so the next call re-consults the registry."""
-    entry = {k: int(v) for k, v in tiles.items() if v is not None}
-    key = (kind, sig)
-    if _TUNED.get(key) == entry:
-        return
-    _TUNED[key] = entry
-    _invalidate_tuned_consumers()
-
-
-def clear_tuned() -> None:
-    if _TUNED:
-        _TUNED.clear()
-        _invalidate_tuned_consumers()
 
 
 def acc_dtype_for(operand_dtype) -> jnp.dtype:

@@ -152,16 +152,12 @@ def im2col_conv(
     epilogue (``scales``/``bias``/``relu``/``out_scale``, DESIGN.md §9)
     fuses the layer's bias + ReLU + requantize-to-int8 into the flush, so
     even the fp32 stem of an int8-resident model is one kernel."""
-    n, h, wd, c = x.shape
+    n, c = x.shape[0], x.shape[3]
     kh, kw, wc, f = w.shape
     if wc != c:
         raise ValueError(f"channel mismatch: x has {c}, w has {wc}")
-    if bf is None and tile_h is None and tile_w is None:
-        (sh, sw), _, (ho, wo) = core.conv_geometry(h, wd, kh, kw, stride, padding)
-        sig = core.conv_sig(n, ho, wo, c, f, kh, kw, sh, sw, 0, 0, x.dtype)
-        bf, tile_h, tile_w = core.tuned_conv_tiles(core.KIND_CONV_DENSE, sig, ho, wo, f)
     xt, g = plan_conv(x, kh, kw, stride=stride, padding=padding, tile_h=tile_h, tile_w=tile_w)
-    bf = core.resolve_or_pick(f, bf, 128, "bf", align=core.LANES)
+    bf = core.default_bf(f, kh, kw) if bf is None else core.resolve_tile(f, bf, "bf")
     grid = (n * g["th"] * g["tw"], f // bf)
     acc_dtype = core.acc_dtype_for(x.dtype)  # int32 on the int8 path (§8)
     ep, e_ops, e_specs, out_dtype = core.epilogue_plan(

@@ -57,9 +57,8 @@ def _matmul_dispatch(a, w, scales, bm, bn, kb, interpret, *, bias=None,
     """tc vs bw on the weight's pattern-sharing mode (shared by the fp,
     raw-int8 and quantized entry points).
 
-    Tile resolution is permissive here (the ops layer): default tiles come
-    from the autotune registry when a measured-best config is installed for
-    this launch signature, and explicit/tuned ``bm``/``bn`` that do not
+    Tile resolution is permissive here (the ops layer): tiles not given
+    come from ``core.default_matmul_tiles``, and ``bm``/``bn`` that do not
     divide M/N take the pad-to-tile path — the ragged edge is zero-padded
     and sliced back off, which is exact (padded rows/columns contribute
     nothing; padded ``out_scale`` columns divide by 1 and are discarded).
@@ -71,14 +70,11 @@ def _matmul_dispatch(a, w, scales, bm, bn, kb, interpret, *, bias=None,
     fmt = w.fmt
     g = fmt.group_size(n)
     tc = g == n
-    kind = core.KIND_MATMUL_TC if tc else core.KIND_MATMUL_BW
-    if bm is None and bn is None and kb is None:
-        tuned = core.lookup_tiles(
-            kind, core.matmul_sig(m, k, n, fmt.bz, fmt.nnz, a.dtype)
-        ) or {}
-        bm, bn, kb = tuned.get("bm"), tuned.get("bn"), tuned.get("kb")
-        if kb is not None and (k // fmt.bz) % kb != 0:
-            kb = None  # a tuned K tile must divide exactly; fall back
+    if bm is None or bn is None or kb is None:
+        rule = core.default_matmul_tiles(m, k, n, fmt.bz, a.dtype)
+        bm = rule["bm"] if bm is None else bm
+        bn = rule["bn"] if bn is None else bn
+        kb = rule["kb"] if kb is None else kb
     bm, mp = core.pad_tile(m, bm, 128, core.sublanes(a.dtype))
     bn, n_pad = core.pad_tile(n, bn, 256, core.LANES)
     if mp != m:
@@ -293,20 +289,3 @@ def quant_conv(
         tile_h=tile_h, tile_w=tile_w, interpret=interpret,
     )
 
-
-def _drop_jit_caches() -> None:
-    """Drop every entry point's jit cache. Registered with the kernel core
-    as the tuned-registry invalidation hook: default-tile traces capture
-    registry lookups at trace time, so any registry change must force a
-    retrace (DESIGN.md §10)."""
-    for f in (vdbb_matmul, quant_matmul, fused_im2col_conv, sparse_conv,
-              quant_conv):
-        clear = getattr(f, "clear_cache", None)
-        if callable(clear):
-            try:
-                clear()
-            except Exception:  # noqa: BLE001 — cache drop is best-effort
-                pass
-
-
-core.register_invalidation_hook(_drop_jit_caches)

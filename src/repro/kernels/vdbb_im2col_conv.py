@@ -114,20 +114,6 @@ def _vdbb_conv_bw_kernel(x_ref, v_ref, idx_ref, *rest, bz, geom, ep=None):
 # ---------------------------------------------------------------------------
 
 
-def _tuned_conv_defaults(kind, x, fmt, kh, kw, f, stride, padding,
-                         bf, tile_h, tile_w):
-    """Fill default conv tiles from the autotune registry (measured-best
-    configs installed by ``repro.kernels.autotune``); explicit requests
-    pass through untouched."""
-    if bf is not None or tile_h is not None or tile_w is not None:
-        return bf, tile_h, tile_w
-    n, h, w = x.shape[0], x.shape[1], x.shape[2]
-    c = x.shape[3]
-    (sh, sw), _, (ho, wo) = core.conv_geometry(h, w, kh, kw, stride, padding)
-    sig = core.conv_sig(n, ho, wo, c, f, kh, kw, sh, sw, fmt.bz, fmt.nnz, x.dtype)
-    return core.tuned_conv_tiles(kind, sig, ho, wo, f)
-
-
 def kernel_name(base: str, kh: int, kw: int, residual) -> str:
     """The Pallas call's name in a device profile: ``base``, with ``_1x1``
     for a pointwise conv and ``_res`` when the flush adds a residual, so a
@@ -196,10 +182,7 @@ def vdbb_im2col_conv_tc(
     nb, nnz, f = values.shape
     c = nb * fmt.bz // (kh * kw)
     cb = c // fmt.bz
-    bf, tile_h, tile_w = _tuned_conv_defaults(
-        core.KIND_CONV_TC, x, fmt, kh, kw, f, stride, padding, bf, tile_h, tile_w
-    )
-    bf = core.resolve_or_pick(f, bf, core.default_bf(f, kh, kw), "bf", align=core.LANES)
+    bf = core.default_bf(f, kh, kw) if bf is None else core.resolve_tile(f, bf, "bf")
     v = values.reshape(kh * kw, cb * nnz, f)
     # per tap, the channel of the (·, C) patch each compressed column reads
     pos = core.mux_positions(indices, cb, fmt.bz).reshape(kh * kw, cb * nnz, 1)
@@ -243,10 +226,7 @@ def vdbb_im2col_conv_bw(
     nb, nnz, f = values.shape
     c = nb * fmt.bz // (kh * kw)
     cb = c // fmt.bz
-    bf, tile_h, tile_w = _tuned_conv_defaults(
-        core.KIND_CONV_BW, x, fmt, kh, kw, f, stride, padding, bf, tile_h, tile_w
-    )
-    bf = core.resolve_or_pick(f, bf, core.default_bf(f, kh, kw), "bf", align=core.LANES)
+    bf = core.default_bf(f, kh, kw) if bf is None else core.resolve_tile(f, bf, "bf")
     # (kh·kw, nnz, cb, F): per tap, slot-major blocks (dbb_expand_block)
     v = values.reshape(kh * kw, cb, nnz, f).transpose(0, 2, 1, 3)
     idx = indices.astype(jnp.int32).reshape(kh * kw, cb, nnz, f).transpose(0, 2, 1, 3)

@@ -64,25 +64,17 @@ def _check_compressed_operands(a, values, fmt):
     return m, k, nb, n
 
 
-def _resolve_tiles(kind, a, m, k, n, fmt, bm, bn, kb):
+def _resolve_tiles(a, m, k, n, fmt, bm, bn, kb):
     """``(bm, bn, kb)`` of one launch: explicit tiles must divide exactly;
-    when all are None, a tuned registry entry that divides, else the
-    aligned defaults — bm a sublane multiple for the operand dtype, bn a
-    lane multiple, kb·bz a lane multiple — or the whole dimension when no
-    aligned divisor exists (a full-extent block is always legal)."""
-    tuned = {}
-    if bm is None and bn is None and kb is None:
-        tuned = core.lookup_tiles(
-            kind, core.matmul_sig(m, k, n, fmt.bz, fmt.nnz, a.dtype)) or {}
+    None takes the aligned default — bm a sublane multiple for the
+    operand dtype, bn a lane multiple, kb from ``core.default_kb`` — or
+    the whole dimension when no aligned divisor exists (a full-extent
+    block is always legal)."""
     nb = k // fmt.bz
-    kb_align = core.default_kb(nb, fmt.bz)
     return (
-        core.resolve_or_pick(m, bm, 128, "bm", align=core.sublanes(a.dtype),
-                             tuned=tuned.get("bm")),
-        core.resolve_or_pick(n, bn, 256, "bn", align=core.LANES,
-                             tuned=tuned.get("bn")),
-        core.resolve_or_pick(nb, kb, kb_align, "kb", align=kb_align,
-                             tuned=tuned.get("kb")),
+        core.resolve_or_pick(m, bm, 128, "bm", align=core.sublanes(a.dtype)),
+        core.resolve_or_pick(n, bn, 256, "bn", align=core.LANES),
+        core.resolve_tile(nb, core.default_kb(nb, fmt.bz) if kb is None else kb, "kb"),
     )
 
 
@@ -129,7 +121,7 @@ def vdbb_matmul_tc(
     must divide exactly."""
     m, k, nb, n = _check_compressed_operands(a, values, fmt)
     bz, nnz = fmt.bz, fmt.nnz
-    bm, bn, kb = _resolve_tiles(core.KIND_MATMUL_TC, a, m, k, n, fmt, bm, bn, kb)
+    bm, bn, kb = _resolve_tiles(a, m, k, n, fmt, bm, bn, kb)
     v2 = values.reshape(nb * nnz, n)
     pos = core.mux_positions(indices, kb, bz)
     acc_dtype = core.acc_dtype_for(a.dtype)
@@ -223,7 +215,7 @@ def vdbb_matmul_bw(
     :func:`vdbb_matmul_tc`."""
     m, k, nb, n = _check_compressed_operands(a, values, fmt)
     bz, nnz = fmt.bz, fmt.nnz
-    bm, bn, kb = _resolve_tiles(core.KIND_MATMUL_BW, a, m, k, n, fmt, bm, bn, kb)
+    bm, bn, kb = _resolve_tiles(a, m, k, n, fmt, bm, bn, kb)
     v3 = values.transpose(1, 0, 2)  # (nnz, nb, N): slot-major
     idx3 = indices.astype(jnp.int32).transpose(1, 0, 2)
     acc_dtype = core.acc_dtype_for(a.dtype)

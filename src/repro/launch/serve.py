@@ -6,16 +6,14 @@ weight-bandwidth-bound.
       --batch 4 --prompt-len 32 --gen 16
 
 CNN archs serve through a **frozen plan** (DESIGN.md §10): INT8
-quantization is calibrated, every layer's tuned tile config + staged
-weight buffers are resolved once by ``SparseCNN.plan()``, and the timed
-loop runs the single-dispatch ``plan.serve`` hot path. ``--no-plan``
-serves the unplanned path — jitted once, so the comparison measures the
-plan's staging win, not python dispatch overhead; ``--tune search``
-runs the tile autotuner at plan-build time (persisted in the autotune
-cache, so repeat launches are search-free).
+quantization is calibrated, every layer's tiles (``kernels/core.py``'s
+rule) + staged weight buffers are resolved once by ``SparseCNN.plan()``,
+and the timed loop runs the single-dispatch ``plan.serve`` hot path.
+``--no-plan`` serves the unplanned path — jitted once, so the comparison
+measures the plan's staging win, not python dispatch overhead.
 
   PYTHONPATH=src python -m repro.launch.serve --arch sparse-cnn-tiny --smoke \
-      --batch 4 --steps 16 --tune search
+      --batch 4 --steps 16
 
 ``--server`` runs the **continuous-batching tier** (DESIGN.md §11)
 instead of a fixed-batch loop: a bucketed plan set (1/2/…/--max-batch),
@@ -116,10 +114,9 @@ def serve_cnn(args):
     if args.server:
         return serve_cnn_continuous(args, model, qparams, xb)
     if args.plan:
-        plan = model.plan(qparams, batch=args.batch, tune=args.tune)
-        tiles = plan.tiles
+        plan = model.plan(qparams, batch=args.batch)
         print(f"[serve] frozen plan: {len(plan.layers)} stages, "
-              f"tuned tiles for {len(tiles)} layers ({args.tune})")
+              f"tiles frozen for {len(plan.tiles)} layers")
         step = plan.serve
     else:
         # jitted once: the comparison vs --plan then measures what plans
@@ -128,7 +125,7 @@ def serve_cnn(args):
         # on every timed call.
         print("[serve] unplanned path, jitted once (--no-plan)")
         step = jax.jit(lambda xb: model.apply(qparams, xb))
-    from repro.xla_utils import median_time_us  # the shared bench/tuner harness
+    from repro.xla_utils import median_time_us  # the shared bench harness
 
     logits = step(xb)
     us = median_time_us(step, xb, warmup=1, reps=args.steps)
@@ -157,8 +154,8 @@ def serve_cnn_continuous(args, model, qparams, xpool):
     from repro.launch.supervisor import Supervisor
 
     sample_shape = xpool.shape[1:]
-    plan_set = model.plan_set(qparams, max_batch=args.max_batch, tune=args.tune)
-    print(f"[serve] plan set: buckets {plan_set.buckets} ({args.tune}), "
+    plan_set = model.plan_set(qparams, max_batch=args.max_batch)
+    print(f"[serve] plan set: buckets {plan_set.buckets}, "
           f"max-wait {args.max_wait_ms}ms, max-queue {args.max_queue} "
           f"({args.shed})")
     rate = args.rate
@@ -175,13 +172,9 @@ def serve_cnn_continuous(args, model, qparams, xpool):
     deadline_s = args.deadline_ms / 1e3 if args.deadline_ms else None
     srv = CNNServer(plan_set, max_wait_ms=args.max_wait_ms,
                     max_queue=args.max_queue, shed=args.shed)
-    # reload plans resolve tiles from the autotune cache the first build
-    # populated — a live reload must never block on a tile search
-    retune = "cache" if args.tune == "search" else args.tune
     sup = Supervisor(
         srv,
-        rebuild=lambda tree: model.plan_set(
-            tree, max_batch=args.max_batch, tune=retune),
+        rebuild=lambda tree: model.plan_set(tree, max_batch=args.max_batch),
         template=qparams,
     )
     ckpt_dir = None
@@ -285,9 +278,8 @@ def serve_lm_plan(args):
     qparams = model.quantize(params, stats)
     print(f"[serve] {cfg.name}: INT8-calibrated VDBB LM "
           f"(nnz={cfg.dbb.nnz}/{cfg.dbb.bz}, kernel_mode={cfg.kernel_mode})")
-    plan = model.plan(qparams, batch=args.batch, seq=args.prompt_len,
-                      tune=args.tune)
-    print(f"[serve] frozen plan: {len(plan.layers)} stages ({args.tune})")
+    plan = model.plan(qparams, batch=args.batch, seq=args.prompt_len)
+    print(f"[serve] frozen plan: {len(plan.layers)} stages")
     # the §14 admission check guards the LM path too: token batches are
     # validated against the plan's sample spec before any dispatch
     from repro.launch.server import validate_request
@@ -322,9 +314,6 @@ def main(argv=None):
                     help="timed forward passes (CNN serving)")
     ap.add_argument("--plan", action=argparse.BooleanOptionalAction, default=True,
                     help="CNN: serve through a frozen plan (--no-plan = per-call path)")
-    ap.add_argument("--tune", choices=("off", "cache", "search"), default="cache",
-                    help="CNN plan tile resolution: autotune cache hits only "
-                         "(default), full search, or the default tiles")
     ap.add_argument("--lm-plan", action="store_true",
                     help="LM: serve prefill through a frozen ModelPlan "
                          "(DESIGN §13) instead of the decode loop")

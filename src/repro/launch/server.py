@@ -369,7 +369,7 @@ _DEPTH = 2
 class CNNServer:
     """Continuous-batching front end over a frozen :class:`PlanSet`.
 
-    >>> plan_set = model.plan_set(qparams, max_batch=8, tune="cache")
+    >>> plan_set = model.plan_set(qparams, max_batch=8)
     >>> with CNNServer(plan_set, max_wait_ms=5.0, max_queue=64) as srv:
     ...     srv.warmup()                      # buckets from the plan spec
     ...     fut = srv.submit(x1, deadline_s=0.2)   # x1: (1, 32, 32, 3)

@@ -22,11 +22,11 @@ bad, a compiled kernel path breaking:
 - **Hot reload** (:meth:`reload`). Restore a checkpoint through the §15
   integrity verification (``CorruptCheckpointError`` on any damage —
   the old plan keeps serving), rebuild quantize→plan *off* the
-  dispatcher thread (reusing the tune cache and the serving
-  ``sample_spec`` contract), warm the new buckets, then swap the
-  ``PlanSet`` atomically between bucket dispatches — zero dropped or
-  hung requests. A ``StalePlanError`` after a weight refresh is thereby
-  a recoverable event: rebuild through ``reload`` instead of dying.
+  dispatcher thread (keeping the serving ``sample_spec`` contract),
+  warm the new buckets, then swap the ``PlanSet`` atomically between
+  bucket dispatches — zero dropped or hung requests. A
+  ``StalePlanError`` after a weight refresh is thereby a recoverable
+  event: rebuild through ``reload`` instead of dying.
 - **Degradation** rides the server's per-bucket kernel fallback
   (``fallback=`` / ``demote_after`` / ``probe_every``); the supervisor
   surfaces demoted buckets in :meth:`health` and rebuilds the fallback
@@ -53,7 +53,7 @@ class Supervisor:
 
     >>> srv = CNNServer(plan_set, max_wait_ms=5.0)
     >>> sup = Supervisor(srv, rebuild=lambda tree: model.plan_set(tree,
-    ...                  max_batch=8, tune="cache"), template=qparams)
+    ...                  max_batch=8), template=qparams)
     >>> with sup:
     ...     sup.warmup()
     ...     fut = sup.submit(x)            # delegates to the server
@@ -72,9 +72,7 @@ class Supervisor:
         stretched by up to ``jitter`` fraction of seeded randomness —
         bounded, and deterministic for a given seed.
     rebuild:
-        ``params_tree -> PlanSet`` for :meth:`reload` (quantize→plan;
-        reuse the tune cache inside the closure so reloads never
-        re-search).
+        ``params_tree -> PlanSet`` for :meth:`reload` (quantize→plan).
     template:
         A params pytree with the checkpoint's structure (what
         ``checkpoint.store.restore`` restores into).

@@ -85,12 +85,11 @@ class CNNLifecycle:
 
     def plan_set(self, params: dict, *, max_batch: Optional[int] = None,
                  buckets: Optional[Sequence[int]] = None, dp: int = 1,
-                 tune: str = "cache", cache=None, top_k: int = 4,
-                 reps: int = 3):
+                 tune: str = "cache"):
         """Freeze a bucketed serving plan set (DESIGN.md §11).
 
-        One ``plan`` per batch-size bucket, all sharing the same
-        tune cache and params fingerprint. ``buckets`` defaults to the
+        One ``plan`` per batch-size bucket, all sharing the same params
+        fingerprint. ``buckets`` defaults to the
         power-of-two ladder ``make_buckets(max_batch, dp=dp)``; ``dp``
         (the data-parallel degree the set will be served at) forces
         every bucket to shard evenly over a mesh's data axis. The
@@ -98,14 +97,21 @@ class CNNLifecycle:
         size retrace-free after warmup: ragged batches pad up to the
         nearest bucket and slice back, bit-identical to per-request
         serving.
-        """
-        from repro.models.plan import build_plan_set, resolve_tune_cache
 
-        cache = resolve_tune_cache(tune, cache)  # one parse for all buckets
+        ``tune`` is kept only for the benchmark's callers
+        (``benchmarks/chip/``), which pass ``tune="cache"``: tiles come
+        from ``kernels/core.py``'s rule, and any other value raises
+        ``ValueError``.
+        """
+        from repro.models.plan import build_plan_set
+
+        if tune != "cache":
+            raise ValueError(
+                f"tune={tune!r}: tiles come from kernels/core.py's rule "
+                "(default_matmul_tiles / default_conv_tiles); only 'cache' "
+                "is accepted")
         return build_plan_set(
-            self.cfg.name, params,
-            lambda b: self.plan(params, batch=b, tune=tune, cache=cache,
-                                top_k=top_k, reps=reps),
+            self.cfg.name, params, lambda b: self.plan(params, batch=b),
             max_batch=max_batch, buckets=buckets, dp=dp,
         )
 
@@ -121,8 +127,7 @@ class CNNLifecycle:
         from repro.models.plan import fallback_closures
 
         ref_model = type(self)(dataclasses.replace(self.cfg, kernel_mode="ref"))
-        ref_set = ref_model.plan_set(params, buckets=primary.buckets,
-                                     tune="off")
+        ref_set = ref_model.plan_set(params, buckets=primary.buckets)
         return fallback_closures(primary, ref_set, verify=verify)
 
 
@@ -342,14 +347,12 @@ class SparseCNN(CNNLifecycle):
         return head.quant_serve(params[f"l{n}"], x)
 
     # ------------------------------------------- frozen serving plans (§10)
-    def plan(self, params: dict, *, batch: int, tune: str = "cache",
-             cache=None, top_k: int = 4, reps: int = 3):
+    def plan(self, params: dict, *, batch: int):
         """Freeze a once-per-model serving plan (DESIGN.md §10).
 
-        Resolves every layer's tuned tile config (autotune registry →
-        persistent cache → search when ``tune='search'``; ``'cache'``
-        never searches, ``'off'`` keeps the default tiles), stages each
-        layer's serving closure with its weight buffers frozen in —
+        Freezes every layer's tiles (``kernels/core.py``'s rule, at this
+        layer's launch shape), stages each layer's serving closure with
+        its weight buffers frozen in —
         replicating exactly the path :meth:`apply` takes for these params,
         including the §9 int8-resident chain when calibrated quantized
         params are detected — and jit-compiles the whole chain once.
@@ -359,9 +362,9 @@ class SparseCNN(CNNLifecycle):
         content fingerprint; serving through :meth:`apply`'s ``plan=``
         kwarg re-checks that pin.
 
-        ``batch`` fixes the input batch size the plan is staged (and
-        tuned) for — other batch shapes still run, but retrace and fall
-        back to registry/default tiles.
+        ``batch`` fixes the input batch size the plan is staged for —
+        other batch shapes still run, but retrace with the tiles frozen
+        for ``batch``.
         """
         from repro.models.plan import PlanBuilder
 
@@ -371,8 +374,7 @@ class SparseCNN(CNNLifecycle):
         c = self.cfg
         h = w = c.image_size
         n = len(convs)
-        pb = PlanBuilder(c.name, params, batch=batch, tune=tune, cache=cache,
-                         top_k=top_k, reps=reps,
+        pb = PlanBuilder(c.name, params, batch=batch,
                          sample_spec=((c.image_size, c.image_size,
                                        c.in_channels), "float32"))
         for i, m in enumerate(convs):

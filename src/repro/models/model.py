@@ -539,33 +539,7 @@ class LM:
         return params
 
     # ------------------------------------------------------------- plan
-    def _tune_gemms(self, params, m, *, tune, cache, top_k, reps):
-        """Resolve measured-best tiles for each unique compressed GEMM
-        shape in the param tree. ``tiles_for_matmul`` installs results
-        into the autotuner's global registry, so the plan's jit trace
-        (ops-layer dispatch) picks them up without per-stage pinning."""
-        from repro.core.quant import QuantDBBWeight
-        from repro.kernels import autotune
-        from repro.models.plan import resolve_tune_cache
-
-        cache = resolve_tune_cache(tune, cache)
-        seen = set()
-        for path, pdef in dbb_leaves(self.defs()):
-            w = tree_get(params, path)
-            if not hasattr(w, "fmt"):
-                continue  # never compressed (e.g. 4-D expert stacks)
-            k, n = pdef.shape[-2:]
-            dtype = (jnp.int8 if isinstance(w, QuantDBBWeight)
-                     else self.cfg.compute_dtype)
-            sig = (m, k, n, w.fmt, jnp.dtype(dtype).name)
-            if sig in seen:
-                continue
-            seen.add(sig)
-            autotune.tiles_for_matmul(m, k, n, w.fmt, dtype, mode=tune,
-                                      cache=cache, top_k=top_k, reps=reps)
-
-    def plan(self, params, *, batch: int, seq: int, tune: str = "cache",
-             cache=None, top_k: int = 4, reps: int = 3):
+    def plan(self, params, *, batch: int, seq: int):
         """Freeze a serving plan for a fixed (batch, seq) shape (§13).
 
         Stages: ``embed`` → one stage per block (layer groups unrolled:
@@ -573,9 +547,9 @@ class LM:
         logits). Composition and staleness come from the shared
         :class:`~repro.models.plan.ModelPlan` machinery, exactly like the
         CNN plan. One deviation from the CNN: LM stages carry empty
-        ``tiles`` — GEMM tile choices are resolved once up front via the
-        autotuner registry (``_tune_gemms``) rather than pinned per stage,
-        because a transformer block mixes several GEMMs per stage.
+        ``tiles`` — a transformer block mixes several GEMMs per stage, and
+        each resolves ``kernels/core.py``'s tile rule for its own shape
+        when the stage is traced (the rule the CNN layers freeze).
 
         Raises ``NotImplementedError`` for cross-attention / multimodal
         configs: their blocks need extra per-call inputs (memory, vision
@@ -588,9 +562,6 @@ class LM:
             raise NotImplementedError(
                 "LM.plan supports decoder-only text models; cross_attn or "
                 f"frontend={c.frontend!r} needs per-call side inputs")
-        if c.kernel_mode == "pallas" and tune != "off":
-            self._tune_gemms(params, batch * seq, tune=tune, cache=cache,
-                             top_k=top_k, reps=reps)
         positions = jnp.broadcast_to(
             jnp.arange(seq, dtype=jnp.int32)[None], (batch, seq))
         pb = PlanBuilder(c.name, params, batch=batch,

@@ -1,14 +1,14 @@
 """Frozen serving plans (DESIGN.md §10).
 
 A :class:`ModelPlan` is the once-per-model resolution of everything the
-serving path would otherwise redo on every call: tuned tile configs
-(``repro.kernels.autotune``), epilogue wiring, and the compressed/
-quantized weight buffers themselves. Each layer's serving step is staged
-into a closure with its parameters *frozen in*, and the whole chain is
-jit-compiled once — weights become trace-time constants, so XLA folds
-the per-call weight relayout (reshape / index expand / dtype cast) at
-compile time and steady-state serving is a single dispatch with zero
-per-call tile resolution, re-layout, or retracing.
+serving path would otherwise redo on every call: each launch's tiles
+(``kernels/core.py``'s rule, frozen per layer), epilogue wiring, and the
+compressed/quantized weight buffers themselves. Each layer's serving
+step is staged into a closure with its parameters *frozen in*, and the
+whole chain is jit-compiled once — weights become trace-time constants,
+so XLA folds the per-call weight relayout (reshape / index expand /
+dtype cast) at compile time and steady-state serving is a single
+dispatch with zero per-call tile resolution, re-layout, or retracing.
 
 Plans are immutable (frozen dataclasses) and *pinned to the exact
 parameters they were built from*: :func:`params_fingerprint` hashes every
@@ -86,7 +86,7 @@ class ModelPlan:
     model: str
     fingerprint: str
     layers: Tuple[LayerPlan, ...]
-    batch: Optional[int] = None  # the batch the plan was staged/tuned for
+    batch: Optional[int] = None  # the batch the plan was staged for
     # One sample's (shape-sans-batch, dtype-name) the plan was staged for,
     # e.g. ((32, 32, 3), 'float32') for a CNN or ((128,), 'int32') for LM
     # prefill. The serving tier validates every request against this at
@@ -419,59 +419,32 @@ def fallback_closures(primary: "PlanSet", fallback: "PlanSet", *,
 # ----------------------------------------------------------------- §13
 # Model-agnostic plan staging. SparseCNN.plan/plan_set and LM.plan are
 # thin compositions over these — any model family stages per-layer
-# closures through a PlanBuilder and inherits fingerprint pinning, tile
-# resolution (one TuneCache parse per build), and bucketed PlanSets.
-
-
-def resolve_tune_cache(tune: str, cache):
-    """Parse the on-disk autotune cache once per plan build (``tune='off'``
-    skips it). Idempotent: an already-parsed ``TuneCache`` passes through,
-    so nested builders (plan_set → plan per bucket) share one parse."""
-    if tune == "off":
-        return cache
-    from repro.kernels.autotune import TuneCache
-
-    if not isinstance(cache, TuneCache):
-        cache = TuneCache(cache)
-    return cache
+# closures through a PlanBuilder and inherits fingerprint pinning, the
+# tiles each layer's make_plan froze, and bucketed PlanSets.
 
 
 class PlanBuilder:
     """Collects staged serving layers into an immutable :class:`ModelPlan`.
 
     One builder per (model, params, batch): the params fingerprint is
-    taken at construction, tuning knobs are normalized once
-    (:func:`resolve_tune_cache`), and every :meth:`stage` call receives
-    the shared ``tune/cache/top_k/reps`` keywords so per-layer
-    ``make_plan`` implementations resolve tiles against the same cache.
-    Layers that stage plain closures without tile resolution (pooling,
-    norms, whole transformer blocks) use :meth:`raw`.
+    taken at construction, and every :meth:`stage` call records the tiles
+    the layer's ``make_plan`` froze. Layers that stage plain closures
+    without tile resolution (pooling, norms, whole transformer blocks)
+    use :meth:`raw`.
     """
 
     def __init__(self, model: str, params, *, batch: Optional[int] = None,
-                 tune: str = "cache", cache=None, top_k: int = 4,
-                 reps: int = 3,
                  sample_spec: Optional[Tuple[Tuple[int, ...], str]] = None):
         self.model = model
         self.batch = batch
         self.sample_spec = sample_spec
         self.fingerprint = params_fingerprint(params)
-        self.tune = tune
-        self.cache = resolve_tune_cache(tune, cache)
-        self.top_k = top_k
-        self.reps = reps
         self._stages: list = []
 
-    @property
-    def tune_kw(self) -> dict:
-        """The shared tuning keywords every ``make_plan`` receives."""
-        return dict(tune=self.tune, cache=self.cache, top_k=self.top_k,
-                    reps=self.reps)
-
     def stage(self, name: str, kind: str, make_plan: Callable, *args, **kw):
-        """Stage one layer via its ``make_plan(*args, **kw, **tune_kw)``
-        → ``(run, tiles)`` contract. Returns self (chainable)."""
-        run, tiles = make_plan(*args, **kw, **self.tune_kw)
+        """Stage one layer via its ``make_plan(*args, **kw)`` → ``(run,
+        tiles)`` contract. Returns self (chainable)."""
+        run, tiles = make_plan(*args, **kw)
         self._stages.append(
             LayerPlan(name, kind, tuple(sorted(tiles.items())), run)
         )

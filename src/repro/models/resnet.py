@@ -125,14 +125,12 @@ class Bottleneck:
             return jax.nn.relu(runs["c3"](h) + short)
 
     def make_plan(self, params: dict, *, batch: int, h: int, w: int,
-                  out_scale=None, fused: bool = False, tune: str = "cache",
-                  cache=None, top_k: int = 4, reps: int = 3):
+                  out_scale=None, fused: bool = False):
         """Stage the whole block once (DESIGN.md §10): each conv's
         ``make_plan`` with its tiles pinned, composed by :meth:`forward`.
         Returns ``(run, tiles)``, tiles keyed ``c1.bf`` and so on."""
         p = {k: params[f"{self.name}.{k}"] for k, _ in self.convs()}
-        kw = dict(batch=batch, fused=fused, tune=tune, cache=cache,
-                  top_k=top_k, reps=reps)
+        kw = dict(batch=batch, fused=fused)
         ho, wo = self.out_hw(h, w)
         specs = {
             "c1": dict(h=h, w=w, relu=True,
@@ -368,8 +366,7 @@ class SparseResNet(CNNLifecycle):
         return out
 
     # ------------------------------------------- frozen serving plans (§10)
-    def plan(self, params: dict, *, batch: int, tune: str = "cache",
-             cache=None, top_k: int = 4, reps: int = 3):
+    def plan(self, params: dict, *, batch: int):
         """Freeze a serving plan (DESIGN.md §10): stages ``stem``,
         ``pool``, one per block (``s{i}b{j}``, each conv under its own
         scope inside), ``gap`` and ``fc`` — the path :meth:`apply` takes
@@ -379,8 +376,7 @@ class SparseResNet(CNNLifecycle):
         c = self.cfg
         fused = self._int8_chain_ready(params)
         blocks = self.blocks()
-        pb = PlanBuilder(c.name, params, batch=batch, tune=tune, cache=cache,
-                         top_k=top_k, reps=reps,
+        pb = PlanBuilder(c.name, params, batch=batch,
                          sample_spec=((c.image_size, c.image_size,
                                        c.in_channels), "float32"))
         stem = self.stem()

@@ -1,7 +1,6 @@
 """Small helpers over jax compiled-artifact introspection APIs, plus the
 shared wall-time measurement harness (``benchmarks/timing.py`` re-exports
-it and ``repro.kernels.autotune`` times candidates with it, so benchmark
-and autotuner numbers come from one code path).
+it, so benchmark and serving-driver numbers come from one code path).
 
 Measurement statistics (DESIGN.md §12): on a shared/contended host,
 scheduling noise is strictly *additive* — a sample is the true cost plus
@@ -114,12 +113,11 @@ def interleaved_time_us(fn_a, fn_b, *, warmup: int = 1, reps: int = 5,
                         stat: str = "median"):
     """``(a_us, b_us)`` wall times of two nullary callables sampled
     alternately (A, B, A, B, …) — the canonical harness for any paired
-    perf claim (winner-vs-default confirmation, fused-vs-unfused gates).
+    perf claim (fused-vs-unfused gates, plan-vs-unplanned).
 
     ``stat='min'`` over many reps is the noise-robust choice for gating
     (additive-noise argument in the module docstring); ``'median'`` is
-    kept as the default for the historical ``interleaved_medians`` alias
-    in :mod:`repro.kernels.autotune`.
+    the default.
     """
     sa, sb = interleaved_samples_us(fn_a, fn_b, warmup=warmup, reps=reps)
     return _reduce(sa, stat), _reduce(sb, stat)

@@ -40,7 +40,7 @@ def served():
     )
     _, stats = model.apply(params, x[:4], collect_act_stats=True)
     qparams = model.quantize(params, stats)
-    plan_set = model.plan_set(qparams, max_batch=4, tune="off")
+    plan_set = model.plan_set(qparams, max_batch=4)
     return model, qparams, np.asarray(x), plan_set
 
 
@@ -324,8 +324,7 @@ def test_serve_cli_exit_code(monkeypatch, tmp_path, poison):
     monkeypatch.setattr(server, "CNNServer",
                         functools.partial(server.CNNServer, faults=inj))
     argv = ["--arch", "sparse-cnn-tiny", "--smoke", "--server", "--batch", "4",
-            "--max-batch", "4", "--requests", "8", "--rate", "400",
-            "--tune", "off"]
+            "--max-batch", "4", "--requests", "8", "--rate", "400"]
     if poison:
         with pytest.raises(SystemExit) as e:
             serve.main(argv)

@@ -166,13 +166,12 @@ class TestLMPlan:
         model, tokens = tiny["model"], tiny["tokens"]
         f = jax.jit(lambda p, t: model.forward(p, {"tokens": t}))
         for params in (tiny["cparams"], tiny["qparams"]):
-            plan = model.plan(params, batch=2, seq=16, tune="off")
+            plan = model.plan(params, batch=2, seq=16)
             np.testing.assert_array_equal(
                 np.asarray(plan(tokens)), np.asarray(f(params, tokens)))
 
     def test_plan_stages(self, tiny):
-        plan = tiny["model"].plan(tiny["cparams"], batch=2, seq=16,
-                                  tune="off")
+        plan = tiny["model"].plan(tiny["cparams"], batch=2, seq=16)
         names = [lp.name for lp in plan.layers]
         assert names[0] == "embed" and names[-1] == "head"
         assert "g0.b0" in names and "g1.b0" in names
@@ -180,8 +179,7 @@ class TestLMPlan:
     def test_stale_plan_raises(self, tiny):
         from repro.models.plan import StalePlanError
 
-        plan = tiny["model"].plan(tiny["cparams"], batch=2, seq=16,
-                                  tune="off")
+        plan = tiny["model"].plan(tiny["cparams"], batch=2, seq=16)
         plan.check(tiny["cparams"])  # same params: fine
         with pytest.raises(StalePlanError):
             plan.check(tiny["qparams"])
@@ -189,7 +187,7 @@ class TestLMPlan:
     def test_unsupported_configs_raise(self, tiny):
         xcfg = dataclasses.replace(tiny["cfg"], cross_attn=True)
         with pytest.raises(NotImplementedError):
-            LM(xcfg).plan(tiny["cparams"], batch=2, seq=16, tune="off")
+            LM(xcfg).plan(tiny["cparams"], batch=2, seq=16)
 
 
 # ---------------------------------------------------------------------------
@@ -208,8 +206,8 @@ class TestRaggedKPlan:
         dw = lin.compress_params(lin.init(jax.random.PRNGKey(0)))
         ragged = dataclasses.replace(lin, in_features=20)
         with pytest.raises(ValueError, match="not a multiple"):
-            ragged.make_plan(dw, batch=16, tune="off")
-        run, tiles = lin.make_plan(dw, batch=16, tune="off")  # exact K: fine
+            ragged.make_plan(dw, batch=16)
+        run, tiles = lin.make_plan(dw, batch=16)  # exact K: fine
         assert tiles
 
     def test_ref_mode_unaffected(self):
@@ -219,7 +217,7 @@ class TestRaggedKPlan:
         lin = DBBLinear(24, 32, fmt, kernel_mode="ref")
         dw = lin.compress_params(lin.init(jax.random.PRNGKey(0)))
         ragged = dataclasses.replace(lin, in_features=20)
-        run, tiles = ragged.make_plan(dw, batch=16, tune="off")
+        run, tiles = ragged.make_plan(dw, batch=16)
         assert tiles == {}  # ref mode never freezes pallas tiles
 
 

@@ -201,7 +201,7 @@ def test_plan_is_bit_identical_to_apply(case, mode, quantized):
     model, _, params, x, q = case
     model = _model(mode)
     p = q if quantized else params
-    plan = model.plan(p, batch=8, tune="off")
+    plan = model.plan(p, batch=8)
     assert [l.name for l in plan.layers] == ["stem", "pool", "s1b1", "s2b1", "s3b1",
                                              "s4b1", "gap", "fc"]
     # apply compiled whole with the weights folded in, as the plan folds
@@ -240,7 +240,7 @@ def test_identity_shortcut_reads_the_block_input_scale():
 
 def test_plan_set_serves_ragged_batches(case):
     model, _, _, x, q = case
-    ps = model.plan_set(q, buckets=(4, 8), tune="off")
+    ps = model.plan_set(q, buckets=(4, 8))
     y = ps.serve(np.asarray(x[:5]))  # padded to the bucket of 8, sliced back
     np.testing.assert_array_equal(y, np.asarray(jax.jit(lambda x: model.apply(q, x))(x))[:5])
 
@@ -253,5 +253,5 @@ def test_serve_launcher_serves_resnet(monkeypatch, tmp_path):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     results = serve.main(["--arch", "sparse-resnet50", "--smoke", "--sparsity", "0.5",
                           "--server", "--batch", "4", "--max-batch", "4",
-                          "--requests", "6", "--rate", "400", "--tune", "off"])
+                          "--requests", "6", "--rate", "400"])
     assert len(results) == 6 and all(r is not None and r.shape == (1, 10) for r in results)

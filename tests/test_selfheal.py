@@ -37,7 +37,7 @@ def served():
     )
     _, stats = model.apply(params, x[:4], collect_act_stats=True)
     qparams = model.quantize(params, stats)
-    plan_set = model.plan_set(qparams, max_batch=4, tune="off")
+    plan_set = model.plan_set(qparams, max_batch=4)
     return model, qparams, np.asarray(x), plan_set
 
 
@@ -281,7 +281,7 @@ def test_hot_reload_swaps_atomically_and_corrupt_leaves_old(served, tmp_path):
     srv = CNNServer(ps, max_wait_ms=2.0)
     sup = Supervisor(
         srv,
-        rebuild=lambda tree: model.plan_set(tree, max_batch=4, tune="off"),
+        rebuild=lambda tree: model.plan_set(tree, max_batch=4),
         template=qparams,
     )
     with sup:
@@ -314,7 +314,7 @@ def test_swap_plan_set_validates_ladder(served):
     """The atomic swap refuses a plan set whose bucket ladder differs —
     the micro-batcher's aggregation targets would dangle."""
     model, qparams, _, ps = served
-    other = model.plan_set(qparams, max_batch=2, tune="off")
+    other = model.plan_set(qparams, max_batch=2)
     srv = CNNServer(ps, max_wait_ms=2.0)
     with srv:
         with pytest.raises(ValueError, match="ladder"):
@@ -383,6 +383,6 @@ def test_fallback_closures_pin_fingerprint(served):
             break
     other_q = jax.tree_util.tree_unflatten(treedef, flat)
     ref_model = SparseCNN(dataclasses.replace(model.cfg, kernel_mode="ref"))
-    other_set = ref_model.plan_set(other_q, buckets=ps.buckets, tune="off")
+    other_set = ref_model.plan_set(other_q, buckets=ps.buckets)
     with pytest.raises(StalePlanError):
         fallback_closures(ps, other_set)

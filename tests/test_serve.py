@@ -52,14 +52,14 @@ def _quantized_model(kernel_mode: str):
 def ref_served():
     """Ref-kernel model + quantized params + a bucketed plan set."""
     model, qparams, x = _quantized_model("ref")
-    plan_set = model.plan_set(qparams, max_batch=8, tune="off")
+    plan_set = model.plan_set(qparams, max_batch=8)
     return model, qparams, x, plan_set
 
 
 @pytest.fixture(scope="module")
 def pallas_served():
     model, qparams, x = _quantized_model("pallas")
-    plan_set = model.plan_set(qparams, max_batch=4, tune="off")
+    plan_set = model.plan_set(qparams, max_batch=4)
     return model, qparams, x, plan_set
 
 
@@ -426,7 +426,7 @@ def test_mesh_data_parallel_serve_matches_single_device():
     qparams = model.quantize(params, stats)
 
     # dp=2 (the mesh's data axis): every bucket shards evenly
-    plan_set = model.plan_set(qparams, max_batch=8, dp=2, tune="off")
+    plan_set = model.plan_set(qparams, max_batch=8, dp=2)
     assert plan_set.buckets == (2, 4, 8)
     single = np.asarray(plan_set.serve(x))          # single-device reference
 

@@ -41,7 +41,7 @@ def served():
     )
     _, stats = model.apply(params, x[:4], collect_act_stats=True)
     qparams = model.quantize(params, stats)
-    plan_set = model.plan_set(qparams, max_batch=4, tune="off")
+    plan_set = model.plan_set(qparams, max_batch=4)
     return model, qparams, np.asarray(x), plan_set
 
 
@@ -323,7 +323,7 @@ def test_reload_lands_between_dispatches(served, tmp_path):
     sup = Supervisor(
         srv, template=qparams,
         rebuild=lambda tree: on_device(
-            model.plan_set(tree, max_batch=4, tune="off"), dev, "new"))
+            model.plan_set(tree, max_batch=4), dev, "new"))
     reloaded = threading.Event()
 
     def reload():

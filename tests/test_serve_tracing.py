@@ -46,8 +46,7 @@ def served():
     x = jax.random.normal(jax.random.PRNGKey(1),
                           (16, cfg.image_size, cfg.image_size, cfg.in_channels))
     _, stats = model.apply(params, x[:4], collect_act_stats=True)
-    plan_set = model.plan_set(model.quantize(params, stats), max_batch=8,
-                              tune="off")
+    plan_set = model.plan_set(model.quantize(params, stats), max_batch=8)
     return np.asarray(x), plan_set
 
 

@@ -2,16 +2,14 @@
 
 Deterministic fake-clock tests of :mod:`repro.xla_utils` — median-of-k
 semantics, warmup exclusion, every supported statistic, interleaved
-(A, B, A, B, …) sample alternation, the noise estimator — plus the
-autotuner's confirmation-pass demotion logic driven through fake timers.
-No real timing: the clock is a scripted ``perf_counter`` and
+(A, B, A, B, …) sample alternation, and the noise estimator. No real
+timing: the clock is a scripted ``perf_counter`` and
 ``jax.block_until_ready`` is a recorder, so the tests pin the harness
 *contract* without inheriting host noise.
 """
 import pytest
 
 from repro import xla_utils
-from repro.kernels import autotune, core
 
 
 class FakeTime:
@@ -115,13 +113,6 @@ class TestInterleaved:
             lambda: None, lambda: None, warmup=0, reps=2, stat="min")
         assert (a, b) == (pytest.approx(10.0), pytest.approx(20.0))
 
-    def test_autotune_alias_delegates(self, clock, block):
-        clock([100.0, 300.0, 200.0, 400.0, 150.0, 350.0])
-        a, b = autotune.interleaved_medians(lambda: None, lambda: None,
-                                            warmup=0, reps=3)
-        assert a == pytest.approx(150.0)  # median{100, 200, 150}
-        assert b == pytest.approx(350.0)  # median{300, 400, 350}
-
 
 class TestNoiseFrac:
     def test_quiet_host_is_zero(self):
@@ -134,52 +125,3 @@ class TestNoiseFrac:
 
     def test_nonpositive_min_guard(self):
         assert xla_utils.noise_frac([0.0, 10.0]) == 0.0
-
-
-# ---------------------------------------------------------------------------
-# confirmation-pass demotion (_search) through fake timers
-# ---------------------------------------------------------------------------
-
-
-@pytest.fixture(autouse=True)
-def _clean_registry():
-    core.clear_tuned()
-    yield
-    core.clear_tuned()
-
-
-def _run_search(monkeypatch, *, confirm):
-    """Drive ``autotune._search`` with fake timers: candidate {bm: 1}
-    measures faster than the default {bm: 2}; ``confirm`` scripts the
-    interleaved head-to-head (winner_us, default_us)."""
-    sig = core.matmul_sig(64, 128, 96, 8, 3, "float32")
-    monkeypatch.setattr(
-        autotune, "median_time_us",
-        lambda fn, *a, **k: 50.0 if fn() == {"bm": 1} else 100.0)
-    monkeypatch.setattr(
-        autotune, "interleaved_medians", lambda *a, **k: confirm)
-    return autotune._search(
-        core.KIND_MATMUL_TC, sig, [{"bm": 1}, {"bm": 2}],
-        cost_fn=lambda t: t["bm"], build=lambda t: (lambda: t),
-        default_tiles={"bm": 2}, top_k=2, reps=3, warmup=1,
-        cache=None, save=False,
-    )
-
-
-class TestSearchDemotion:
-    def test_replicating_winner_is_kept(self, monkeypatch):
-        res = _run_search(monkeypatch, confirm=(50.0, 100.0))
-        assert res.tiles == {"bm": 1}
-        assert res.measured_us == 50.0 and res.default_us == 100.0
-
-    def test_non_replicating_winner_demoted_to_default(self, monkeypatch):
-        """An apparent win that does not replicate beyond CONFIRM_MARGIN in
-        the interleaved pass must never be persisted."""
-        res = _run_search(monkeypatch, confirm=(98.0, 100.0))  # a tie
-        assert res.tiles == {"bm": 2}
-        assert res.measured_us == res.default_us == 100.0
-
-    def test_margin_boundary(self, monkeypatch):
-        # exactly at the margin: 95.2 * 1.05 = 99.96 <= 100 -> kept
-        res = _run_search(monkeypatch, confirm=(95.0, 100.0))
-        assert res.tiles == {"bm": 1}

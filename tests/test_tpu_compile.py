@@ -170,7 +170,7 @@ def test_resnet50_stem(one_chip):
 
     def fn(x, w, b, o):
         run, _ = stem.make_plan({"w": w, "b": b}, batch=B, h=224, w=224, relu=True,
-                                out_scale=o, fused=True, tune="off")
+                                out_scale=o, fused=True)
         return max_pool(run(x))
 
     compiled = jax.jit(fn).lower(

@@ -221,7 +221,13 @@ class DBBConv2d:
         if tiled:
             _, _, (ho, wo) = conv_geometry(h, w, self.kh, self.kw,
                                            self.stride, self.padding)
-            tiles = default_conv_tiles(ho, wo, self.out_channels, self.kh, self.kw)
+            if stem_fused:  # dense (kh, kw, C, F)
+                block = (self.kh * self.kw * self.in_channels, wp.dtype.itemsize)
+            else:
+                from repro.kernels.vdbb_im2col_conv import weight_block
+
+                block = weight_block(wp.values, wp.fmt)
+            tiles = default_conv_tiles(ho, wo, self.out_channels, *block)
         if quant and fused and residual_scale is not None:
             def run(x, residual):
                 return self.quant_serve(params, x, relu=relu,

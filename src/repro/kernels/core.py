@@ -160,18 +160,29 @@ def default_matmul_tiles(m: int, k: int, n: int, bz: int, dtype) -> dict:
             "kb": default_kb(k // bz, bz)}
 
 
-def default_bf(f: int, kh: int = 3, kw: int = 3) -> int:
-    """The F block of a fused conv: lane-aligned 128 (or all of F),
-    and all of F for a 1×1 conv, whose activation mux (``dbb_mux``) would
-    otherwise run again for every F block — whole-F blocks ran 8–29 %
-    faster on v5e at ResNet-50's 1×1 shapes (PERF.md §3)."""
-    return aligned_divisor(f, f if kh == kw == 1 else 128, LANES)
+# VMEM the weight block of a fused conv's default F block may take, double
+# buffered by the pipeline (v5e scopes 16 MiB a kernel; ResNet-50's largest,
+# s4's 3×3 at 4/8, holds 2304 × 512 int8: 1.2 MB)
+CONV_WEIGHT_VMEM = 8 << 20
 
 
-def default_conv_tiles(ho: int, wo: int, f: int, kh: int = 3, kw: int = 3) -> dict:
+def default_bf(f: int, k: int, itemsize: int = 1) -> int:
+    """The F block of a fused conv whose weight block has ``k`` rows of
+    ``itemsize`` bytes a column: all of F. The kernels build an output
+    tile's activation patches, and in tc mode its mux (``dbb_mux``), once
+    per F block, so a narrower block repeats them and re-streams every
+    weight block for every image — a 1×1 conv ran 8–29 % faster on v5e
+    with all of F (PERF.md §3), and a k×k conv's mux is as deep as its
+    main dot. Only where a double-buffered whole-F weight block would
+    overflow :data:`CONV_WEIGHT_VMEM`: the largest lane-aligned divisor of
+    F that fits."""
+    return aligned_divisor(f, CONV_WEIGHT_VMEM // (2 * k * itemsize), LANES)
+
+
+def default_conv_tiles(ho: int, wo: int, f: int, k: int, itemsize: int = 1) -> dict:
     """The ``(bf, tile_h, tile_w)`` of one fused-conv launch: the
     :func:`default_bf` block over the whole output map."""
-    return {"bf": default_bf(f, kh, kw), "tile_h": ho, "tile_w": wo}
+    return {"bf": default_bf(f, k, itemsize), "tile_h": ho, "tile_w": wo}
 
 
 def pad_tile(dim: int, tile, default: int, align: int = 1) -> tuple:

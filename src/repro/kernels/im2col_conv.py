@@ -91,7 +91,8 @@ def conv_out_spec(geom, bf):
 
 
 def tap_geom(geom) -> dict:
-    """The statics :func:`conv_taps` needs, from a :func:`plan_conv` geom."""
+    """The statics :func:`conv_taps` and :func:`packed_patches` need, from
+    a :func:`plan_conv` geom."""
     return {k: geom[k] for k in ("kh", "kw", "sh", "sw", "bh", "bw")}
 
 
@@ -117,19 +118,29 @@ def conv_taps(x_ref, tap_contribution, acc_ref, *, kh, kw, sh, sw, bh, bw):
 PACKED_PIXELS = 512
 
 
+def packed_patches(x_ref, tap, *, kh, kw, sh, sw, bh, bw):
+    """The packed im2col patches of one output tile, chunk by chunk: for
+    each static chunk of ``rh`` output rows (about :data:`PACKED_PIXELS`
+    pixels) yield ``(r, rh, patch)``, where ``patch`` holds the kh·kw taps'
+    ``tap(t, window)`` matrices side by side along the lanes; ``window`` is
+    tap ``t``'s (rh·bw, C) activation matrix (:func:`core.conv_tap`). One
+    tap packs nothing, so a 1×1 conv's whole tile is one chunk."""
+    rh = bh if kh * kw == 1 else core.aligned_divisor(bh, PACKED_PIXELS // bw, 1)
+    for r in range(0, bh, rh):  # tap row dy + r·sh: phase dy % sh, row dy // sh + r
+        yield r, rh, jnp.concatenate(
+            [tap(t, core.conv_tap(x_ref, t // kw + r * sh, t % kw, bh=rh, bw=bw,
+                                  sh=sh, sw=sw))
+             for t in range(kh * kw)], axis=1)
+
+
 def _im2col_conv_packed_kernel(x_ref, w_ref, *rest, geom, ep=None):
     """Grid: (N·th·tw, F/bf). x: (1, sh·sw, Hq, Wq, C); w: (kh·kw·C, bf).
     The kh·kw taps of ``rh`` output rows sit side by side along the lanes
-    of one (rh·bw, kh·kw·C) patch, one MXU contraction each; no
-    accumulator scratch."""
+    of one (rh·bw, kh·kw·C) patch (:func:`packed_patches`), one MXU
+    contraction each; no accumulator scratch."""
     flush, o_ref, _ = core.split_epilogue(ep, (*rest, None))
-    kh, kw, sh, sw, bh, bw = (geom[k] for k in ("kh", "kw", "sh", "sw", "bh", "bw"))
-    rh = core.aligned_divisor(bh, PACKED_PIXELS // bw, 1)
     w = w_ref[...]
-    for r in range(0, bh, rh):  # tap row dy + r·sh: phase dy % sh, row dy // sh + r
-        patch = jnp.concatenate(
-            [core.conv_tap(x_ref, t // kw + r * sh, t % kw, bh=rh, bw=bw, sh=sh, sw=sw)
-             for t in range(kh * kw)], axis=1)
+    for r, rh, patch in packed_patches(x_ref, lambda t, window: window, **geom):
         core.store_epilogue(core.mxu_dot(patch, w), o_ref.at[:, pl.ds(r, rh)], **flush)
 
 
@@ -157,7 +168,8 @@ def im2col_conv(
     if wc != c:
         raise ValueError(f"channel mismatch: x has {c}, w has {wc}")
     xt, g = plan_conv(x, kh, kw, stride=stride, padding=padding, tile_h=tile_h, tile_w=tile_w)
-    bf = core.default_bf(f, kh, kw) if bf is None else core.resolve_tile(f, bf, "bf")
+    bf = (core.default_bf(f, kh * kw * c, w.dtype.itemsize) if bf is None
+          else core.resolve_tile(f, bf, "bf"))
     grid = (n * g["th"] * g["tw"], f // bf)
     acc_dtype = core.acc_dtype_for(x.dtype)  # int32 on the int8 path (§8)
     ep, e_ops, e_specs, out_dtype = core.epilogue_plan(

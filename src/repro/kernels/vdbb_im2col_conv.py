@@ -14,20 +14,33 @@ in-VMEM transforms in one kernel:
 The conv weight (kh, kw, C, F) is DBB-compressed along K = kh·kw·C with
 C % bz == 0, so every bz-block lies inside a single kernel tap and tap
 (dy, dx) reads exactly its own C/bz compressed blocks. Geometry, tiling,
-and the output-stationary accumulator all come from
-:mod:`repro.kernels.core` (DESIGN.md §6).
+and the epilogue all come from :mod:`repro.kernels.core` (DESIGN.md §6).
 
 Tiling, as the TPU compiler enforces it: the grid is (output tile, F
-block) with bf a multiple of 128 or all of F; one step holds the whole
-(kh·kw, cb·nnz, bf) compressed weight block, whose middle dim is the
-whole array dim and so needs no alignment; the kh·kw taps run as a
-static loop inside the step, each a static contiguous window of the
-stride-phase-split input tile (no ``dynamic_slice``, no strided 8-bit
-load). The per-tap mux is the 2-D selection matmul ``core.dbb_mux`` —
-a ``(bh·bw, C) → (bh·bw, cb, bz)`` reshape splits the lane axis, which
-the compiler refuses. A 1×1 conv is the same kernel with one tap (a
-strided one reads its input subsampled, ``plan_conv``); the int8 maps of
-ResNet-50 (56, 28, 14 and 7 wide) compile for v5e as they are, W unpadded
+block), and the F block is all of F (``core.default_bf``; smaller only
+where a whole-F weight block would overflow VMEM). One step holds the
+whole (kh·kw, cb·nnz, bf) compressed weight block, whose middle dim is
+the whole array dim and so needs no alignment. The kh·kw taps are each
+a static contiguous window of the stride-phase-split input tile (no
+``dynamic_slice``, no strided 8-bit load). The mux is the 2-D selection
+matmul ``core.dbb_mux`` — a ``(bh·bw, C) → (bh·bw, cb, bz)`` reshape
+splits the lane axis, which the compiler refuses.
+
+tc mode packs the compressed patch: the output rows go in static chunks
+of about ``PACKED_PIXELS`` pixels; per chunk each tap's window goes
+through the mux once, the taps' (rows, cb·nnz) compressed columns sit
+side by side along the lanes of one (rows, kh·kw·cb·nnz) patch
+(:func:`repro.kernels.im2col_conv.packed_patches`, as the dense conv
+packs its raw taps), and one MXU dot contracts it against the weight
+block viewed, inside the kernel, as (kh·kw·cb·nnz, bf). The result goes
+straight into the chunk's flush: no accumulator scratch. A contraction
+of K = cb·nnz (8–96 at sparse-cnn-s's widths) pays a whole 128-deep
+pass per tap; packed, the nine taps share ⌈9·cb·nnz/128⌉. bw mode
+accumulates one dense dot per tap in a VMEM scratch.
+
+A 1×1 conv is the same kernel with one tap (a strided one reads its
+input subsampled, ``plan_conv``); the int8 maps of ResNet-50 (56, 28,
+14 and 7 wide) compile for v5e as they are, W unpadded
 (``tests/test_tpu_compile.py``). Calls are named by kind
 (:func:`kernel_name`): ``vdbb_im2col_conv_tc`` for a k×k conv,
 ``…_1x1`` for a pointwise one, ``…_res`` where the flush adds a residual.
@@ -49,7 +62,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.vdbb import DBBFormat, DBBWeight
 from repro.kernels import core
 from repro.kernels.im2col_conv import (
-    conv_in_spec, conv_out_spec, conv_taps, plan_conv, tap_geom,
+    conv_in_spec, conv_out_spec, conv_taps, packed_patches, plan_conv, tap_geom,
 )
 from repro.kernels.vdbb_matmul import dbb_expand_block
 
@@ -79,15 +92,26 @@ def _vdbb_conv_tc_kernel(x_ref, v_ref, pos_ref, *rest, geom, ep=None):
     bf); pos: (kh·kw, cb·nnz, 1) int32 — per tap, the channel each
     compressed-K column reads; ``rest`` carries the optional (1, bf) fp32
     epilogue rows named by the static ``ep`` (scale/bias/out_scale —
-    DESIGN.md §9)."""
-    flush, o_ref, acc_ref = core.split_epilogue(ep, rest)
+    DESIGN.md §9), and a residual tile, sliced here to each chunk's rows.
 
-    def tap(t, patch):
-        # The activation mux: patch[:, pos[t, j]] -> compressed K.
-        return core.mxu_dot(core.dbb_mux(patch, pos_ref[t]), v_ref[t])
+    Per chunk of output rows, each tap's window goes through the mux
+    once; the taps' compressed columns sit side by side along the lanes of
+    one (rows, kh·kw·cb·nnz) patch (:func:`packed_patches`), contracted in
+    one MXU dot against the weight block viewed as (kh·kw·cb·nnz, bf) —
+    the row order of that view is the patch's lane order. No accumulator
+    scratch: each chunk's result goes straight into its flush."""
+    flush, o_ref, _ = core.split_epilogue(ep, (*rest, None))
+    taps, ck, bf = v_ref.shape
+    w = v_ref[...].reshape(taps * ck, bf)
+    residual = flush.pop("residual", None)
 
-    conv_taps(x_ref, tap, acc_ref, **geom)
-    core.store_epilogue(acc_ref[...], o_ref, **flush)
+    def mux(t, window):  # the activation mux: window[:, pos[t, j]] -> compressed K
+        return core.dbb_mux(window, pos_ref[t])
+
+    for r, rh, patch in packed_patches(x_ref, mux, **geom):
+        if residual is not None:
+            flush["residual"] = residual[:, r:r + rh]
+        core.store_epilogue(core.mxu_dot(patch, w), o_ref.at[:, pl.ds(r, rh)], **flush)
 
 
 # ---------------------------------------------------------------------------
@@ -114,6 +138,15 @@ def _vdbb_conv_bw_kernel(x_ref, v_ref, idx_ref, *rest, bz, geom, ep=None):
 # ---------------------------------------------------------------------------
 
 
+def weight_block(values: jax.Array, fmt: DBBFormat) -> tuple:
+    """``(k, itemsize)`` of the fused conv's weight block for compressed
+    ``values`` (nb, nnz, F), as :func:`core.default_bf` sizes it: the kept
+    rows, and the bytes an entry takes across the weight operands (in bw
+    mode the int32 per-column indices ride beside the values)."""
+    nb, nnz, f = values.shape
+    return nb * nnz, values.dtype.itemsize + (0 if fmt.group_size(f) == f else 4)
+
+
 def kernel_name(base: str, kh: int, kw: int, residual) -> str:
     """The Pallas call's name in a device profile: ``base``, with ``_1x1``
     for a pointwise conv and ``_res`` when the flush adds a residual, so a
@@ -122,8 +155,11 @@ def kernel_name(base: str, kh: int, kw: int, residual) -> str:
 
 
 def _launch(kernel, name, x, operands, wspecs, kh, kw, *, stride, padding,
-            bf, tile_h, tile_w, out_dtype, interpret, scales=None, bias=None,
-            relu=False, out_scale=None, residual=None, residual_scale=None):
+            bf, tile_h, tile_w, out_dtype, interpret, accumulate=False,
+            scales=None, bias=None, relu=False, out_scale=None, residual=None,
+            residual_scale=None):
+    """Launch a fused conv; ``accumulate`` gives the kernel a (bh·bw, bf)
+    accumulator scratch after its output ref (the per-tap bw kernel)."""
     n = x.shape[0]
     f = operands[0].shape[-1]
     xt, g = plan_conv(x, kh, kw, stride=stride, padding=padding,
@@ -144,7 +180,8 @@ def _launch(kernel, name, x, operands, wspecs, kh, kw, *, stride, padding,
         in_specs=[conv_in_spec(xt), *wspecs],
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((n, g["ho"], g["wo"], f), out_dtype),
-        scratch_shapes=[pltpu.VMEM((g["bh"] * g["bw"], bf), acc_dtype)],
+        scratch_shapes=([pltpu.VMEM((g["bh"] * g["bw"], bf), acc_dtype)]
+                        if accumulate else []),
         interpret=core.resolve_interpret(interpret),
         name=kernel_name(name, kh, kw, residual),
     )(xt, *operands)
@@ -182,7 +219,8 @@ def vdbb_im2col_conv_tc(
     nb, nnz, f = values.shape
     c = nb * fmt.bz // (kh * kw)
     cb = c // fmt.bz
-    bf = core.default_bf(f, kh, kw) if bf is None else core.resolve_tile(f, bf, "bf")
+    bf = (core.default_bf(f, *weight_block(values, fmt)) if bf is None
+          else core.resolve_tile(f, bf, "bf"))
     v = values.reshape(kh * kw, cb * nnz, f)
     # per tap, the channel of the (·, C) patch each compressed column reads
     pos = core.mux_positions(indices, cb, fmt.bz).reshape(kh * kw, cb * nnz, 1)
@@ -226,7 +264,8 @@ def vdbb_im2col_conv_bw(
     nb, nnz, f = values.shape
     c = nb * fmt.bz // (kh * kw)
     cb = c // fmt.bz
-    bf = core.default_bf(f, kh, kw) if bf is None else core.resolve_tile(f, bf, "bf")
+    bf = (core.default_bf(f, *weight_block(values, fmt)) if bf is None
+          else core.resolve_tile(f, bf, "bf"))
     # (kh·kw, nnz, cb, F): per tap, slot-major blocks (dbb_expand_block)
     v = values.reshape(kh * kw, cb, nnz, f).transpose(0, 2, 1, 3)
     idx = indices.astype(jnp.int32).reshape(kh * kw, cb, nnz, f).transpose(0, 2, 1, 3)
@@ -236,7 +275,8 @@ def vdbb_im2col_conv_bw(
         x, (v, idx),
         [spec, spec], kh, kw,
         stride=stride, padding=padding, bf=bf, tile_h=tile_h, tile_w=tile_w,
-        out_dtype=out_dtype, interpret=interpret, scales=scales, bias=bias,
+        out_dtype=out_dtype, interpret=interpret, accumulate=True,
+        scales=scales, bias=bias,
         relu=relu, out_scale=out_scale, residual=residual,
         residual_scale=residual_scale,
     )

@@ -282,8 +282,8 @@ def _cnn_launch(model, launch: str):
     if i == 0:
         # the fp32 stem runs on XLA's conv in a CPU plan (interpret-mode
         # Pallas loses to it there); on the chip the plan freezes the rule
-        return core.default_conv_tiles(dims["tile_h"], dims["tile_w"],
-                                       m.out_channels, m.kh, m.kw), dims
+        return core.default_conv_tiles(dims["tile_h"], dims["tile_w"], m.out_channels,
+                                       m.kh * m.kw * m.in_channels, 4), dims
     _, tiles = m.make_plan({"w": _qweight(m), "aq": 1.0}, batch=BUCKET, h=h,
                            w=w, relu=True, out_scale=1.0, fused=True)
     return tiles, dims
@@ -313,18 +313,18 @@ def _resnet_launch(model, launch: str):
             if k.split(".")[0] == conv}, dims
 
 
-# What every ledger line ran: the tiles each launch froze at the parent of
-# the change that made kernels/core.py's rule the only source of tiles.
+# The tiles each launch freezes from kernels/core.py's rule: all of F in one
+# block for every conv, 1×1 and k×k.
 CELL_TILES = {
     'sparse-cnn-s.d3of8': {
         'l0': {'bf': 64, 'tile_h': 64, 'tile_w': 64},
         'l1': {'bf': 64, 'tile_h': 64, 'tile_w': 64},
         'l2': {'bf': 128, 'tile_h': 32, 'tile_w': 32},
         'l3': {'bf': 128, 'tile_h': 32, 'tile_w': 32},
-        'l4': {'bf': 128, 'tile_h': 16, 'tile_w': 16},
-        'l5': {'bf': 128, 'tile_h': 16, 'tile_w': 16},
-        'l6': {'bf': 128, 'tile_h': 8, 'tile_w': 8},
-        'l7': {'bf': 128, 'tile_h': 8, 'tile_w': 8},
+        'l4': {'bf': 256, 'tile_h': 16, 'tile_w': 16},
+        'l5': {'bf': 256, 'tile_h': 16, 'tile_w': 16},
+        'l6': {'bf': 512, 'tile_h': 8, 'tile_w': 8},
+        'l7': {'bf': 512, 'tile_h': 8, 'tile_w': 8},
         'l8': {'bm': 128, 'bn': 256, 'kb': 16},
     },
     'sparse-cnn-s.d1of8': {
@@ -332,10 +332,10 @@ CELL_TILES = {
         'l1': {'bf': 64, 'tile_h': 64, 'tile_w': 64},
         'l2': {'bf': 128, 'tile_h': 32, 'tile_w': 32},
         'l3': {'bf': 128, 'tile_h': 32, 'tile_w': 32},
-        'l4': {'bf': 128, 'tile_h': 16, 'tile_w': 16},
-        'l5': {'bf': 128, 'tile_h': 16, 'tile_w': 16},
-        'l6': {'bf': 128, 'tile_h': 8, 'tile_w': 8},
-        'l7': {'bf': 128, 'tile_h': 8, 'tile_w': 8},
+        'l4': {'bf': 256, 'tile_h': 16, 'tile_w': 16},
+        'l5': {'bf': 256, 'tile_h': 16, 'tile_w': 16},
+        'l6': {'bf': 512, 'tile_h': 8, 'tile_w': 8},
+        'l7': {'bf': 512, 'tile_h': 8, 'tile_w': 8},
         'l8': {'bm': 128, 'bn': 256, 'kb': 16},
     },
     'resnet50.d4of8': {
@@ -351,17 +351,17 @@ CELL_TILES = {
         's2b2.c1': {'bf': 128, 'tile_h': 28, 'tile_w': 28},
         's2b2.c2': {'bf': 128, 'tile_h': 28, 'tile_w': 28},
         's3b1.c1': {'bf': 256, 'tile_h': 28, 'tile_w': 28},
-        's3b1.c2': {'bf': 128, 'tile_h': 14, 'tile_w': 14},
+        's3b1.c2': {'bf': 256, 'tile_h': 14, 'tile_w': 14},
         's3b1.c3': {'bf': 1024, 'tile_h': 14, 'tile_w': 14},
         's3b1.proj': {'bf': 1024, 'tile_h': 14, 'tile_w': 14},
         's3b2.c1': {'bf': 256, 'tile_h': 14, 'tile_w': 14},
-        's3b2.c2': {'bf': 128, 'tile_h': 14, 'tile_w': 14},
+        's3b2.c2': {'bf': 256, 'tile_h': 14, 'tile_w': 14},
         's4b1.c1': {'bf': 512, 'tile_h': 14, 'tile_w': 14},
-        's4b1.c2': {'bf': 128, 'tile_h': 7, 'tile_w': 7},
+        's4b1.c2': {'bf': 512, 'tile_h': 7, 'tile_w': 7},
         's4b1.c3': {'bf': 2048, 'tile_h': 7, 'tile_w': 7},
         's4b1.proj': {'bf': 2048, 'tile_h': 7, 'tile_w': 7},
         's4b2.c1': {'bf': 512, 'tile_h': 7, 'tile_w': 7},
-        's4b2.c2': {'bf': 128, 'tile_h': 7, 'tile_w': 7},
+        's4b2.c2': {'bf': 512, 'tile_h': 7, 'tile_w': 7},
         'fc': {'bm': 128, 'bn': 256, 'kb': 16},
     },
 }
@@ -379,3 +379,19 @@ def test_cell_tiles_match_parent(config, launch):
     assert dict(tiles) == CELL_TILES[config][launch]
     for name, dim in dims.items():
         assert dim % tiles[name] == 0, (name, tiles[name], dim)
+
+
+# (F, weight rows, bytes an entry, the F block): all of F while the
+# double-buffered weight block fits core.CONV_WEIGHT_VMEM, else the largest
+# lane-aligned divisor that does
+@pytest.mark.parametrize("f,k,itemsize,bf", [
+    (512, 2304, 1, 512),     # ResNet-50 s4's 3×3 at 4/8: 1.2 MB
+    (2048, 256, 1, 2048),    # its last c3 (1×1, 512 → 2048)
+    (64, 27, 4, 64),         # the fp32 stem
+    (2048, 4608, 4, 128),    # a dense fp32 3×3, 512 → 2048: 38 MB whole
+    (1024, 4608, 1, 512),    # int8, 512 → 1024: 9.4 MB whole, 4.7 MB at 512
+    (192, 10**5, 1, 192),    # no lane-aligned divisor: the whole dim
+])
+def test_default_bf_whole_f_within_vmem(f, k, itemsize, bf):
+    assert core.default_bf(f, k, itemsize) == bf
+    assert bf == f or 2 * k * itemsize * bf <= core.CONV_WEIGHT_VMEM

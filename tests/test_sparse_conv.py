@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.quant import QuantDBBWeight, quantize_dbb
 from repro.core.sparse_conv import DBBConv2d
 from repro.core.vdbb import (
     DBBFormat,
@@ -181,6 +182,78 @@ class TestFusedSparseConv:
         bad = dataclasses.replace(dw, shape=(9 * 4, 8))  # C=4 not % bz=8
         with pytest.raises(ValueError, match="straddle"):
             vdbb_im2col_conv(x, bad, 3, 3)
+
+
+def _int8_conv(n, h, c, f, k, nnz, seed):
+    """int8 codes and a 3/8-style int8 compressed weight with power-of-two
+    scales, so every fp32 flush below is exact whatever order its
+    multiply-adds run in."""
+    k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(seed), 4)
+    x = jax.random.randint(k1, (n, h, h, c), -127, 128).astype(jnp.int8)
+    w4 = jax.random.normal(k2, (k, k, c, f), jnp.float32)
+    qw = quantize_dbb(dbb_encode_conv(w4, DBBFormat(8, nnz, "matrix"), prune=True))
+    scales = 2.0 ** -jax.random.randint(k3, (f,), 6, 10).astype(jnp.float32)
+    bias = jax.random.randint(k4, (f,), -64, 64).astype(jnp.float32) / 4
+    return x, QuantDBBWeight(qw.values, qw.indices, scales, qw.fmt, qw.shape), bias
+
+
+class TestPackedTcConv:
+    """The tc conv muxes each tap once per chunk of output rows and contracts
+    the packed compressed patch in one dot over all of F: on int8 operands
+    it equals the exact int32 oracle bit for bit, for every flush."""
+
+    @pytest.mark.parametrize(
+        "n,h,c,f,k,stride,nnz,flush,bf",
+        [
+            (2, 8, 64, 64, 3, 1, 3, "raw", None),         # Ck = 24
+            (2, 9, 64, 128, 3, 2, 1, "requant", None),    # Ck = 8, stride 2
+            (1, 32, 64, 64, 3, 1, 3, "raw", None),        # two 512-pixel chunks
+            (1, 8, 128, 128, 3, 1, 3, "requant", None),   # Ck = 48
+            (2, 8, 128, 64, 3, 2, 4, "raw", None),        # Ck = 64, stride 2
+            (1, 4, 512, 128, 3, 1, 3, "requant", None),   # Ck = 192
+            (1, 6, 512, 128, 3, 2, 1, "requant", None),   # Ck = 64, stride 2
+            (1, 24, 64, 64, 3, 1, 4, "res_requant", None),  # residual over 2 chunks
+            (1, 8, 128, 128, 3, 2, 4, "res_fp32", None),  # the fp32-out _res flush
+            (1, 8, 64, 128, 3, 1, 3, "fp32", None),       # dequant + bias, fp32 out
+            (1, 32, 64, 128, 1, 1, 4, "res_requant", None),  # 1×1 _res, one chunk
+            (1, 8, 64, 256, 3, 1, 3, "requant", 128),     # explicit bf < F
+        ],
+        ids=["c64_ck24", "c64_ck8_s2", "c64_chunks", "c128_ck48", "c128_ck64_s2",
+             "c512_ck192", "c512_ck64_s2", "res_q_chunks", "res_fp32", "fp32",
+             "1x1_res", "bf128_of_256"],
+    )
+    def test_int8_bit_exact_vs_int32_oracle(self, n, h, c, f, k, stride, nnz, flush, bf):
+        x, qw, bias = _int8_conv(n, h, c, f, k, nnz, seed=h + c + nnz)
+        pad = (k // 2, k // 2)
+        padding = (pad, pad)
+        acc = ref.sparse_conv_int_ref(x, qw.as_dbb(), k, k, stride=stride, padding=padding)
+        if flush == "raw":
+            got = ops.sparse_conv(x, qw.as_dbb(), k, k, stride=stride, padding=padding,
+                                  bf=bf, interpret=True)
+            assert got.dtype == jnp.int32
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(acc))
+            return
+        act = jnp.float32(0.25)
+        res = res_scale = None
+        if flush.startswith("res"):
+            res = jax.random.randint(jax.random.PRNGKey(5), acc.shape, -127, 128).astype(jnp.int8)
+            res_scale = jnp.float32(0.5)
+        out_scale = None
+        if flush.endswith("requant"):  # a power of two near the 99th percentile / 127
+            y = ref.quant_epilogue_ref(acc, act * qw.scales, bias=bias, residual=res,
+                                       residual_scale=res_scale)
+            out_scale = float(2.0 ** np.round(np.log2(np.percentile(np.abs(y), 99) / 127)))
+        got = ops.quant_conv(x, qw, k, k, act, bias=bias, relu=flush != "fp32",
+                             out_scale=out_scale, residual=res, residual_scale=res_scale,
+                             stride=stride, padding=padding, bf=bf, interpret=True)
+        want = ref.quant_epilogue_ref(acc, act * qw.scales, bias=bias, relu=flush != "fp32",
+                                      out_scale=out_scale, residual=res,
+                                      residual_scale=res_scale)
+        assert got.dtype == want.dtype == (jnp.float32 if out_scale is None else jnp.int8)
+        if out_scale is not None:  # the codes span the int8 range, few clipped
+            codes = np.abs(np.asarray(want, np.int32))
+            assert codes.max() == 127 and np.mean(codes == 127) < 0.05
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 class TestGroupedRoundTrip:

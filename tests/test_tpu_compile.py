@@ -11,7 +11,11 @@ The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library,
 and every test worker imports every test file.
 """
+import importlib
+import json
+import pathlib
 import re
+import types
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +24,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.quant import QuantDBBWeight
 from repro.core.vdbb import DBBFormat, DBBWeight
-from repro.kernels import ops
+from repro.kernels import core, ops
 
 FMT = DBBFormat(8, 3, "matrix")  # sparse-cnn-s: 3/8 DBB, shared patterns
 FMT_BW = DBBFormat(8, 3, None)   # paper-faithful per-column patterns
@@ -69,10 +73,14 @@ def _weight(k, n, dtype, sharding, fmt=FMT):
     return DBBWeight(_spec((nb, fmt.nnz, n), dtype, sharding), idx, fmt, (k, n))
 
 
-def _assert_kernel(fn, *args):
+def _compile(fn, *args):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
-    return compiled.as_text()
+    return compiled
+
+
+def _assert_kernel(fn, *args):
+    return _compile(fn, *args).as_text()
 
 
 @pytest.mark.parametrize("n", [8, 128])  # 128: the benchmark's bucket
@@ -119,6 +127,33 @@ def test_tc_sparse_conv(one_chip, h, c, f, stride, dtype):
             x, w, 3, 3, stride=stride, interpret=False)
         args = ()
     _assert_kernel(fn, _spec((8, h, h, c), dtype, s), w, *args)
+
+
+# sparse-cnn-s's compressed 3×3 convs l1–l7 (H, C, F, stride), bucket 128
+CNN_CONVS = {"l1": (64, 64, 64, 1), "l2": (64, 64, 128, 2), "l3": (32, 128, 128, 1),
+             "l4": (32, 128, 256, 2), "l5": (16, 256, 256, 1), "l6": (16, 256, 512, 2),
+             "l7": (8, 512, 512, 1)}
+
+
+def _cnn_conv3x3(one_chip, layer, nnz):
+    """sparse-cnn-s's ``layer`` at ``nnz``/8 compiled as the plan serves
+    it: int8 in, the epilogue fused, all of F in one block."""
+    h, c, f, stride = CNN_CONVS[layer]
+    s = one_chip
+    w = _weight(9 * c, f, jnp.int8, s, DBBFormat(8, nnz, "matrix"))
+    assert core.default_bf(f, 9 * c // 8 * nnz) == f
+    return _compile(
+        lambda x, w, a, b, o: ops.quant_conv(
+            x, w, 3, 3, a, bias=b, relu=True, out_scale=o, stride=stride, bf=f,
+            interpret=False),
+        _spec((B, h, h, c), jnp.int8, s), w, _spec((), jnp.float32, s),
+        _spec((f,), jnp.float32, s), _spec((), jnp.float32, s))
+
+
+@pytest.mark.parametrize("nnz", [3, 1], ids=["d3of8", "d1of8"])
+@pytest.mark.parametrize("layer", list(CNN_CONVS))
+def test_cnn_conv3x3(one_chip, layer, nnz):
+    assert _kernels(_cnn_conv3x3(one_chip, layer, nnz).as_text()) == {"vdbb_im2col_conv_tc"}
 
 
 def test_int8_head_quant_matmul(one_chip):
@@ -242,3 +277,56 @@ def test_resnet50_head(one_chip):
         _spec((B, 2048), jnp.float32, s), _weight(2048, 1000, jnp.int8, s, FMT_R50),
         _spec((), jnp.float32, s), _spec((1000,), jnp.float32, s))
     assert _kernels(hlo) == {"vdbb_matmul_tc"}
+
+
+# ---------------------------------------------------------------------------
+# the benchmark's roofline readers find the compiled calls
+# ---------------------------------------------------------------------------
+
+BENCH = pathlib.Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+
+
+def _call_line(compiled, name):
+    """The HLO instruction of the Pallas call ``name`` as a device trace
+    names the kernel's event: operands printed with their shapes."""
+    from jax._src.lib import xla_client
+
+    opts = xla_client._xla.HloPrintOptions()
+    opts.print_operand_shape = True
+    hlo = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+    return re.search(rf"^\s*(?:ROOT )?(%{name}(?:\.\d+)? = .*custom-call\(.*)$",
+                     hlo, re.M).group(1)
+
+
+@pytest.mark.parametrize("case", ["cnn.l1.d3of8", "cnn.l7.d1of8", "r50.c2.56", "r50.c2.7"])
+def test_rooflines_match_compiled_conv(one_chip, case, monkeypatch):
+    """``conv_roofline`` (output map and input channels) and
+    ``costs_resnet.match_conv`` (and the weight operand (kh·kw, C·nnz/bz,
+    F)) still find the compiled k×k call, so neither roofline goes silent."""
+    monkeypatch.syspath_prepend(str(BENCH))
+    family, layer, tag = case.split(".")
+    if family == "cnn":
+        line = _call_line(_cnn_conv3x3(one_chip, layer, int(tag[1])), "vdbb_im2col_conv_tc")
+        config = json.loads((BENCH / "configs" / f"sparse-cnn-s.{tag}.json").read_text())
+        spec = importlib.util.spec_from_file_location("conv_roofline",
+                                                      BENCH / "metrics" / "conv_roofline.py")
+        reader = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(reader)
+        peaks = json.loads((BENCH / "peaks.json").read_text())["TPU v5 lite"]
+        run = types.SimpleNamespace(config=config, peaks=peaks, trace={
+            "window": [0, 10**7], "devices": {"tpu0": [[line, 0, 10**6]]}})
+        assert reader.read(run) is not None
+        return
+    h = int(tag)
+    s = one_chip
+    c = {56: 64, 7: 512}[h]
+    compiled = _compile(
+        lambda x, w, a, b, o: ops.quant_conv(
+            x, w, 3, 3, a, bias=b, relu=True, out_scale=o, padding=P1, interpret=False),
+        _spec((B, h, h, c), jnp.int8, s), _weight(9 * c, c, jnp.int8, s, FMT_R50),
+        _spec((), jnp.float32, s), _spec((c,), jnp.float32, s), _spec((), jnp.float32, s))
+    costs_resnet = importlib.import_module("costs_resnet")
+    config = json.loads((BENCH / "configs" / "resnet50.d4of8.json").read_text())
+    convs = [l for l in costs_resnet.layers(config) if l["kind"].startswith("conv")]
+    hit = costs_resnet.match_conv(_call_line(compiled, "vdbb_im2col_conv_tc"), convs)
+    assert hit is not None and hit[0]["kind"] == "conv3x3" and hit[1] == B
